@@ -18,12 +18,6 @@ backed by the optional native C++ loader under ``native/`` when built.
 
 __version__ = "0.1.0"
 
-# Before any framework module touches jax: shim older jax installs up to
-# the surface this package is written against (see utils/jax_compat.py).
-from pytorch_distributed_mnist_tpu.utils import jax_compat as _jax_compat
-
-_jax_compat.install()
-
 from pytorch_distributed_mnist_tpu.train.state import TrainState, create_train_state
 from pytorch_distributed_mnist_tpu.train.trainer import Trainer
 from pytorch_distributed_mnist_tpu.models import get_model

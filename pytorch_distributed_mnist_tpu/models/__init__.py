@@ -4,7 +4,7 @@ The reference hard-codes a single model (``Net``, a ``Linear(784, 10)``,
 ``/root/reference/multi_proc_single_gpu.py:119-126``) and constructs it at a
 fixed call site (``:185``). Here the model is pluggable via a registry:
 ``linear`` is the exact reference-parity model, ``cnn`` is the small convnet
-required for the >=99% MNIST accuracy target (BASELINE.md north star).
+required for the >=99% MNIST accuracy target (BASELINE.json north star).
 """
 
 from pytorch_distributed_mnist_tpu.models.linear import LinearNet

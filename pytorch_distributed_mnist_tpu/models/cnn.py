@@ -2,7 +2,7 @@
 
 The reference's model is a bare ``Linear(784, 10)``
 (``/root/reference/multi_proc_single_gpu.py:119-126``) which tops out around
-92-93% MNIST test accuracy; BASELINE.md's north star (>=99% in <60s on TPU)
+92-93% MNIST test accuracy; BASELINE.json's north star (>=99% in <60s on TPU)
 requires a conv model, so the zoo carries this 2-conv CNN in addition to the
 parity ``linear`` model (SURVEY.md section 0).
 

@@ -72,7 +72,7 @@ def _fused_per_example(logits: jnp.ndarray, labels: jnp.ndarray) -> jnp.ndarray:
         fused_cross_entropy_per_example,
     )
 
-    if _MESH is None or _MESH.shape[_MESH_AXIS] == 1:
+    if _MESH is None or _MESH.size == 1:
         return fused_cross_entropy_per_example(logits, labels)
     size = _MESH.shape[_MESH_AXIS]
     if logits.shape[0] % size:

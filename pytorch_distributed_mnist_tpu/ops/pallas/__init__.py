@@ -25,8 +25,9 @@ hand-written kernels cover the two places a fused kernel beats stock XLA:
   through the models' ``dot_general`` field so int8 buys chip clock,
   not just smaller transfers.
 
-Every kernel auto-selects interpret mode off-TPU so the whole suite runs
-hermetically on the virtual CPU mesh (tests/conftest.py).
+Every kernel lowers through Mosaic on a TPU and runs interpreted on the CPU
+backend, so the whole suite runs hermetically on the virtual CPU mesh
+(tests/conftest.py); any other backend is an error (``backend.py``).
 """
 
 from pytorch_distributed_mnist_tpu.ops.pallas.adam import fused_adam_leaf, pallas_adam

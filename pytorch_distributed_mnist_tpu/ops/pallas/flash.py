@@ -39,6 +39,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from pytorch_distributed_mnist_tpu.ops.attention import NEG_INF
+from pytorch_distributed_mnist_tpu.ops.pallas.backend import should_interpret
 
 __all__ = ["flash_attention", "sharded_flash_attention"]
 
@@ -117,15 +118,12 @@ def _block_sizes(t: int, block: int | None = None):
     # a divisor of T — a prime T would degrade to block 1); padded K
     # positions are masked inside the kernels, padded Q rows sliced off.
     # Default block 128 = the MXU tile. No flash-vs-dense ratio is
-    # currently established at any T: the round-3 capture that timed
-    # this config was invalidated (sync returned early; BASELINE.md,
-    # tools/captured/kernels_r3_invalid.json). Bigger tiles at long T
-    # are a plausible win (amortized loop/pipeline overhead; s/p
-    # scratch is block^2 f32, 256 KB at 256 — well inside VMEM) but
-    # UNMEASURED: the on-chip sweep (tools/sweep_flash.py, queued in
-    # tools/tpu_watch_r4.sh) exists to decide it. Until a valid
-    # flash_sweep.json lands, the default stays the MXU tile and the
-    # hypothesis is reachable via the explicit ``block=`` override.
+    # measured at any T on today's code. Bigger tiles at long T are a
+    # plausible win (amortized loop/pipeline overhead; s/p scratch is
+    # block^2 f32, 256 KB at 256 — well inside VMEM) but UNMEASURED: the
+    # on-chip sweep (tools/sweep_flash.py) exists to decide it. Until
+    # then the default stays the MXU tile and the hypothesis is reachable
+    # via the explicit ``block=`` override.
     if block is None:
         block = 128 if t >= 128 else ((t + 7) // 8) * 8
     t_pad = ((t + block - 1) // block) * block
@@ -330,20 +328,16 @@ def _flash_backward(q, k, v, o_heads, lse, g, causal: bool, scale: float,
 # --------------------------------------------------------------------------
 
 
-def _interpret_default() -> bool:
-    return jax.default_backend() != "tpu"
-
-
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
 def _flash(q, k, v, causal, scale, block):
     out, _, _ = _flash_forward(
-        q, k, v, causal, scale, _interpret_default(), block)
+        q, k, v, causal, scale, should_interpret(), block)
     return out
 
 
 def _flash_fwd(q, k, v, causal, scale, block):
     out, o_heads, lse = _flash_forward(
-        q, k, v, causal, scale, _interpret_default(), block
+        q, k, v, causal, scale, should_interpret(), block
     )
     return out, (q, k, v, o_heads, lse)
 
@@ -351,7 +345,7 @@ def _flash_fwd(q, k, v, causal, scale, block):
 def _flash_bwd(causal, scale, block, residuals, g):
     q, k, v, o_heads, lse = residuals
     return _flash_backward(
-        q, k, v, o_heads, lse, g, causal, scale, _interpret_default(), block
+        q, k, v, o_heads, lse, g, causal, scale, should_interpret(), block
     )
 
 
@@ -363,14 +357,13 @@ def flash_attention(q, k, v, *, causal: bool = False,
     """Flash attention on ``(B, T, H, D)``; drop-in for ``full_attention``.
 
     Fully differentiable with fused Pallas forward and backward kernels
-    (no (T, T) materialization in either pass); off-TPU the kernels run in
-    interpreter mode so tests are hermetic. Self-attention shapes only:
-    Tq must equal Tk (the kernel's start-aligned causal mask and the dense
+    (no (T, T) materialization in either pass); on the CPU backend the
+    kernels run in interpreter mode so tests are hermetic. Self-attention
+    shapes only: Tq must equal Tk (the kernel's start-aligned causal mask and the dense
     oracle's end-aligned mask agree exactly there).
 
     ``block`` overrides the q/k tile edge (multiple of 8; default 128 —
-    the MXU tile and the configuration all captured measurements used.
-    The override exists for the on-chip block sweep,
+    the MXU tile. The override exists for the on-chip block sweep,
     tools/sweep_flash.py, which decides whether long sequences get a
     bigger default).
     """

@@ -27,9 +27,9 @@ computed inside the jitted program — no host round-trip) on BOTH
 operands. The weight operand arrives already dequantized by the int8
 plane (per-leaf scales); re-quantizing per-tensor here costs one extra
 rounding relative to the dequant path, which is why the kernel is
-allclose-pinned against ``lax.dot_general`` rather than bitwise. Off-TPU
-the identical kernel runs in Pallas interpret mode (the
-``_should_interpret`` convention every kernel in this package follows).
+allclose-pinned against ``lax.dot_general`` rather than bitwise. On the
+CPU backend the identical kernel runs in Pallas interpret mode (the
+``should_interpret`` rule every kernel in this package follows).
 """
 
 from __future__ import annotations
@@ -38,7 +38,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from pytorch_distributed_mnist_tpu.ops.pallas.xent import _should_interpret
+from pytorch_distributed_mnist_tpu.ops.pallas.backend import should_interpret
 
 # int8 operands tile at (32, 128) on the MXU (int32 accumulators at
 # (8, 128)); padding every dim up to these keeps Mosaic's layout happy
@@ -54,17 +54,23 @@ __all__ = ["int8_dot_general", "matmul_i8", "quantize_dynamic_i8"]
 def _matmul_i8_kernel(a_ref, b_ref, out_ref):
     """One (bm, K) x (K, N) block product: int8 x int8 contracted on
     the MXU into the int32 accumulator — the whole point of the kernel;
-    an inferred accumulator would silently round in f32."""
+    an inferred accumulator would silently round in f32.
+
+    The precision is pinned to DEFAULT: an integer contraction is exact,
+    so there is nothing for a higher setting to buy, and an ambient
+    ``jax.default_matmul_precision("highest")`` would otherwise reach the
+    kernel as an fp32 contract precision that Mosaic refuses on int8
+    operands ("Bad lhs type", v5e, PR 21)."""
     out_ref[:] = jnp.dot(a_ref[:], b_ref[:],
-                         preferred_element_type=jnp.int32)
+                         preferred_element_type=jnp.int32,
+                         precision=jax.lax.Precision.DEFAULT)
 
 
 def _pad_to(n: int, m: int) -> int:
     return ((n + m - 1) // m) * m
 
 
-def matmul_i8(a: jnp.ndarray, b: jnp.ndarray,
-              interpret=None) -> jnp.ndarray:
+def matmul_i8(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
     """``(M, K) int8 x (K, N) int8 -> (M, N) int32`` on the MXU.
 
     Shapes pad up to the int8 tile grid (M to the 32-sublane multiple,
@@ -73,8 +79,6 @@ def matmul_i8(a: jnp.ndarray, b: jnp.ndarray,
     MNIST-scale operands (K <= a few thousand, N <= a few hundred) fit
     with room to spare, so no K-loop accumulation pass is needed.
     """
-    if interpret is None:
-        interpret = _should_interpret()
     if a.dtype != jnp.int8 or b.dtype != jnp.int8:
         raise ValueError(
             f"matmul_i8 takes int8 operands, got {a.dtype}/{b.dtype}")
@@ -97,7 +101,7 @@ def matmul_i8(a: jnp.ndarray, b: jnp.ndarray,
         ],
         out_specs=pl.BlockSpec((bm, np_), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((mp, np_), jnp.int32),
-        interpret=interpret,
+        interpret=should_interpret(),
     )(ap, bp)
     return out[:m, :n]
 

@@ -20,9 +20,9 @@ MNIST/FashionMNIST have 10). Wider heads would need a lane-tiled
 online-softmax (the flash-attention pattern); ``fused_cross_entropy``
 asserts rather than silently slowing down.
 
-Off-TPU the identical kernel runs in Pallas interpret mode, so the CPU
-suite exercises the same code path the chip compiles (conftest +
-``tests_tpu/`` split, like the other kernels).
+On the CPU backend the identical kernel runs in Pallas interpret mode, so
+the CPU suite exercises the same code path the chip compiles (conftest +
+``tests_tpu/`` split, like the other kernels; ``ops/pallas/backend.py``).
 """
 
 from __future__ import annotations
@@ -33,13 +33,11 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from pytorch_distributed_mnist_tpu.ops.pallas.backend import should_interpret
+
 _LANES = 128
 _BLOCK_ROWS = 128
 _SUBLANE = 8
-
-
-def _should_interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def _xent_fwd_kernel(c: int, logits_ref, label_ref, loss_ref, lse_ref):
@@ -121,9 +119,7 @@ def fused_cross_entropy_per_example(logits, labels):
     return loss
 
 
-def _fwd_impl(logits, labels, interpret=None):
-    if interpret is None:
-        interpret = _should_interpret()
+def _fwd_impl(logits, labels):
     b = logits.shape[0]
     l32, lab, r, n_blocks, bp, c = _prep(logits, labels)
     loss, lse = pl.pallas_call(
@@ -141,7 +137,7 @@ def _fwd_impl(logits, labels, interpret=None):
             jax.ShapeDtypeStruct((bp, 1), jnp.float32),
             jax.ShapeDtypeStruct((bp, 1), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=should_interpret(),
     )(l32, lab)
     return loss[:b, 0], lse
 
@@ -153,7 +149,6 @@ def _fwd_rule(logits, labels):
 
 def _bwd_rule(res, g):
     logits, labels, lse = res
-    interpret = _should_interpret()
     b = logits.shape[0]
     l32, lab, r, n_blocks, bp, c = _prep(logits, labels)
     gp = jnp.zeros((bp, 1), jnp.float32)
@@ -170,7 +165,7 @@ def _bwd_rule(res, g):
         ],
         out_specs=pl.BlockSpec((r, _LANES), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((bp, _LANES), jnp.float32),
-        interpret=interpret,
+        interpret=should_interpret(),
     )(l32, lab, lse, gp)
     dlogits = dl[:b, : logits.shape[1]].astype(logits.dtype)
     return dlogits, None

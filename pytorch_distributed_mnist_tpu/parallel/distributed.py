@@ -53,44 +53,6 @@ def _multiprocess_env_detected() -> bool:
     return False
 
 
-def _enable_cpu_collectives() -> None:
-    """Give the CPU backend a cross-process collectives implementation.
-
-    A multi-process world on the CPU backend (the local pod simulation
-    every ``--spawn``/subprocess-twin test runs, and the chaos harness)
-    needs one explicitly on this jaxlib: the default is ``none``, under
-    which EVERY global computation — train-step psums and the
-    supervision agreement allgathers alike — dies with
-    "Multiprocess computations aren't implemented on the CPU backend".
-    Gloo (TCP, wired to the jax.distributed client) is jax's own local
-    multi-process answer. Enabled when CPU is (or will resolve to) the
-    PRIMARY platform: an explicit request whose first entry is cpu, or
-    no platform preference at all on a machine with no TPU-pod markers —
-    the bare-CPU-cluster case, where jax resolves to CPU by itself.
-    Real pods (accelerator-first platform lists, or pod environment
-    variables) are untouched. Tolerant of jax versions that renamed or
-    removed the knob. Must run before the backend initializes (the same
-    ordering contract as ``jax.distributed.initialize`` itself).
-    """
-    try:
-        configured = (jax.config.jax_platforms or "").lower()
-    except AttributeError:
-        configured = ""
-    spec = configured or (os.environ.get("JAX_PLATFORMS") or "").lower()
-    if spec:
-        if spec.split(",")[0].strip() != "cpu":
-            return  # an accelerator owns the collectives
-    else:
-        env = os.environ
-        if env.get("TPU_WORKER_HOSTNAMES") \
-                or env.get("MEGASCALE_COORDINATOR_ADDRESS"):
-            return  # a real pod with no explicit platform preference
-    try:
-        jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except (AttributeError, ValueError):
-        pass
-
-
 def initialize_distributed(
     coordinator_address: Optional[str] = None,
     num_processes: Optional[int] = None,
@@ -113,7 +75,6 @@ def initialize_distributed(
 
     explicit = coordinator_address is not None or (num_processes or 0) > 1
     if explicit:
-        _enable_cpu_collectives()
         jax.distributed.initialize(
             coordinator_address=coordinator_address,
             num_processes=num_processes,
@@ -124,7 +85,6 @@ def initialize_distributed(
     elif _multiprocess_env_detected():
         # Let JAX's cluster autodetection (TPU pod metadata, Slurm, OMPI)
         # work out coordinator/size/rank on its own.
-        _enable_cpu_collectives()
         jax.distributed.initialize()
         _init_info["mode"] = "auto"
     else:

@@ -70,6 +70,14 @@ def strip_spawn_flag(argv: Sequence[str]) -> List[str]:
     return strip_flags(argv, {"--spawn": 1})
 
 
+def announce_cpu_simulation(nprocs: int) -> None:
+    """A spawned world is a CPU simulation of a multi-host pod; say so on
+    stdout when it starts, so nothing it prints is read as a chip's."""
+    print(f"spawn: CPU simulation of a {nprocs}-host world — {nprocs} "
+          f"local processes with one CPU device each (JAX_PLATFORMS=cpu); "
+          f"no accelerator is used", flush=True)
+
+
 def _child_env() -> dict:
     """Environment for one spawned host process: CPU backend, exactly ONE
     local device (any ``xla_force_host_platform_device_count`` from the
@@ -108,6 +116,7 @@ def spawn_local(
     child_argv = strip_spawn_flag(argv)
     port = free_port()
     env = _child_env()
+    announce_cpu_simulation(nprocs)
 
     procs = []
     logs = []

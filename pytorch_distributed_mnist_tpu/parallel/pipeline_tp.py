@@ -313,7 +313,7 @@ def create_pipelined_tp_vit_state(
         model.init(rng, jnp.zeros((1, 28, 28, 1), jnp.float32)),
         model.num_heads,
     )
-    tx = make_optimizer(lr, optimizer, momentum, weight_decay)
+    tx = make_optimizer(lr, optimizer, momentum, weight_decay, mesh=mesh)
     apply_fn = make_pipelined_tp_vit_apply(
         model, mesh, stage_axis=stage_axis, tp_axis=tp_axis,
         data_axis=data_axis, num_microbatches=num_microbatches,

@@ -300,7 +300,7 @@ def create_pipelined_vit_state(
     params = split_vit_params(
         model.init(rng, jnp.zeros((1, 28, 28, 1), jnp.float32))
     )
-    tx = make_optimizer(lr, optimizer, momentum, weight_decay)
+    tx = make_optimizer(lr, optimizer, momentum, weight_decay, mesh=mesh)
     apply_fn = make_pipelined_vit_apply(
         model, mesh, axis=axis, data_axis=data_axis,
         num_microbatches=num_microbatches,

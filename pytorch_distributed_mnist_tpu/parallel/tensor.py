@@ -363,7 +363,7 @@ def create_overlap_tp_vit_state(model, rng: jax.Array, mesh: Mesh, *,
         model.init(rng, jnp.zeros((1, 28, 28, 1), jnp.float32)),
         model.num_heads,
     )
-    tx = make_optimizer(lr, optimizer, momentum, weight_decay)
+    tx = make_optimizer(lr, optimizer, momentum, weight_decay, mesh=mesh)
     apply_fn = make_overlap_tp_vit_apply(
         model, mesh, tp_axis=tp_axis, data_axis=data_axis)
     state = TrainState(

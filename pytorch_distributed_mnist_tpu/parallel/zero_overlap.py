@@ -23,9 +23,8 @@ forward. This module writes that schedule out explicitly:
   previous bucket — so bucket k's communication can start the moment its
   gradients exist, while the backward still computes other buckets'
   gradients, and XLA's latency-hiding scheduler is free to overlap the
-  two. ``lax.optimization_barrier`` (AD shim: ``utils/jax_compat.py``)
-  provides the fences: it pins bucket order without inventing data
-  dependencies on unrelated compute.
+  two. ``lax.optimization_barrier`` provides the fences: it pins bucket
+  order without inventing data dependencies on unrelated compute.
 - **Carried allgather** (ZeRO-3): the step takes the previous step's
   gathered (replicated) params as an argument and returns the next
   gathered copy rebuilt from the updated shards — the allgather sits at

@@ -124,6 +124,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 from pytorch_distributed_mnist_tpu.parallel.launcher import (
     _child_env,
+    announce_cpu_simulation,
     free_port,
     strip_flags,
     strip_spawn_flag,
@@ -769,6 +770,7 @@ def supervise(
             f"--max-world {max_world} is below the initial world size "
             f"{nprocs} (0 = unbounded)")
     base_argv = strip_spawn_flag(strip_elastic_flags(argv))
+    announce_cpu_simulation(nprocs)
     own_dir = rendezvous_dir is None
     if own_dir:
         rendezvous_dir = tempfile.mkdtemp(prefix="tpumnist-elastic-")

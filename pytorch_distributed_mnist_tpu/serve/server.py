@@ -49,6 +49,7 @@ from __future__ import annotations
 import argparse
 import json
 import os
+import signal
 import threading
 import time
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
@@ -92,6 +93,7 @@ from pytorch_distributed_mnist_tpu.utils.profiling import (
     JsonlSink,
     ServeLog,
     compile_log,
+    device_report,
 )
 
 
@@ -380,9 +382,10 @@ def build_parser() -> argparse.ArgumentParser:
                         "appears)")
     p.add_argument("--compile-cache", type=str, default=None, metavar="DIR",
                    help="persistent XLA compile cache (same resolution as "
-                        "training: flag > TPUMNIST_COMPILE_CACHE > repo "
-                        "default; '' disables) — a warm cache turns the "
-                        "startup bucket compiles into fetches")
+                        "training: JAX_COMPILATION_CACHE_DIR when set, "
+                        "else this flag, else <checkout>/.xla_cache; '' "
+                        "disables) — a warm cache turns the startup "
+                        "bucket compiles into fetches")
     p.add_argument("--metrics-file", type=str, default=None,
                    help="append serve_stats / serve_reload JSONL lines "
                         "here — the same format/flag as training, so one "
@@ -780,6 +783,10 @@ class _Handler(BaseHTTPRequestHandler):
                 # router must distinguish "drain in progress" from
                 # "dead" or it would quarantine every rolling deploy.
                 "draining": ctx.draining,
+                # What answers, as jax reports it, so a client (loadgen
+                # --smoke, chip_smoke.py) asserts the device through this
+                # interface rather than from a boot log.
+                **device_report(),
             }
             if ctx.multi_model:
                 payload["models"] = {
@@ -1917,6 +1924,15 @@ def main(argv: Optional[list] = None) -> None:
         stats_timer = (threading.Thread(target=_periodic, daemon=True,
                                         name="serve-stats"), stop)
         stats_timer[0].start()
+    # SIGTERM (the signal an orchestrator, `kill`, or chip_smoke.py sends)
+    # takes the same clean path as Ctrl-C: stop accepting, close the
+    # serving stack, exit 0. Installed after create_server — the TPU
+    # runtime installs its own fatal-signal reporter at backend init, and
+    # this handler has to be the later one.
+    def _terminate(signum, frame):
+        raise KeyboardInterrupt
+
+    signal.signal(signal.SIGTERM, _terminate)
     try:
         httpd.serve_forever()
     except KeyboardInterrupt:

@@ -55,6 +55,7 @@ def make_optimizer(
     optimizer: str = "adam",
     momentum: float = 0.9,
     weight_decay: float = 1e-4,
+    mesh=None,
 ) -> optax.GradientTransformation:
     """Build the optimizer.
 
@@ -62,6 +63,8 @@ def make_optimizer(
     with momentum+weight-decay mirrors its commented-out alternative
     (``:192-194``) so the ``--momentum`` / ``--wd`` flags are functional here
     rather than dead as in the reference (SURVEY.md section 5 config notes).
+    ``mesh`` is the mesh the train step runs over; only ``adam_pallas``
+    needs it (a Mosaic kernel must be shard_mapped on a multi-device mesh).
     """
     if optimizer == "adam":
         return optax.inject_hyperparams(optax.adam)(learning_rate=lr)
@@ -70,7 +73,10 @@ def make_optimizer(
         # fused Pallas kernel (ops/pallas/adam.py) — checkpoint-compatible.
         from pytorch_distributed_mnist_tpu.ops.pallas.adam import pallas_adam
 
-        return optax.inject_hyperparams(pallas_adam)(learning_rate=lr)
+        # static: a Mesh is callable, and inject_hyperparams would take a
+        # callable argument for a schedule.
+        return optax.inject_hyperparams(pallas_adam, static_args="mesh")(
+            learning_rate=lr, mesh=mesh)
     if optimizer == "sgd":
 
         def sgd_wd(learning_rate):
@@ -91,10 +97,12 @@ def create_train_state(
     optimizer: str = "adam",
     momentum: float = 0.9,
     weight_decay: float = 1e-4,
+    mesh=None,
 ) -> TrainState:
-    """Initialize params (float32) and optimizer state for ``model``."""
+    """Initialize params (float32) and optimizer state for ``model``.
+    ``mesh``: see :func:`make_optimizer`."""
     params = model.init(rng, jnp.zeros(input_shape, jnp.float32))
-    tx = make_optimizer(lr, optimizer, momentum, weight_decay)
+    tx = make_optimizer(lr, optimizer, momentum, weight_decay, mesh=mesh)
     return TrainState(
         step=jnp.zeros((), jnp.int32),
         params=params,

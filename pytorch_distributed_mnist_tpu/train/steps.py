@@ -361,14 +361,12 @@ def make_train_epoch_indexed(
     drops: one (B, ...) batch materializes per tick instead of the staged
     (S, B, ...) epoch.
 
-    Measured on chip (round 3, ``tools/captured/bench.json``) this path
-    is ~10% SLOWER than host-gather on the MNIST CNN (337,085 vs 375,868
-    img/s/chip) — the random-row HBM gather costs more than the staged
-    epoch's one upload saves at this dataset size. It is therefore the
-    documented memory/host-bandwidth saver, NOT the throughput default
-    (``--epoch-gather host`` everywhere since round 5); ``bench.py``'s
-    sorted-index secondary probes whether gather locality (sort indices
-    within a tick) closes the gap.
+    Host against device gather is not measured on today's code (the one
+    old chip line put this path ~10% slower on the MNIST CNN; ROADMAP
+    S3.2 re-measures it). Until then it is the documented
+    memory/host-bandwidth saver, not the default (``--epoch-gather
+    host``); ``bench.py``'s sorted-index secondary probes whether gather
+    locality (sort indices within a tick) matters.
     """
     return _make_epoch(mesh, axis, state_sharding,
                        make_accum_train_step_fn(grad_accum, aux_weight),

@@ -1,27 +1,26 @@
 """Persistent XLA compile-cache wiring — ONE config-update path for every
-entry point (``cli.run``, ``bench.py``, ``tools/northstar.py``, the test
-harness).
+entry point (``cli.run``, ``serve``, ``bench.py``, ``chip_smoke.py``'s
+children, ``tests_tpu/``, the tools).
 
 First compilation of the jitted whole-epoch programs is the framework's
-startup tax (VERDICT round 5: the entire 62.4s-vs-60s cold north-star gap
-is compile time), and pjit-era practice treats the persistent compilation
-cache + AOT lowering as the standard remedy. Before this module each entry
-point carried its own copy of the config dance (``cli.run`` had one,
-``bench.configure_jax`` another, the trainer none); they drifted. Now all
-of them call :func:`configure`.
+startup tax, and a chip-tool call starts on a cold machine every time, so
+the persistent compilation cache + AOT lowering is the standard remedy.
+Every entry point calls :func:`configure`; none writes
+``jax_compilation_cache_dir`` itself.
 
 Resolution order for the cache directory:
 
-1. explicit argument (the ``--compile-cache`` flag) — empty string means
-   "explicitly disabled";
-2. ``TPUMNIST_COMPILE_CACHE`` env var — empty string disables;
-3. the AMBIENT process config: whatever a harness installed process-wide
-   before the first ``configure()`` call (``tests/conftest.py`` installs
-   its shared cache via :func:`configure_ambient`), so flag-less re-entrant
-   ``run()`` calls keep the harness's cache instead of clobbering it;
-4. the default ``<repo>/.xla_cache`` — the same dir ``tools/tpu_watch.sh``
-   pre-warms and ``bench.py`` shares, so a production ``cli run`` benefits
-   from any prior warmup with zero flags.
+1. an explicit empty ``--compile-cache ""`` — caching disabled;
+2. ``JAX_COMPILATION_CACHE_DIR`` when set and non-empty — the standard jax
+   variable places the cache from outside and WINS over any directory
+   named in code or flags (jax already bound it at import; this module
+   sets no other);
+3. a non-empty ``--compile-cache DIR`` flag;
+4. ``JAX_COMPILATION_CACHE_DIR`` set but empty — caching disabled (what
+   the hermetic CPU suite exports);
+5. the default ``<checkout>/.xla_cache`` — a fixed path, never a temporary
+   name: the path is part of the cache key, so a directory that moves
+   never hits.
 
 Cache entries are keyed by jax/jaxlib version, backend, and the serialized
 program, so CPU test entries never collide with TPU entries and a jax
@@ -36,51 +35,38 @@ from typing import Optional, Tuple
 
 import jax
 
-ENV_VAR = "TPUMNIST_COMPILE_CACHE"
+ENV_VAR = "JAX_COMPILATION_CACHE_DIR"
 
 _lock = threading.Lock()
-# (dir, min_compile_secs, min_entry_bytes) from before the first
-# configure() — the config a flag-less run restores its disable path to.
-_ambient: Optional[Tuple] = None
-# True once a harness PINNED the ambient config via configure_ambient():
-# flag-less runs then follow the harness (even "no cache"), instead of
-# falling through to the repo default. tests/conftest.py pins "disabled"
-# on jaxlibs whose in-process cache reuse is unsound (see its comment).
-_pinned = False
+# jax's own (min_compile_secs, min_entry_bytes) from before the first
+# configure(): what the implicit default dir and the disable path restore.
+_jax_thresholds: Optional[Tuple] = None
 
 
 def default_cache_dir() -> str:
-    """``<repo>/.xla_cache`` (gitignored, shared with bench/tools/tests)."""
+    """``<checkout>/.xla_cache`` (gitignored, shared by every entry point)."""
     here = os.path.dirname(os.path.abspath(__file__))
     return os.path.join(os.path.dirname(os.path.dirname(here)), ".xla_cache")
-
-
-def _snapshot() -> Tuple:
-    return (
-        jax.config.jax_compilation_cache_dir,
-        jax.config.jax_persistent_cache_min_compile_time_secs,
-        jax.config.jax_persistent_cache_min_entry_size_bytes,
-    )
 
 
 def _resolve(flag: Optional[str]):
     """``(dir, explicit)``: the directory :func:`configure` would activate
     for ``flag`` (``None`` = disabled) and whether it was explicitly
-    requested (flag/env/harness pin) rather than the implicit repo
-    default. Explicit requests cache EVERY program (thresholds zeroed —
-    the CPU-test programs compile sub-second and must still hit); the
-    implicit default keeps jax's thresholds, which skip sub-second
-    micro-programs (model-init one-offs) so a flag-less production run
-    doesn't litter the dir with hundreds of tiny entries per run."""
-    if flag is not None:
-        return flag or None, True
+    requested (env/flag) rather than the implicit checkout default.
+    Explicit requests cache EVERY program (thresholds zeroed — the CPU-test
+    programs compile sub-second and must still hit); the implicit default
+    keeps jax's thresholds, which skip sub-second micro-programs
+    (model-init one-offs) so a flag-less run doesn't litter the dir with
+    hundreds of tiny entries per run."""
+    if flag == "":
+        return None, True
     env = os.environ.get(ENV_VAR)
+    if env:
+        return env, True
+    if flag:
+        return flag, True
     if env is not None:
-        return env or None, True
-    if _pinned:
-        return _ambient[0] or None, True
-    if _ambient is not None and _ambient[0]:
-        return _ambient[0], True
+        return None, True
     return default_cache_dir(), False
 
 
@@ -90,7 +76,9 @@ def resolve_cache_dir(flag: Optional[str] = None) -> Optional[str]:
     return _resolve(flag)[0]
 
 
-def _apply(cache_dir: Optional[str], cache_everything: bool = True) -> None:
+def _apply(cache_dir: Optional[str], cache_everything: bool) -> None:
+    min_secs, min_bytes = (0.0, 0) if cache_dir and cache_everything \
+        else _jax_thresholds
     if cache_dir:
         if jax.config.jax_compilation_cache_dir != cache_dir:
             # jax binds its cache object to the first dir that initializes
@@ -105,33 +93,14 @@ def _apply(cache_dir: Optional[str], cache_everything: bool = True) -> None:
 
             _cc.reset_cache()
             jax.clear_caches()
+            jax.config.update("jax_compilation_cache_dir", cache_dir)
         # Created eagerly (idempotent) so a first run's background
         # precompile threads never race the cache backend's own mkdir.
         os.makedirs(cache_dir, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-        if cache_everything:
-            # Cache every program, however small/fast-compiling (defaults
-            # skip sub-second compiles, which covers most CPU-test
-            # programs) — for explicitly-requested dirs (see _resolve).
-            jax.config.update(
-                "jax_persistent_cache_min_compile_time_secs", 0.0)
-            jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-        else:
-            _, amb_secs, amb_bytes = _ambient
-            jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                              amb_secs)
-            jax.config.update("jax_persistent_cache_min_entry_size_bytes",
-                              amb_bytes)
     else:
-        # Explicit disable (flag/env ""): the user asked for NO cache, not
-        # for the ambient one — dir goes to None; the entry-size/compile-
-        # time thresholds return to their pre-run values.
-        _, amb_secs, amb_bytes = _ambient
         jax.config.update("jax_compilation_cache_dir", None)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                          amb_secs)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes",
-                          amb_bytes)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", min_secs)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", min_bytes)
 
 
 def configure(flag: Optional[str] = None) -> Optional[str]:
@@ -140,31 +109,17 @@ def configure(flag: Optional[str] = None) -> Optional[str]:
     previous run's dir never leaks into a run that asked for another (or
     for none), and an unchanged dir never clears the in-memory jit cache.
     """
-    global _ambient
+    global _jax_thresholds
     with _lock:
-        if _ambient is None:
-            _ambient = _snapshot()
+        if _jax_thresholds is None:
+            _jax_thresholds = (
+                jax.config.jax_persistent_cache_min_compile_time_secs,
+                jax.config.jax_persistent_cache_min_entry_size_bytes,
+            )
         cache_dir, explicit = _resolve(flag)
         _apply(cache_dir, cache_everything=explicit)
         return cache_dir
 
 
-def configure_ambient(cache_dir: Optional[str]) -> Optional[str]:
-    """Harness-level entry (``tests/conftest.py``): activate ``cache_dir``
-    AND pin the result as the ambient baseline — later flag-less
-    :func:`configure` calls follow it exactly, INCLUDING a pinned
-    "no cache" (``cache_dir`` empty/None), instead of falling through to
-    the repo default."""
-    global _ambient, _pinned
-    with _lock:
-        if _ambient is None:
-            _ambient = _snapshot()
-        if cache_dir:
-            _apply(cache_dir)
-        _ambient = _snapshot()
-        _pinned = True
-        return cache_dir or None
-
-
 def active_cache_dir() -> Optional[str]:
-    return jax.config.jax_compilation_cache_dir
+    return jax.config.jax_compilation_cache_dir or None

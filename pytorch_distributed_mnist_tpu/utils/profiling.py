@@ -3,7 +3,7 @@
 The reference has none — ``time`` is imported but never used
 (``/root/reference/multi_proc_single_gpu.py:5``; SURVEY.md section 5
 "Tracing/profiling: ABSENT"). The TPU build reports steps/sec and
-images/sec/chip (the BASELINE.md metric) and can capture an XLA profiler
+images/sec/chip (the BASELINE.json metric) and can capture an XLA profiler
 trace for xprof/tensorboard.
 """
 
@@ -241,6 +241,48 @@ def stage_occupancy(stage_step_ms: dict) -> dict:
             for name, ms in stage_step_ms.items()}
 
 
+def device_report() -> dict:
+    """What this process runs on and through, for every run summary and
+    ``/healthz`` reply, so nothing is read without its device:
+    ``platform``/``device_kind``/``device_count`` in jax's own words,
+    ``input_backend`` (the host input path in use: ``native`` C++ or
+    ``numpy``), and ``pallas_lowerings`` (:class:`LoweringLog`)."""
+    from pytorch_distributed_mnist_tpu.data import native
+
+    devices = jax.devices()
+    return {"platform": devices[0].platform,
+            "device_kind": devices[0].device_kind,
+            "device_count": len(devices),
+            "input_backend": "native" if native.available() else "numpy",
+            "pallas_lowerings": pallas_lowerings.snapshot()}
+
+
+class LoweringLog:
+    """How many ``pallas_call`` sites were traced under each lowering in
+    this process — ``mosaic`` (compiled for the TPU) or ``interpret`` (the
+    CPU interpreter). Trace-time decisions, not executions
+    (``ops/pallas/backend.py`` records them). Run summaries and
+    ``/healthz`` carry the snapshot: a chip run must show zero
+    interpreted, and a run that selected a kernel must show it lowered."""
+
+    def __init__(self) -> None:
+        self._lock = threading.Lock()
+        self._counts = {"mosaic": 0, "interpret": 0}
+
+    def record(self, kind: str) -> None:
+        with self._lock:
+            self._counts[kind] += 1
+
+    def snapshot(self) -> Dict[str, int]:
+        with self._lock:
+            return dict(self._counts)
+
+
+# Process-wide for the same reason as compile_log: kernels are traced from
+# whatever thread compiles the program that contains them.
+pallas_lowerings = LoweringLog()
+
+
 class CompileLog:
     """Per-program compile observability: wall ms, XLA backend compiles,
     and persistent-cache hit/miss, attributed to named programs.
@@ -276,7 +318,7 @@ class CompileLog:
     # -- jax.monitoring plumbing ------------------------------------------
 
     def _ensure_listening(self) -> None:
-        from jax._src import monitoring
+        from jax import monitoring
 
         # Under the lock: concurrent FIRST measures (the trainer's
         # background precompile threads) must not both register, or every
@@ -295,14 +337,13 @@ class CompileLog:
         hold a strong reference to the instance and fire on every future
         compile — fine for the module singleton, a leak for throwaway
         instances (tests), which should close() when done."""
-        from jax._src import monitoring
+        from jax import monitoring
 
         with self._lock:
             if not self._listening:
                 return
-            monitoring._unregister_event_listener_by_callback(self._on_event)
-            monitoring._unregister_event_duration_listener_by_callback(
-                self._on_duration)
+            monitoring.unregister_event_listener(self._on_event)
+            monitoring.unregister_event_duration_listener(self._on_duration)
             self._listening = False
 
     def _current(self) -> Optional[Dict]:
@@ -322,9 +363,7 @@ class CompileLog:
                 rec[key] += 1
 
     def _on_duration(self, name: str, secs: float, **kwargs) -> None:
-        # The event was renamed across jax versions; accept both.
-        if name not in ("/jax/core/compile/backend_compile_duration",
-                        "/jax/core/compile/backend_compile_time_sec"):
+        if name != "/jax/core/compile/backend_compile_duration":
             return
         rec = self._current()
         with self._lock:

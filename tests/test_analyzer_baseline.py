@@ -201,29 +201,3 @@ def test_json_output_is_deterministic(tmp_path):
     a = run_analysis([str(target)], baseline=None).to_dict()
     b = run_analysis([str(target)], baseline=None).to_dict()
     assert json.dumps(a, sort_keys=True) == json.dumps(b, sort_keys=True)
-
-
-def test_collected_skip_dirs_are_rooted_not_bare_names(tmp_path):
-    """A source directory merely NAMED 'captured' must still be analyzed;
-    only the repo-rooted tools/captured artifact dir is skipped (bare-name
-    skipping would let the gate silently drop a real package dir)."""
-    from tools.analyzer.core import collect_files
-
-    (tmp_path / "pyproject.toml").write_text("[tool.x]\n")
-    pkg = tmp_path / "pkg" / "captured"
-    pkg.mkdir(parents=True)
-    (pkg / "mod.py").write_text("x = 1\n")
-    artifacts = tmp_path / "tools" / "captured"
-    artifacts.mkdir(parents=True)
-    (artifacts / "stray.py").write_text("x = 1\n")
-    cache = tmp_path / "pkg" / "__pycache__"
-    cache.mkdir()
-    (cache / "junk.py").write_text("x = 1\n")
-
-    files, problems = collect_files([str(tmp_path)])
-    rel = {str(f).replace(str(tmp_path), "").replace("\\", "/").lstrip("/")
-           for f in files}
-    assert problems == []
-    assert "pkg/captured/mod.py" in rel
-    assert "tools/captured/stray.py" not in rel
-    assert not any("__pycache__" in f for f in rel)

@@ -21,14 +21,14 @@ def test_bench_input_reports_pipeline_and_native_fields():
     env = os.environ.copy()
     env.update({
         "JAX_PLATFORMS": "cpu",
+        "BENCH_FORCE_CPU": "1",  # the explicit CPU schema switch
         # Small drives: this asserts SCHEMA, not throughput. The compile
         # cache stays off — the bench both writes and re-reads entries
         # in one process, the exact pattern DESIGN.md 6c bans.
         "BENCH_INPUT_STEPS": "4",
         "BENCH_INPUT_BATCH": "256",
         "BENCH_INPUT_REPS": "3",
-        "BENCH_COMPILE_CACHE": "",
-        "TPUMNIST_COMPILE_CACHE": "",
+        "JAX_COMPILATION_CACHE_DIR": "",
     })
     env.pop("XLA_FLAGS", None)  # let the bench pick its own isolation
     proc = subprocess.run(
@@ -90,12 +90,12 @@ def test_bench_input_numpy_fallback_labelled():
     env = os.environ.copy()
     env.update({
         "JAX_PLATFORMS": "cpu",
+        "BENCH_FORCE_CPU": "1",  # the explicit CPU schema switch
         "TPUMNIST_NATIVE": "0",
         "BENCH_INPUT_STEPS": "2",
         "BENCH_INPUT_BATCH": "128",
         "BENCH_INPUT_REPS": "2",
-        "BENCH_COMPILE_CACHE": "",
-        "TPUMNIST_COMPILE_CACHE": "",
+        "JAX_COMPILATION_CACHE_DIR": "",
     })
     env.pop("XLA_FLAGS", None)
     proc = subprocess.run(

@@ -16,7 +16,8 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 @pytest.mark.slow
 def test_bench_kernels_quick_emits_json():
-    env = dict(os.environ, JAX_PLATFORMS="cpu", BENCH_COMPILE_CACHE="")
+    env = dict(os.environ, JAX_PLATFORMS="cpu", BENCH_FORCE_CPU="1",
+               JAX_COMPILATION_CACHE_DIR="")
     proc = subprocess.run(
         [sys.executable, os.path.join(REPO, "tools", "bench_kernels.py"),
          "--quick", "--reps", "1", "--iters", "1"],
@@ -40,7 +41,8 @@ def test_bench_kernels_impossible_mfu_fails_loudly():
     nonzero, stamp "invalid", and NOT carry the "sync": "host_read"
     validity marker. Peak is faked to 1 FLOP/s so any real timing
     violates it."""
-    env = dict(os.environ, JAX_PLATFORMS="cpu", BENCH_COMPILE_CACHE="",
+    env = dict(os.environ, JAX_PLATFORMS="cpu", BENCH_FORCE_CPU="1",
+               JAX_COMPILATION_CACHE_DIR="",
                BENCH_FAKE_PEAK_FLOPS="1.0")
     proc = subprocess.run(
         [sys.executable, os.path.join(REPO, "tools", "bench_kernels.py"),
@@ -59,7 +61,8 @@ def test_bench_kernels_adam_hbm_guard_fails_loudly():
     """Same contract for the HBM-bandwidth bound on the (attention-MFU-
     blind) Adam rows: faked 1 byte/s bandwidth makes any timing
     impossible."""
-    env = dict(os.environ, JAX_PLATFORMS="cpu", BENCH_COMPILE_CACHE="",
+    env = dict(os.environ, JAX_PLATFORMS="cpu", BENCH_FORCE_CPU="1",
+               JAX_COMPILATION_CACHE_DIR="",
                BENCH_FAKE_HBM_BW="1.0")
     proc = subprocess.run(
         [sys.executable, os.path.join(REPO, "tools", "bench_kernels.py"),
@@ -74,7 +77,8 @@ def test_bench_kernels_adam_hbm_guard_fails_loudly():
 
 @pytest.mark.slow
 def test_sweep_flash_impossible_mfu_fails_loudly():
-    env = dict(os.environ, JAX_PLATFORMS="cpu", BENCH_COMPILE_CACHE="",
+    env = dict(os.environ, JAX_PLATFORMS="cpu", BENCH_FORCE_CPU="1",
+               JAX_COMPILATION_CACHE_DIR="",
                BENCH_FAKE_PEAK_FLOPS="1.0")
     proc = subprocess.run(
         [sys.executable, os.path.join(REPO, "tools", "sweep_flash.py"),
@@ -93,7 +97,8 @@ def test_sweep_flash_quick_emits_json():
     watcher runs it unattended in a rare chip-recovery window, and it
     imports across modules by path hack (bench.configure_jax,
     bench_kernels._timeit) — drift there must fail here, not there."""
-    env = dict(os.environ, JAX_PLATFORMS="cpu", BENCH_COMPILE_CACHE="")
+    env = dict(os.environ, JAX_PLATFORMS="cpu", BENCH_FORCE_CPU="1",
+               JAX_COMPILATION_CACHE_DIR="")
     proc = subprocess.run(
         [sys.executable, os.path.join(REPO, "tools", "sweep_flash.py"),
          "--quick", "--reps", "1", "--iters", "1"],

@@ -27,8 +27,7 @@ def _run(extra_env):
         # or the adjacency measurement degenerates to one-chunk leaves.
         "BENCH_PUBLISH_CHUNK_MB": "0.25",
         "BENCH_PUBLISH_BACKENDS": "3",
-        "BENCH_COMPILE_CACHE": "",
-        "TPUMNIST_COMPILE_CACHE": "",
+        "JAX_COMPILATION_CACHE_DIR": "",
     })
     env.update(extra_env)
     return subprocess.run(

@@ -34,8 +34,7 @@ def test_bench_serve_reports_scaling_and_pipeline_fields():
         "BENCH_FLEET_REQUESTS": "24",
         "BENCH_ECONOMICS_SECONDS": "0.6",
         "BENCH_ECONOMICS_REQUESTS": "48",
-        "BENCH_COMPILE_CACHE": "",
-        "TPUMNIST_COMPILE_CACHE": "",
+        "JAX_COMPILATION_CACHE_DIR": "",
     })
     proc = subprocess.run(
         [sys.executable, os.path.join(REPO, "bench.py"), "--mode", "serve"],
@@ -279,8 +278,7 @@ def test_bench_serve_overload_verdicts_fail_loudly():
         "BENCH_ECONOMICS_SECONDS": "0.5",
         "BENCH_ECONOMICS_REQUESTS": "32",
         "BENCH_ECONOMICS_INJECT_FAIL": "1",
-        "BENCH_COMPILE_CACHE": "",
-        "TPUMNIST_COMPILE_CACHE": "",
+        "JAX_COMPILATION_CACHE_DIR": "",
     })
     proc = subprocess.run(
         [sys.executable, os.path.join(REPO, "bench.py"), "--mode",

@@ -64,14 +64,14 @@ def _run_zero_bench(env_extra, timeout=540):
     env = os.environ.copy()
     env.update({
         "JAX_PLATFORMS": "cpu",
+        "BENCH_FORCE_CPU": "1",  # the explicit CPU schema switch
         # Small drives: this asserts SCHEMA, not throughput. The compile
         # cache stays off — the bench both writes and re-reads entries
         # in one process, the exact pattern DESIGN.md 6c bans.
         "BENCH_ZERO_STEPS": "3",
         "BENCH_ZERO_BATCH": "128",
         "BENCH_ZERO_REPS": "3",
-        "BENCH_COMPILE_CACHE": "",
-        "TPUMNIST_COMPILE_CACHE": "",
+        "JAX_COMPILATION_CACHE_DIR": "",
         # Exercises the MFU math on CPU (the _peak_flops test hook the
         # training bench uses); stamped into the line as fake_bounds.
         "BENCH_FAKE_PEAK_FLOPS": "1e12",

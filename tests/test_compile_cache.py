@@ -3,11 +3,11 @@
 CompileLog), and the trainer's AOT precompile (train/steps.py +
 train/trainer.py).
 
-The persistent cache is deliberately NEVER enabled inside this pytest
-process (see tests/conftest.py: in-process write-then-deserialize is
-unsound on this jaxlib). Everything cache-ON runs in fresh subprocesses —
-exactly the safe production patterns (cold run writes, warm fresh process
-reads).
+The persistent cache is off inside this pytest process by default
+(tests/conftest.py exports an empty JAX_COMPILATION_CACHE_DIR so the suite
+is deterministic and writes nothing into the checkout). Cache-ON behaviour
+is exercised through an explicit directory, mostly in fresh subprocesses —
+the production pattern (cold run writes, warm fresh process reads).
 """
 
 import json
@@ -31,17 +31,14 @@ from pytorch_distributed_mnist_tpu.utils.profiling import (  # noqa: E402
 
 @pytest.fixture
 def cache_module_state():
-    """Snapshot/restore compile_cache's module globals and the jax cache
-    config so precedence tests can't leak into the suite (where the
-    harness pinned 'no cache')."""
-    saved = (compile_cache._ambient, compile_cache._pinned)
+    """Restore the jax cache config after a test that calls configure(),
+    so it can't leak into the suite (which runs with the cache off)."""
     saved_cfg = (
         jax.config.jax_compilation_cache_dir,
         jax.config.jax_persistent_cache_min_compile_time_secs,
         jax.config.jax_persistent_cache_min_entry_size_bytes,
     )
     yield
-    compile_cache._ambient, compile_cache._pinned = saved
     jax.config.update("jax_compilation_cache_dir", saved_cfg[0])
     jax.config.update("jax_persistent_cache_min_compile_time_secs",
                       saved_cfg[1])
@@ -52,41 +49,41 @@ def cache_module_state():
 # -- resolution precedence --------------------------------------------------
 
 
-def test_flag_beats_env_and_default(cache_module_state, monkeypatch):
+def test_env_dir_beats_flag_dir(monkeypatch):
+    """A cache placed from outside wins over a directory named in code or
+    flags; only an explicit empty flag (disable) outranks it."""
     monkeypatch.setenv(compile_cache.ENV_VAR, "/env/dir")
-    assert compile_cache.resolve_cache_dir("/flag/dir") == "/flag/dir"
-    # Empty flag = explicit disable, even with the env set.
+    assert compile_cache.resolve_cache_dir("/flag/dir") == "/env/dir"
+    assert compile_cache.resolve_cache_dir(None) == "/env/dir"
     assert compile_cache.resolve_cache_dir("") is None
 
 
-def test_env_beats_default(cache_module_state, monkeypatch):
-    monkeypatch.setattr(compile_cache, "_pinned", False)
-    monkeypatch.setattr(compile_cache, "_ambient", None)
-    monkeypatch.setenv(compile_cache.ENV_VAR, "/env/dir")
-    assert compile_cache.resolve_cache_dir(None) == "/env/dir"
+def test_flag_beats_empty_env_and_default(monkeypatch):
     monkeypatch.setenv(compile_cache.ENV_VAR, "")
+    assert compile_cache.resolve_cache_dir("/flag/dir") == "/flag/dir"
+    # Set-but-empty variable, no flag: disabled (what this suite exports).
     assert compile_cache.resolve_cache_dir(None) is None
+    monkeypatch.delenv(compile_cache.ENV_VAR)
+    assert compile_cache.resolve_cache_dir("/flag/dir") == "/flag/dir"
 
 
-def test_default_is_repo_xla_cache(cache_module_state, monkeypatch):
-    monkeypatch.setattr(compile_cache, "_pinned", False)
-    monkeypatch.setattr(compile_cache, "_ambient", None)
+def test_default_is_checkout_xla_cache(monkeypatch):
     monkeypatch.delenv(compile_cache.ENV_VAR, raising=False)
     assert compile_cache.resolve_cache_dir(None) \
         == os.path.join(REPO, ".xla_cache")
 
 
-def test_pinned_ambient_followed_by_flagless(cache_module_state, monkeypatch):
-    """The harness's pin wins over the repo default for flag-less runs —
-    including a pinned 'no cache' (what this very suite relies on)."""
-    monkeypatch.delenv(compile_cache.ENV_VAR, raising=False)
-    monkeypatch.setattr(compile_cache, "_pinned", True)
-    monkeypatch.setattr(compile_cache, "_ambient", ("/pinned/dir", 1.0, 2))
-    assert compile_cache.resolve_cache_dir(None) == "/pinned/dir"
-    monkeypatch.setattr(compile_cache, "_ambient", (None, 1.0, 2))
-    assert compile_cache.resolve_cache_dir(None) is None
-    # An explicit flag still overrides the pin.
-    assert compile_cache.resolve_cache_dir("/flag/dir") == "/flag/dir"
+def test_configure_keeps_env_dir(cache_module_state, monkeypatch, tmp_path):
+    """With the variable set, configure() activates THAT directory
+    whatever the flag says, and zeroes the thresholds (explicit dir:
+    cache every program)."""
+    env_dir = tmp_path / "from_env"
+    monkeypatch.setenv(compile_cache.ENV_VAR, str(env_dir))
+    got = compile_cache.configure(str(tmp_path / "from_flag"))
+    assert got == str(env_dir) and env_dir.is_dir()
+    assert compile_cache.active_cache_dir() == str(env_dir)
+    assert not (tmp_path / "from_flag").exists()
+    assert jax.config.jax_persistent_cache_min_compile_time_secs == 0.0
 
 
 def test_configure_creates_dir_once(cache_module_state, tmp_path):
@@ -117,6 +114,21 @@ def test_compile_log_counts_backend_compiles():
     assert rec["wall_ms"] >= rec["backend_compile_ms"] * 0.5
     # Persistent cache is off in-process: hit/miss must be None, not False.
     assert rec["persistent_cache_hit"] is None
+
+
+def test_compile_log_close_detaches_listeners():
+    """close() unregisters through the public jax.monitoring API: a closed
+    log no longer hears compiles, and closing twice is harmless."""
+    log = CompileLog()
+    spec = jax.ShapeDtypeStruct((4,), np.float32)
+    with log.measure("heard"):
+        jax.jit(lambda x: x * 3 + 1).lower(spec).compile()
+    log.close()
+    heard = log.stats()["totals"]["backend_compiles"]
+    assert heard >= 1
+    jax.jit(lambda x: x * 5 - 1).lower(spec).compile()
+    assert log.stats()["totals"]["backend_compiles"] == heard
+    log.close()
 
 
 def test_compile_log_thread_attribution():
@@ -208,7 +220,7 @@ def _build_trainer(mode="scan", gather="host", seed=0):
 
 def _count_backend_compiles(fn):
     """Backend-compile events fired while ``fn()`` runs on THIS thread."""
-    from jax._src import monitoring
+    from jax import monitoring
 
     events = []
 
@@ -220,7 +232,7 @@ def _count_backend_compiles(fn):
     try:
         fn()
     finally:
-        monitoring._unregister_event_duration_listener_by_callback(listener)
+        monitoring.unregister_event_duration_listener(listener)
     return len(events)
 
 
@@ -329,23 +341,21 @@ def test_cli_and_bench_share_cache_wiring(cache_module_state, monkeypatch,
     wiring — both route through utils/compile_cache.configure, no
     duplicated config-update code.
 
-    configure is stubbed to RECORD without applying: actually enabling
-    the persistent cache inside the pytest process is the exact
-    read-after-write hazard conftest disables it for (an earlier version
-    of this test applied it for real and planted a heap corruption that
-    detonated two test files later). The application side is covered by
-    test_configure_creates_dir_once (no jit compiles while enabled) and
-    the subprocess tests below."""
+    configure is stubbed to RECORD without applying (the suite runs with
+    the cache off); the application side is covered by
+    test_configure_creates_dir_once / test_configure_keeps_env_dir and the
+    subprocess tests below."""
     calls = []
     monkeypatch.setattr(compile_cache, "configure",
                         lambda flag=None: calls.append(flag) or flag)
 
     # bench side: configure_jax is the prologue every bench child runs.
+    # It names no directory of its own — no flag reaches configure(), so
+    # JAX_COMPILATION_CACHE_DIR (or the checkout default) decides.
     import bench
 
-    monkeypatch.setenv("BENCH_COMPILE_CACHE", str(tmp_path / "bench"))
-    bench.configure_jax(jax, force_cpu=True)
-    assert calls == [str(tmp_path / "bench")]
+    bench.configure_jax()
+    assert calls == [None]
 
     # cli side: run() passes its --compile-cache flag to the same function.
     from pytorch_distributed_mnist_tpu.cli import build_parser, run
@@ -419,7 +429,7 @@ def test_warm_second_run_recompiles_zero_programs(tmp_path):
 
 def test_compile_report_renders_stats(tmp_path, capsys):
     """tools/compile_report.py renders the compile_stats of bench-style
-    artifacts (top-level and watcher-captured) and exits nonzero when no
+    lines and --metrics-file run_summary rows, and exits nonzero when no
     block exists."""
     sys.path.insert(0, os.path.join(REPO, "tools"))
     import compile_report
@@ -433,24 +443,27 @@ def test_compile_report_renders_stats(tmp_path, capsys):
     direct = tmp_path / "bench.json"
     direct.write_text(json.dumps({
         "metric": "m", "backend": "tpu", "compile_stats": stats}) + "\n")
-    nested = tmp_path / "watcher.json"
-    nested.write_text(json.dumps({
-        "captured": {"compile_stats": stats}, "backend": "cpu"}) + "\n")
+    summary = tmp_path / "metrics.jsonl"
+    summary.write_text(
+        json.dumps({"epoch": 0, "train_loss": 1.0}) + "\n"
+        + json.dumps({"kind": "run_summary", "platform": "tpu",
+                      "compile_stats": stats}) + "\n")
     empty = tmp_path / "old.json"
     empty.write_text(json.dumps({"metric": "m", "value": 1.0}) + "\n")
 
-    assert compile_report.main([str(direct), str(nested)]) == 0
+    assert compile_report.main([str(direct), str(summary)]) == 0
     out = capsys.readouterr().out
-    assert out.count("train_epoch") == 2
+    assert out.count("train_epoch") == 2 and "run_summary [tpu]" in out
     assert "miss" in out
     assert compile_report.main([str(empty)]) == 1
+    assert compile_report.main([]) == 1
 
 
 def test_bench_output_contains_compile_stats_block(tmp_path):
     """Acceptance: bench.py child output carries the compile_stats block
     with per-program compile ms and cache hit/miss."""
-    env = dict(os.environ, BENCH_FORCE_CPU="1", BENCH_PROBE="1",
-               BENCH_COMPILE_CACHE=str(tmp_path / "cache"))
+    env = dict(os.environ, BENCH_FORCE_CPU="1",
+               JAX_COMPILATION_CACHE_DIR=str(tmp_path / "cache"))
     proc = subprocess.run(
         [sys.executable, os.path.join(REPO, "bench.py"), "--child", "1", "1"],
         capture_output=True, text=True, timeout=600, env=env, cwd=REPO)
@@ -458,6 +471,8 @@ def test_bench_output_contains_compile_stats_block(tmp_path):
             if l.strip().startswith("{")][-1]
     result = json.loads(line)
     assert result["ok"], result
+    # The child names the directory the standard variable placed.
+    assert result["compile_cache"] == str(tmp_path / "cache")
     stats = result["compile_stats"]
     rec = stats["programs"]["train_step"]
     assert rec["wall_ms"] > 0

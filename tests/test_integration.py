@@ -159,7 +159,9 @@ def test_synthetic_tag_on_epoch_lines_and_metrics(tmp_path, capsys):
     import json
 
     rows = [json.loads(l) for l in mf.read_text().splitlines()]
-    assert rows and all(r["dataset"] == "synthetic" for r in rows)
+    epoch_rows = [r for r in rows if "epoch" in r]
+    assert epoch_rows and all(
+        r["dataset"] == "synthetic" for r in epoch_rows)
 
 
 def test_explicit_synthetic_needs_no_flag_and_is_tagged(tmp_path, capsys):
@@ -199,27 +201,54 @@ def test_debug_nans_flag(tmp_path):
 
 
 def test_metrics_file(tmp_path):
-    """--metrics-file appends one JSON line per epoch (SURVEY section 5)."""
+    """--metrics-file appends one JSON line per epoch (SURVEY section 5),
+    then the run_summary row: the machine-readable account of what the run
+    ran on (jax's own platform/device_kind/count), which host input path
+    fed it, and how its Pallas calls were lowered."""
     import json
+
+    import jax
 
     from pytorch_distributed_mnist_tpu.cli import build_parser, run
 
     mf = tmp_path / "metrics.jsonl"
-    run(build_parser().parse_args([
-        "--dataset", "synthetic", "--model", "linear", "--epochs", "2",
+    common = [
+        "--dataset", "synthetic", "--model", "linear",
         "--batch-size", "64", "--synthetic-train-size", "128",
         "--synthetic-test-size", "64", "--seed", "0",
         "--metrics-file", str(mf),
         "--checkpoint-dir", str(tmp_path / "ckpt"),
         "--root", str(tmp_path / "data"),
-    ]))
+    ]
+    returned = run(build_parser().parse_args(common + ["--epochs", "2"]))
     lines = [json.loads(l) for l in mf.read_text().splitlines()]
-    assert len(lines) == 2
+    assert len(lines) == 3
     assert lines[0]["epoch"] == 0 and lines[1]["epoch"] == 1
-    for row in lines:
+    for row in lines[:2]:
         for key in ("train_loss", "test_acc", "lr", "best_acc",
                     "images_per_sec"):
             assert key in row
+    summary = lines[2]
+    assert summary["kind"] == "run_summary" and summary["epochs_run"] == 2
+    device = jax.devices()[0]
+    assert (summary["platform"], summary["device_kind"],
+            summary["device_count"]) \
+        == (device.platform, device.device_kind, jax.device_count())
+    assert summary["input_backend"] in ("native", "numpy")
+    assert set(summary["pallas_lowerings"]) == {"mosaic", "interpret"}
+    assert "train_epoch" in summary["compile_stats"]["programs"]
+    assert "history" not in summary  # the epoch rows above already say it
+    for key in ("platform", "device_kind", "device_count"):
+        assert returned[key] == summary[key]
+
+    # -e --resume writes its own summary row (no epoch row).
+    run(build_parser().parse_args(common + [
+        "-e", "--resume", str(tmp_path / "ckpt" / "checkpoint_1.npz")]))
+    evaluated = json.loads(mf.read_text().splitlines()[-1])
+    assert evaluated["kind"] == "run_summary"
+    assert evaluated["epochs_run"] == 0 and evaluated["start_epoch"] == 2
+    assert evaluated["platform"] == device.platform
+    assert "evaluate" in evaluated["compile_stats"]["programs"]
 
 
 def test_compile_cache_populated(tmp_path):

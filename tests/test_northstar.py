@@ -1,11 +1,10 @@
-"""tools/northstar.py producer smoke (hermetic, CPU).
+"""tools/northstar.py harness test (hermetic, CPU, BENCH_FORCE_CPU=1).
 
-The north-star runner is a watcher-capture producer: a latent bug in it
-surfaces only during a rare chip-recovery window and burns the capture
-(the round-3 kernels postmortem class). These tests pin its JSON-line
-contract, the honest dataset labelling, and the round-5 --epoch-gather
-flag plumbing (host default, device selectable, identical trajectory)
-on tiny CPU shapes so the on-chip run only ever measures.
+A latent bug in the north-star runner would otherwise surface only in a
+chip run and waste it. These tests pin its JSON-line contract, the honest
+dataset labelling, and the --epoch-gather flag plumbing (host default,
+device selectable, identical trajectory) on tiny CPU shapes so the
+on-chip run only ever measures.
 """
 
 import json
@@ -26,7 +25,8 @@ _TINY = [
 
 
 def _run(tmp_path, extra=()):
-    env = dict(os.environ, JAX_PLATFORMS="cpu", BENCH_COMPILE_CACHE="")
+    env = dict(os.environ, JAX_PLATFORMS="cpu", BENCH_FORCE_CPU="1",
+               JAX_COMPILATION_CACHE_DIR="")
     proc = subprocess.run(
         [sys.executable, _NORTHSTAR, "--root", str(tmp_path / "data")]
         + _TINY + list(extra),
@@ -40,7 +40,7 @@ def _run(tmp_path, extra=()):
 @pytest.mark.slow
 def test_northstar_json_contract_and_labelling(tmp_path):
     out = _run(tmp_path)
-    # The fields BASELINE.md transcription and the watcher gates rely on.
+    # The fields a reader of the line relies on.
     assert out["target_acc"] == 0.99
     assert isinstance(out["reached"], bool)
     assert out["backend"] == "cpu"

@@ -1,8 +1,9 @@
 """Pallas kernels vs their XLA/optax oracles (interpret mode on CPU).
 
-Every kernel runs in interpreter mode off-TPU (the kernels gate on
-``jax.default_backend()``), so these tests exercise the identical kernel
-bodies that compile on real chips.
+Every kernel runs in interpreter mode on the CPU backend
+(``ops/pallas/backend.py`` decides, from ``jax.default_backend()``), so
+these tests exercise the identical kernel bodies that compile on real
+chips.
 """
 
 import jax
@@ -24,7 +25,50 @@ from pytorch_distributed_mnist_tpu.models import get_model
 from pytorch_distributed_mnist_tpu.train.steps import make_train_step
 
 
+# ------------------------------------------------------- lowering decision
+
+def test_should_interpret_cpu_true_tpu_false_else_error(monkeypatch):
+    """Interpret only on ``cpu``; ``tpu`` lowers through Mosaic; any other
+    platform is an error, never a quiet interpret. Each decision is
+    counted for the run summary / /healthz."""
+    from pytorch_distributed_mnist_tpu.ops.pallas.backend import (
+        should_interpret,
+    )
+    from pytorch_distributed_mnist_tpu.utils.profiling import (
+        pallas_lowerings,
+    )
+
+    before = pallas_lowerings.snapshot()
+    assert jax.default_backend() == "cpu" and should_interpret() is True
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert should_interpret() is False
+    after = pallas_lowerings.snapshot()
+    assert after == {"mosaic": before["mosaic"] + 1,
+                     "interpret": before["interpret"] + 1}
+    monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+    with pytest.raises(RuntimeError, match="'gpu'"):
+        should_interpret()
+    assert pallas_lowerings.snapshot() == after
+
+
 # ---------------------------------------------------------------- fused adam
+
+def test_pallas_adam_shard_maps_its_kernel_on_a_multi_device_mesh(mesh8):
+    """GSPMD cannot partition a Mosaic kernel (real multi-chip lowering
+    refuses it; the CPU interpreter never sees that rule), so with a mesh
+    of more than one device the update wraps each leaf's kernel in a
+    shard_map — same numbers, and no wrapper without a mesh."""
+    params = {"w": jnp.ones((16, 8)), "b": jnp.zeros((8,))}
+    grads = jax.tree.map(lambda p: jnp.full_like(p, 0.25), params)
+    plain, meshed = pallas_adam(1e-3), pallas_adam(1e-3, mesh=mesh8)
+    state = plain.init(params)
+    assert "shard_map" not in str(jax.make_jaxpr(plain.update)(grads, state))
+    assert "shard_map" in str(jax.make_jaxpr(meshed.update)(grads, state))
+    want, _ = plain.update(grads, state)
+    got, _ = jax.jit(meshed.update)(grads, state)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
 
 @pytest.mark.parametrize("shape", [(7,), (32, 10), (3, 3, 8, 5), ()])
 def test_fused_adam_leaf_matches_optax(shape):

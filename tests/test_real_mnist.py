@@ -1,10 +1,9 @@
 """Real-MNIST integration: activates only when the actual IDX files exist.
 
-This environment has zero egress (both documented mirrors fail DNS — the
-exact error is recorded in BASELINE.md per round), so these tests are
-skipped here; in any environment where `data/mnist/` holds the real files
-(hand-placed or downloaded), they run automatically and pin the claim the
-synthetic proxy cannot: the CNN reaches real-MNIST accuracy.
+This environment has zero egress (both documented mirrors fail DNS), so
+these tests are skipped here; in any environment where `data/mnist/` holds
+the real files (hand-placed or downloaded), they run automatically and pin
+the claim the synthetic proxy cannot: the CNN reaches real-MNIST accuracy.
 
 Ref contrast: the reference's default path downloads and trains on the
 real dataset (`/root/reference/multi_proc_single_gpu.py:137-138`,
@@ -30,8 +29,7 @@ _REAL_ROOT = next(
 
 pytestmark = pytest.mark.skipif(
     _REAL_ROOT is None,
-    reason="real MNIST IDX files not present (zero-egress environment; "
-           "see BASELINE.md for the recorded download failure)",
+    reason="real MNIST IDX files not present (zero-egress environment)",
 )
 
 

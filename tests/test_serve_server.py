@@ -100,6 +100,13 @@ def test_predict_healthz_stats(server):
 
     health = srv.get("/healthz")
     assert health["ok"] and health["model_epoch"] == 0
+    # What answered, in jax's own words — clients assert the device here.
+    device = jax.devices()[0]
+    assert (health["platform"], health["device_kind"],
+            health["device_count"]) \
+        == (device.platform, device.device_kind, jax.device_count())
+    assert health["input_backend"] in ("native", "numpy")
+    assert set(health["pallas_lowerings"]) == {"mosaic", "interpret"}
 
     reply = srv.post("/predict", {"images": images.tolist()})
     assert len(reply["predictions"]) == 5

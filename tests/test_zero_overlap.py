@@ -255,8 +255,17 @@ def test_cli_zero_overlap_matches_propagation(tmp_path):
     for h_base, h_ov in zip(base["history"], ov["history"]):
         np.testing.assert_allclose(h_base["train_loss"], h_ov["train_loss"],
                                    rtol=1e-4)
+        # One evaluation sample of slack (the synthetic test split has
+        # 128). The two schedules sum gradients in different orders, and
+        # in Adam's first steps an element whose gradient is ~0 takes a
+        # +-lr step whose SIGN is rounding noise: measured on jax 0.9.0
+        # (PR 21), the two runs' kernels differ by up to 2e-3 = 2 x lr
+        # after 8 steps and their bf16 logits by one ulp (7.8e-3), which
+        # flips one near-tied argmax (19 vs 20 of 128 correct) while the
+        # losses agree to 7e-5. Step-level gradient equality is pinned
+        # tightly by the tests above; this one pins the driver.
         np.testing.assert_allclose(h_base["test_acc"], h_ov["test_acc"],
-                                   rtol=1e-6)
+                                   atol=1.0 / 128 + 1e-9)
 
 
 @pytest.mark.slow

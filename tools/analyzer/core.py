@@ -124,37 +124,19 @@ class AnalysisResult:
 # ---------------------------------------------------------------------------
 
 _SKIP_DIRS = {"__pycache__", ".git", ".xla_cache", "node_modules"}
-#: Repo-relative directories to skip. Matching these by bare name (the way
-#: _SKIP_DIRS works) would silently drop any same-named SOURCE directory
-#: added anywhere in the tree while the gate still reports OK — so they
-#: are rooted, like the ruff exclude.
-_SKIP_ROOTED = {"tools/captured"}
-
-
-def _skip_dir(dirpath: str, name: str, root_cache: Dict) -> bool:
-    if name in _SKIP_DIRS:
-        return True
-    root = find_repo_root(dirpath, root_cache)
-    if root is None:
-        return False
-    rel = os.path.relpath(os.path.join(os.path.abspath(dirpath), name),
-                          root).replace(os.sep, "/")
-    return rel in _SKIP_ROOTED
 
 
 def collect_files(paths: Sequence[str]) -> Tuple[List[str], List[Finding]]:
     """Expand files/directories into a sorted, de-duplicated .py list."""
     files: List[str] = []
     problems: List[Finding] = []
-    root_cache: Dict[str, Optional[str]] = {}
     for p in paths:
         if os.path.isfile(p):
             files.append(p)
         elif os.path.isdir(p):
             for dirpath, dirnames, names in os.walk(p):
                 dirnames[:] = sorted(
-                    d for d in dirnames
-                    if not _skip_dir(dirpath, d, root_cache))
+                    d for d in dirnames if d not in _SKIP_DIRS)
                 for name in sorted(names):
                     if name.endswith(".py"):
                         files.append(os.path.join(dirpath, name))

@@ -227,16 +227,27 @@ def _say(msg: str) -> None:
     print(f"chaos: {msg}", file=sys.stderr, flush=True)
 
 
+def _force_cpu_devices(env: dict, cpu_devices: int) -> None:
+    """``--cpu-devices N``: the twin's server runs on N virtual CPU
+    devices — a CPU simulation of an N-chip host, announced on stdout
+    so its numbers are never read as a chip's."""
+    if not cpu_devices:
+        return
+    env["JAX_PLATFORMS"] = "cpu"
+    flags = re.sub(r"--xla_force_host_platform_device_count=\d+", "",
+                   env.get("XLA_FLAGS", "")).strip()
+    env["XLA_FLAGS"] = (flags + " --xla_force_host_platform_device_"
+                        f"count={cpu_devices}").strip()
+    print(f"chaos: CPU simulation — the server runs on {cpu_devices} "
+          f"virtual CPU device(s) (JAX_PLATFORMS=cpu); no accelerator is "
+          f"used", flush=True)
+
+
 def _serve_env(args) -> dict:
     """Environment for a serve-twin subprocess (CPU device forcing +
     unbuffered + repo on path)."""
     env = dict(os.environ)
-    if args.cpu_devices:
-        env["JAX_PLATFORMS"] = "cpu"
-        flags = re.sub(r"--xla_force_host_platform_device_count=\d+", "",
-                       env.get("XLA_FLAGS", "")).strip()
-        env["XLA_FLAGS"] = (flags + " --xla_force_host_platform_device_"
-                            f"count={args.cpu_devices}").strip()
+    _force_cpu_devices(env, args.cpu_devices)
     env["PYTHONUNBUFFERED"] = "1"
     env["PYTHONPATH"] = _REPO + os.pathsep + env.get("PYTHONPATH", "")
     return env
@@ -684,12 +695,7 @@ def run_serve_chaos(args) -> int:
         env[CANARY_FAULT_ENV] = "disagree"
     else:
         env.pop(CANARY_FAULT_ENV, None)
-    if args.cpu_devices:
-        env["JAX_PLATFORMS"] = "cpu"
-        flags = re.sub(r"--xla_force_host_platform_device_count=\d+", "",
-                       env.get("XLA_FLAGS", "")).strip()
-        env["XLA_FLAGS"] = (flags + " --xla_force_host_platform_device_"
-                            f"count={args.cpu_devices}").strip()
+    _force_cpu_devices(env, args.cpu_devices)
     env["PYTHONUNBUFFERED"] = "1"
     env["PYTHONPATH"] = _REPO + os.pathsep + env.get("PYTHONPATH", "")
 

@@ -1,30 +1,24 @@
-"""Print the per-program compile_stats from bench/northstar artifacts.
+"""Print the per-program compile_stats from run artifacts.
 
 The compile-latency subsystem (utils/compile_cache.py + the trainer's AOT
 precompile) records, for every program, its compile wall ms, how many real
 XLA backend compiles ran, and whether the persistent cache served it. That
 lands in:
 
-- ``bench.py`` output lines (``compile_stats`` block) -> ``BENCH_r*.json``
-  and the watcher's ``tools/captured/bench.json``;
-- ``tools/northstar.py`` output (``compile_stats`` + ``compile_cache``);
-- any JSON file a caller passes explicitly.
+- ``bench.py`` output lines (``compile_stats`` block);
+- the ``run_summary`` row of a training run's ``--metrics-file``;
+- ``tools/northstar.py`` output (``compile_stats`` + ``compile_cache``).
 
-This tool renders those blocks as a cold-vs-warm table so the watcher
-scripts can capture a human-readable compile report the moment the chip
-window opens (ISSUE satellite), and so round-over-round BENCH artifacts
-can be compared at a glance.
+This tool renders those blocks as a cold-vs-warm table.
 
 Usage:
-  python tools/compile_report.py            # newest BENCH_r*.json + capture
-  python tools/compile_report.py FILE...    # specific artifact file(s)
+  python tools/compile_report.py FILE...    # artifact file(s), JSON lines
 
 Exit status: 0 if at least one compile_stats block was found, else 1.
 """
 
 from __future__ import annotations
 
-import glob
 import json
 import os
 import sys
@@ -54,24 +48,11 @@ def _load_lines(path: str):
 
 
 def _find_stats(obj: dict):
-    """The compile_stats block of an artifact line, wherever it lives
-    (top level for bench/northstar; nested under ``captured`` for a
-    watcher pass-through)."""
-    for holder in (obj, obj.get("captured") or {}):
-        stats = holder.get("compile_stats")
-        if isinstance(stats, dict) and isinstance(
-                stats.get("programs"), dict):
-            return stats
+    """The compile_stats block of an artifact line, or None."""
+    stats = obj.get("compile_stats")
+    if isinstance(stats, dict) and isinstance(stats.get("programs"), dict):
+        return stats
     return None
-
-
-def default_artifacts():
-    benches = sorted(glob.glob(os.path.join(REPO, "BENCH_r*.json")))
-    paths = benches[-1:] if benches else []
-    captured = os.path.join(REPO, "tools", "captured", "bench.json")
-    if os.path.exists(captured):
-        paths.append(captured)
-    return paths
 
 
 def report(paths) -> int:
@@ -82,9 +63,10 @@ def report(paths) -> int:
             if stats is None:
                 continue
             found += 1
-            label = obj.get("metric") or obj.get("target_acc") or "run"
-            backend = obj.get("backend", "?")
-            when = obj.get("measured_at") or obj.get("capture_timestamp", "")
+            label = (obj.get("metric") or obj.get("kind")
+                     or obj.get("target_acc") or "run")
+            backend = obj.get("backend") or obj.get("platform", "?")
+            when = obj.get("measured_at", "")
             print(f"\n{os.path.relpath(path, REPO)} — {label} "
                   f"[{backend}] {when}")
             print(f"  {'program':<24} {'compile ms':>10} {'XLA':>4} "
@@ -111,7 +93,8 @@ def report(paths) -> int:
 def main(argv=None) -> int:
     paths = list(sys.argv[1:] if argv is None else argv)
     if not paths:
-        paths = default_artifacts()
+        print(__doc__, file=sys.stderr)
+        return 1
     return report(paths)
 
 

@@ -3,19 +3,16 @@
 Hypothesis under test: at long T a small fixed tile (128) turns the
 flash kernel into many small fori_loop matmuls per q-block, which may
 lose to one huge fused XLA matmul while the (T, T) scores still fit
-HBM comfortably — larger tiles amortize better. The round-3 capture
-that first suggested a T=4096 regression was INVALIDATED (its sync
-returned before execution; see BASELINE.md and
-tools/captured/kernels_r3_invalid.json), so no flash-vs-dense ratio is
-currently established either way. ``flash_attention(block=...)``
+HBM comfortably — larger tiles amortize better. No flash-vs-dense ratio
+is established either way: not measured on today's code (ROADMAP S3). ``flash_attention(block=...)``
 exposes the tile edge; this sweep measures fwd+bwd wall-clock per
 (T, block) pair against the dense path so ``_block_sizes``'s heuristic
 becomes a measured choice (the hermetic suite pins numerics for
 non-default blocks — tests/test_pallas_kernels.py
 ``test_flash_attention_block_override``).
 
-Prints ONE JSON line; run on chip (tools/tpu_watch_r4.sh invokes it,
-publication gated on exit code — a physically impossible row exits 1).
+Prints ONE JSON line; runs on the chip (with no TPU it exits non-zero; a
+physically impossible row exits 1).
 """
 
 from __future__ import annotations
@@ -41,7 +38,7 @@ def main() -> None:
     import jax
     import jax.numpy as jnp
 
-    from bench import _peak_flops, configure_jax
+    from bench import _peak_flops, _require_tpu, configure_jax
     from bench_kernels import (
         MeasurementInvalid,
         _fake_bounds,
@@ -51,8 +48,9 @@ def main() -> None:
     from pytorch_distributed_mnist_tpu.ops.attention import full_attention
     from pytorch_distributed_mnist_tpu.ops.pallas.flash import flash_attention
 
-    configure_jax(jax)
+    configure_jax()
     device = jax.devices()[0]
+    _require_tpu(device.platform)
     peak = _peak_flops(device.device_kind)
     fakes = _fake_bounds()
     if fakes and device.platform == "tpu":
@@ -64,7 +62,7 @@ def main() -> None:
         sys.exit(1)
 
     # Same constant ~8k-token budget as bench_kernels.py so rows are
-    # directly comparable with the re-captured kernels.json.
+    # directly comparable.
     configs = [(64, 2)] if args.quick else [(1024, 8), (2048, 4), (4096, 2)]
     blocks = [32] if args.quick else [128, 256, 512]
     heads, dim = (2, 16) if args.quick else (8, 128)
