@@ -25,6 +25,7 @@ from __future__ import annotations
 from typing import Callable, Optional
 
 import flax.linen as nn
+import jax
 import jax.numpy as jnp
 
 from pytorch_distributed_mnist_tpu.models.registry import register_model
@@ -94,11 +95,13 @@ class TransformerBlock(nn.Module):
             dot_general=self.dot_general, name="attn"
         )(y)
         y = nn.LayerNorm(dtype=self.compute_dtype, name="ln2")(x)
-        y = nn.Dense(self.mlp_ratio * c, dtype=self.compute_dtype,
-                     dot_general=self.dot_general, name="mlp1")(y)
-        y = nn.gelu(y)
-        y = nn.Dense(c, dtype=self.compute_dtype,
-                     dot_general=self.dot_general, name="mlp2")(y)
+        # nn.gelu is a function, with no scope of its own in a profile.
+        with jax.named_scope("mlp"):
+            y = nn.Dense(self.mlp_ratio * c, dtype=self.compute_dtype,
+                         dot_general=self.dot_general, name="mlp1")(y)
+            y = nn.gelu(y)
+            y = nn.Dense(c, dtype=self.compute_dtype,
+                         dot_general=self.dot_general, name="mlp2")(y)
         return x + y
 
 

@@ -20,10 +20,15 @@ from __future__ import annotations
 
 from typing import NamedTuple, Optional
 
+import jax
 import jax.numpy as jnp
 
 
 NEG_INF = -1e30  # softmax mask value; avoids -inf NaN propagation in exp
+
+# Every ``attention_fn`` runs under this scope, so a profile separates the
+# attention core from the qkv and output projections whichever one is in.
+CORE_SCOPE = "attn_core"
 
 
 def full_attention(
@@ -42,22 +47,23 @@ def full_attention(
     """
     if scale is None:
         scale = q.shape[-1] ** -0.5
-    qf = q.astype(jnp.float32)
-    kf = k.astype(jnp.float32)
-    # (B, H, Tq, Tk)
-    s = jnp.einsum("bqhd,bkhd->bhqk", qf, kf) * scale
-    if causal:
-        tq, tk = s.shape[-2], s.shape[-1]
-        mask = jnp.tril(jnp.ones((tq, tk), bool), k=tk - tq)
-        s = jnp.where(mask, s, NEG_INF)
-    p = jnp.exp(s - jnp.max(s, axis=-1, keepdims=True))
-    if causal:
-        # A fully-masked row (possible when Tq > Tk) must output zeros, not
-        # the uniform mean of V — match the blockwise op's guard below.
-        p = jnp.where(mask, p, 0.0)
-    p = p / jnp.maximum(jnp.sum(p, axis=-1, keepdims=True), 1e-30)
-    o = jnp.einsum("bhqk,bkhd->bqhd", p, v.astype(jnp.float32))
-    return o.astype(q.dtype)
+    with jax.named_scope(CORE_SCOPE):
+        qf = q.astype(jnp.float32)
+        kf = k.astype(jnp.float32)
+        # (B, H, Tq, Tk)
+        s = jnp.einsum("bqhd,bkhd->bhqk", qf, kf) * scale
+        if causal:
+            tq, tk = s.shape[-2], s.shape[-1]
+            mask = jnp.tril(jnp.ones((tq, tk), bool), k=tk - tq)
+            s = jnp.where(mask, s, NEG_INF)
+        p = jnp.exp(s - jnp.max(s, axis=-1, keepdims=True))
+        if causal:
+            # A fully-masked row (possible when Tq > Tk) must output zeros, not
+            # the uniform mean of V — match the blockwise op's guard below.
+            p = jnp.where(mask, p, 0.0)
+        p = p / jnp.maximum(jnp.sum(p, axis=-1, keepdims=True), 1e-30)
+        o = jnp.einsum("bhqk,bkhd->bqhd", p, v.astype(jnp.float32))
+        return o.astype(q.dtype)
 
 
 class OnlineSoftmaxState(NamedTuple):

@@ -38,7 +38,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from pytorch_distributed_mnist_tpu.ops.attention import NEG_INF
+from pytorch_distributed_mnist_tpu.ops.attention import CORE_SCOPE, NEG_INF
 from pytorch_distributed_mnist_tpu.ops.pallas.backend import should_interpret
 
 __all__ = ["flash_attention", "sharded_flash_attention"]
@@ -386,7 +386,8 @@ def flash_attention(q, k, v, *, causal: bool = False,
             f"beyond that), got {block}")
     if scale is None:
         scale = q.shape[-1] ** -0.5
-    return _flash(q, k, v, causal, float(scale), block)
+    with jax.named_scope(CORE_SCOPE):
+        return _flash(q, k, v, causal, float(scale), block)
 
 
 def sharded_flash_attention(q, k, v, *, mesh, batch_axis=None,

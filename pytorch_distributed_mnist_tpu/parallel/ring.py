@@ -38,6 +38,7 @@ from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
 from pytorch_distributed_mnist_tpu.ops.attention import (
+    CORE_SCOPE,
     online_softmax_block,
     online_softmax_finish,
     online_softmax_init,
@@ -120,10 +121,11 @@ def ring_attention(
     fn = partial(
         ring_attention_local, axis_name=axis, causal=causal, scale=scale
     )
-    return jax.shard_map(
-        fn,
-        mesh=mesh,
-        in_specs=(spec, spec, spec),
-        out_specs=spec,
-        check_vma=False,
-    )(q, k, v)
+    with jax.named_scope(CORE_SCOPE):
+        return jax.shard_map(
+            fn,
+            mesh=mesh,
+            in_specs=(spec, spec, spec),
+            out_specs=spec,
+            check_vma=False,
+        )(q, k, v)

@@ -27,7 +27,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from pytorch_distributed_mnist_tpu.ops.attention import full_attention
+from pytorch_distributed_mnist_tpu.ops.attention import CORE_SCOPE, full_attention
 
 
 def ulysses_attention_local(
@@ -86,10 +86,11 @@ def ulysses_attention(
     spec = P(batch_axis, axis, None, None)
     fn = partial(ulysses_attention_local, axis_name=axis, causal=causal,
                  scale=scale, local_attention=local_attention)
-    return jax.shard_map(
-        fn,
-        mesh=mesh,
-        in_specs=(spec, spec, spec),
-        out_specs=spec,
-        check_vma=False,
-    )(q, k, v)
+    with jax.named_scope(CORE_SCOPE):
+        return jax.shard_map(
+            fn,
+            mesh=mesh,
+            in_specs=(spec, spec, spec),
+            out_specs=spec,
+            check_vma=False,
+        )(q, k, v)

@@ -35,8 +35,10 @@ class TrainState:
     tx: optax.GradientTransformation = flax.struct.field(pytree_node=False)
 
     def apply_gradients(self, grads):
-        updates, new_opt_state = self.tx.update(grads, self.opt_state, self.params)
-        new_params = optax.apply_updates(self.params, updates)
+        # The scope names these ops in a profile (README, "Profiling a run").
+        with jax.named_scope("optimizer"):
+            updates, new_opt_state = self.tx.update(grads, self.opt_state, self.params)
+            new_params = optax.apply_updates(self.params, updates)
         return self.replace(step=self.step + 1, params=new_params, opt_state=new_opt_state)
 
     @property

@@ -72,13 +72,16 @@ def _train_step(state, batch, aux_weight: float = 0.0):
     def loss_fn(params):
         logits, aux = _forward_with_aux(
             state, params, batch["image"], aux_weight)
-        ce = cross_entropy(logits, batch["label"], mask)
+        with jax.named_scope("loss"):
+            ce = cross_entropy(logits, batch["label"], mask)
         return ce + aux_weight * aux, (ce, logits)
 
     (_, (loss, logits)), grads = jax.value_and_grad(
         loss_fn, has_aux=True)(state.params)
     new_state = state.apply_gradients(grads)
-    metrics = metrics_update(metrics_init(), loss, logits, batch["label"], mask)
+    with jax.named_scope("loss"):
+        metrics = metrics_update(
+            metrics_init(), loss, logits, batch["label"], mask)
     return new_state, metrics
 
 
@@ -125,15 +128,18 @@ def make_accum_train_step_fn(accum: int, aux_weight: float = 0.0):
                 # per-example SUM: micro-means weighted by real count so
                 # the accumulated gradient equals the full-batch gradient
                 # even when eval-style masks straddle micro-batches.
-                ce_sum = cross_entropy(logits, mb["label"], mask) * n
+                with jax.named_scope("loss"):
+                    ce_sum = cross_entropy(logits, mb["label"], mask) * n
                 return ce_sum + aux_weight * aux * n, (ce_sum, logits)
 
             (_, (loss_sum_mb, logits)), g = jax.value_and_grad(
                 loss_fn, has_aux=True
             )(state.params)
             g_acc = jax.tree_util.tree_map(jnp.add, g_acc, g)
-            loss_mean = loss_sum_mb / jnp.maximum(n, 1.0)
-            m_acc = metrics_update(m_acc, loss_mean, logits, mb["label"], mask)
+            with jax.named_scope("loss"):
+                loss_mean = loss_sum_mb / jnp.maximum(n, 1.0)
+                m_acc = metrics_update(
+                    m_acc, loss_mean, logits, mb["label"], mask)
             return (g_acc, m_acc), None
 
         zeros = jax.tree_util.tree_map(
@@ -307,6 +313,10 @@ def _make_epoch(mesh, axis, state_sharding, step_fn, train, indexed):
         def epoch(state, batches):
             return scan_epoch(state, lambda b: b, batches)
 
+    # The compiled module's name (``jit_train_epoch``) in a profile and in
+    # the cache key: the name Trainer._run_program and CompileLog use.
+    epoch.__name__ = (("train" if train else "eval") + "_epoch"
+                      + ("_indexed" if indexed else ""))
     repl, _ = _shardings(mesh, axis)
     donate = (0,) if train else ()
     if mesh is None:

@@ -57,6 +57,7 @@ from pytorch_distributed_mnist_tpu.train.steps import (
     make_train_step,
     precompile,
 )
+from pytorch_distributed_mnist_tpu.utils.profiling import phase
 
 
 def _meters(ms: Optional[MetricState]) -> Tuple[Average, Accuracy]:
@@ -304,7 +305,8 @@ class Trainer:
 
         def work():
             t0 = time.perf_counter()
-            staged = self.train_loader.stacked_epoch(epoch)
+            with phase("trainer:stack_epoch"):
+                staged = self.train_loader.stacked_epoch(epoch)
             t1 = time.perf_counter()
             holder["batches"] = staged
             # Timings only; the staging log is written at CONSUMPTION
@@ -313,8 +315,9 @@ class Trainer:
             # skews the input-plane story with an epoch nobody used.
             holder["host_ms"] = (t1 - t0) * 1e3
             if jax.process_count() == 1:
-                holder["device_batches"] = make_global_batch(
-                    staged, self.mesh, leading_replicated=True)
+                with phase("trainer:h2d"):
+                    holder["device_batches"] = make_global_batch(
+                        staged, self.mesh, leading_replicated=True)
                 holder["h2d_ms"] = (time.perf_counter() - t1) * 1e3
 
         t = threading.Thread(target=work, daemon=True,
@@ -499,85 +502,88 @@ class Trainer:
             ticks = make_global_batch(
                 {"idx": idx.astype(np.int32), "mask": mask}, self.mesh,
                 leading_replicated=True)
-            self.state, ms = self._run_program(
-                "train_epoch_indexed", self._train_epoch,
-                self.state, self._train_data, ticks)
+            with phase("trainer:dispatch"):
+                self.state, ms = self._run_program(
+                    "train_epoch_indexed", self._train_epoch,
+                    self.state, self._train_data, ticks)
         elif self.mode == "scan":
             staged = None
             batches = None
             prefetched_host_ms = None
-            if self._prefetch is not None:
-                epoch, t, holder = self._prefetch
-                self._prefetch = None
-                t_wait = time.perf_counter()
-                t.join()
-                if self.staging_log is not None:
-                    self.staging_log.record_wait(
-                        (time.perf_counter() - t_wait) * 1e3)
-                if epoch == self.train_loader.sampler.epoch:
-                    staged = holder.get("batches")
-                    if staged is not None:
-                        prefetched_host_ms = holder.get("host_ms")
-                    batches = holder.get("device_batches")
-                    if batches is not None and self.staging_log is not None:
-                        self.staging_log.record_stage(
-                            host_ms=holder["host_ms"],
-                            h2d_ms=holder["h2d_ms"],
-                            images=int(staged["label"].size),
-                            pipelined=True)
-            if batches is None:
-                # No (valid) prefetched device stage: do whatever is
-                # left on the consumer thread — the whole gather on a
-                # cold first epoch, just the H2D in a multi-host world
-                # where the thread staged host-side only.
-                t0 = time.perf_counter()
-                if staged is None:
-                    staged = self.train_loader.stacked_epoch()
-                t1 = time.perf_counter()
-                batches = make_global_batch(
-                    staged, self.mesh, leading_replicated=True
-                )
-                if self.staging_log is not None:
-                    t2 = time.perf_counter()
-                    if prefetched_host_ms is not None:
-                        # Multi-host: the gather DID run on the prefetch
-                        # thread (its real wall, not the ~0 ms of the
-                        # skipped re-gather above); only the H2D was
-                        # inline — the wait below carries exactly that
-                        # un-overlapped part, so the overlap fraction
-                        # credits the hidden host half and nothing else.
-                        self.staging_log.record_stage(
-                            host_ms=prefetched_host_ms,
-                            h2d_ms=(t2 - t1) * 1e3,
-                            images=int(staged["label"].size),
-                            pipelined=True)
-                    else:
-                        self.staging_log.record_stage(
-                            host_ms=(t1 - t0) * 1e3, h2d_ms=(t2 - t1) * 1e3,
-                            images=int(staged["label"].size),
-                            pipelined=False)
-                    self.staging_log.record_wait((t2 - t0) * 1e3)
-            if self._zero_overlap and self._zero_level == 3:
-                # The carried gathered-param copy: step N's tail
-                # allgather rides the scan carry into step N+1's
-                # forward. Derived state (== allgather(state.params)),
-                # rebuilt whenever absent — first epoch, or any outside
-                # state install (the state setter invalidates it).
-                if self._zero_gathered is None:
-                    self._zero_gathered = self._zero_gather(
-                        self.state.params)
-                new_state, gathered, ms = self._run_program(
-                    "train_epoch_zero_overlap", self._train_epoch,
-                    self.state, self._zero_gathered, batches)
-                self._state = new_state  # direct: keep the matching carry
-                self._zero_gathered = gathered
-            elif self._zero_overlap:
-                self.state, ms = self._run_program(
-                    "train_epoch_zero_overlap", self._train_epoch,
-                    self.state, batches)
-            else:
-                self.state, ms = self._run_program(
-                    "train_epoch", self._train_epoch, self.state, batches)
+            with phase("trainer:input_wait"):
+                if self._prefetch is not None:
+                    epoch, t, holder = self._prefetch
+                    self._prefetch = None
+                    t_wait = time.perf_counter()
+                    t.join()
+                    if self.staging_log is not None:
+                        self.staging_log.record_wait(
+                            (time.perf_counter() - t_wait) * 1e3)
+                    if epoch == self.train_loader.sampler.epoch:
+                        staged = holder.get("batches")
+                        if staged is not None:
+                            prefetched_host_ms = holder.get("host_ms")
+                        batches = holder.get("device_batches")
+                        if batches is not None and self.staging_log is not None:
+                            self.staging_log.record_stage(
+                                host_ms=holder["host_ms"],
+                                h2d_ms=holder["h2d_ms"],
+                                images=int(staged["label"].size),
+                                pipelined=True)
+                if batches is None:
+                    # No (valid) prefetched device stage: do whatever is
+                    # left on the consumer thread — the whole gather on a
+                    # cold first epoch, just the H2D in a multi-host world
+                    # where the thread staged host-side only.
+                    t0 = time.perf_counter()
+                    if staged is None:
+                        staged = self.train_loader.stacked_epoch()
+                    t1 = time.perf_counter()
+                    batches = make_global_batch(
+                        staged, self.mesh, leading_replicated=True
+                    )
+                    if self.staging_log is not None:
+                        t2 = time.perf_counter()
+                        if prefetched_host_ms is not None:
+                            # Multi-host: the gather DID run on the prefetch
+                            # thread (its real wall, not the ~0 ms of the
+                            # skipped re-gather above); only the H2D was
+                            # inline — the wait below carries exactly that
+                            # un-overlapped part, so the overlap fraction
+                            # credits the hidden host half and nothing else.
+                            self.staging_log.record_stage(
+                                host_ms=prefetched_host_ms,
+                                h2d_ms=(t2 - t1) * 1e3,
+                                images=int(staged["label"].size),
+                                pipelined=True)
+                        else:
+                            self.staging_log.record_stage(
+                                host_ms=(t1 - t0) * 1e3, h2d_ms=(t2 - t1) * 1e3,
+                                images=int(staged["label"].size),
+                                pipelined=False)
+                        self.staging_log.record_wait((t2 - t0) * 1e3)
+            with phase("trainer:dispatch"):
+                if self._zero_overlap and self._zero_level == 3:
+                    # The carried gathered-param copy: step N's tail
+                    # allgather rides the scan carry into step N+1's
+                    # forward. Derived state (== allgather(state.params)),
+                    # rebuilt whenever absent — first epoch, or any outside
+                    # state install (the state setter invalidates it).
+                    if self._zero_gathered is None:
+                        self._zero_gathered = self._zero_gather(
+                            self.state.params)
+                    new_state, gathered, ms = self._run_program(
+                        "train_epoch_zero_overlap", self._train_epoch,
+                        self.state, self._zero_gathered, batches)
+                    self._state = new_state  # direct: keep the matching carry
+                    self._zero_gathered = gathered
+                elif self._zero_overlap:
+                    self.state, ms = self._run_program(
+                        "train_epoch_zero_overlap", self._train_epoch,
+                        self.state, batches)
+                else:
+                    self.state, ms = self._run_program(
+                        "train_epoch", self._train_epoch, self.state, batches)
             if self.prefetch_enabled:
                 self._start_prefetch()
         else:
@@ -604,7 +610,8 @@ class Trainer:
                     self.state, m = self._run_program(
                         name, self._train_step, self.state, gbatch)
                 ms = m if ms is None else accumulate_metrics(ms, m)
-        return _meters(ms)
+        with phase("trainer:read_metrics"):
+            return _meters(ms)
 
     def evaluate(self) -> Tuple[Average, Accuracy]:
         """One evaluation pass; returns (loss meter, accuracy meter).

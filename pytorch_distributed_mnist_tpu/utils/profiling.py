@@ -937,5 +937,16 @@ def phase(name: str, **kwargs):
     phase — train/eval/checkpoint per epoch. Zero-cost when no trace is
     being captured; inside a ``--profile-dir`` capture the spans label the
     host timeline so the train/eval/checkpoint split is readable in
-    xprof/perfetto instead of one undifferentiated epoch blob."""
+    xprof/perfetto instead of one undifferentiated epoch blob.
+
+    The names in use. ``cli.run``, per epoch: ``train``, ``eval``,
+    ``checkpoint``, ``checkpoint_drain``. ``Trainer.train()`` in scan mode,
+    per pass, on the calling thread: ``trainer:input_wait`` (join of the
+    prefetch thread and whatever staging is left to do inline),
+    ``trainer:dispatch`` (the call of the epoch program, which returns
+    before the device is done), ``trainer:read_metrics`` (the host read
+    that ends the pass); on the prefetch thread, for the next pass:
+    ``trainer:stack_epoch`` (host gather) and ``trainer:h2d`` (sharded
+    ``device_put``). ``benchmark/scopes.py`` puts each idle gap of the
+    device under the innermost ``trainer:`` span that covers it."""
     return jax.profiler.TraceAnnotation(name, **kwargs)
