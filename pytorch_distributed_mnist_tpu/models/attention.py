@@ -15,7 +15,9 @@ tiny for MNIST, but the code path is the same one a long-context model
 takes, just with T larger and the ``seq`` axis sharded wider.
 
 ``attention_fn`` is a static module field: any ``(q, k, v) -> o`` on
-``(B, T, H, D)``. Default is dense ``ops.attention.full_attention``; pass
+``(B, T, H, D)``. Default is dense ``ops.attention.full_attention``
+(matmuls in ``compute_dtype`` accumulated in float32, float32 softmax, a
+hand-written backward: differentiable in reverse mode only); pass
 ``partial(ring_attention, mesh=mesh)`` (or the Ulysses variant) to make
 every block's attention sequence-parallel with no other model change.
 """

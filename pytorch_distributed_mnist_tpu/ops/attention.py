@@ -10,14 +10,22 @@ blockwise kernel here, and the ``vit`` model (``models/attention.py``)
 exercises it end to end.
 
 Layout convention throughout: ``(B, T, H, D)`` — batch, tokens, heads, head
-dim. TPU notes: scores are computed in float32 (softmax is the numerically
-delicate reduction; the MXU matmuls feeding it may be bf16), and the
-blockwise form is exactly the online-softmax recurrence XLA:TPU fuses well —
-no materialized (T, T) matrix bigger than one (T_q_block, T_k_block) tile.
+dim. Precision of the dense op: its matmuls take their operands in the
+type the caller passes (bf16 in the ``vit`` cells, float32 in the CPU
+presets) and accumulate in float32; the scores, the row max, ``exp``, the
+row sum and its log, and the backward's ``delta`` and ``ds`` are float32,
+and the probabilities are cast to the operands' type only where they enter
+a matmul. No float32 input is rounded to a narrower type. The dense op's
+backward is written out (``jax.custom_vjp``), so it reads and writes the
+``(B, H, Tq, Tk)`` scores a few times instead of once per primitive of the
+softmax; the blockwise form is exactly the online-softmax recurrence
+XLA:TPU fuses well — no materialized (T, T) matrix bigger than one
+(T_q_block, T_k_block) tile.
 """
 
 from __future__ import annotations
 
+from functools import partial
 from typing import NamedTuple, Optional
 
 import jax
@@ -29,6 +37,68 @@ NEG_INF = -1e30  # softmax mask value; avoids -inf NaN propagation in exp
 # Every ``attention_fn`` runs under this scope, so a profile separates the
 # attention core from the qkv and output projections whichever one is in.
 CORE_SCOPE = "attn_core"
+
+
+def _masked_scores(q, k, causal, scale):
+    """``scale * q k^T`` in float32, ``(B, H, Tq, Tk)``; what a causal query
+    may not see is ``NEG_INF`` (end-aligned, so ``Tq != Tk`` is allowed)."""
+    s = jnp.einsum(
+        "bqhd,bkhd->bhqk", q, k, preferred_element_type=jnp.float32
+    ) * scale
+    if causal:
+        tq, tk = s.shape[-2], s.shape[-1]
+        mask = jnp.tril(jnp.ones((tq, tk), bool), k=tk - tq)
+        s = jnp.where(mask, s, NEG_INF)
+    return s
+
+
+def _dense_fwd(q, k, v, causal, scale):
+    s = _masked_scores(q, k, causal, scale)
+    m = jnp.max(s, axis=-1, keepdims=True)
+    if causal:
+        # A fully-masked row (possible when Tq > Tk) must output zeros, not
+        # the uniform mean of V: with 0 for its max, every exp(NEG_INF - 0)
+        # of the row is 0, here and in the backward's exp(s - lse).
+        m = jnp.where(m <= NEG_INF / 2, 0.0, m)
+    p = jnp.exp(s - m)
+    l = jnp.maximum(jnp.sum(p, axis=-1, keepdims=True), 1e-30)
+    o = jnp.einsum(
+        "bhqk,bkhd->bqhd", (p / l).astype(v.dtype), v,
+        preferred_element_type=jnp.float32,
+    ).astype(q.dtype)
+    lse = (m + jnp.log(l))[..., 0]  # (B, H, Tq) float32
+    return o, (q, k, v, o, lse)
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _dense_attention(q, k, v, causal, scale):
+    return _dense_fwd(q, k, v, causal, scale)[0]
+
+
+def _dense_bwd(causal, scale, residuals, do):
+    """Closed form of softmax attention's VJP: with ``p = softmax(s)``,
+    ``ds = scale * p * (dp - rowsum(do * o))``. The probabilities are not
+    kept; they are recomputed in float32 from the same operands and the
+    row log-sum-exp, so they are the forward's values."""
+    q, k, v, o, lse = residuals
+    s = _masked_scores(q, k, causal, scale)
+    p = jnp.exp(s - lse[..., None])  # a masked entry is exp(NEG_INF): 0
+    delta = jnp.einsum(
+        "bqhd,bqhd->bhq", do, o, preferred_element_type=jnp.float32)
+    dv = jnp.einsum(
+        "bhqk,bqhd->bkhd", p.astype(do.dtype), do,
+        preferred_element_type=jnp.float32)
+    dp = jnp.einsum(
+        "bqhd,bkhd->bhqk", do, v, preferred_element_type=jnp.float32)
+    ds = (scale * p * (dp - delta[..., None])).astype(q.dtype)
+    dq = jnp.einsum(
+        "bhqk,bkhd->bqhd", ds, k, preferred_element_type=jnp.float32)
+    dk = jnp.einsum(
+        "bhqk,bqhd->bkhd", ds, q, preferred_element_type=jnp.float32)
+    return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
+
+
+_dense_attention.defvjp(_dense_fwd, _dense_bwd)
 
 
 def full_attention(
@@ -43,27 +113,22 @@ def full_attention(
 
     The single-device reference semantics that the ring / Ulysses
     sequence-parallel paths must reproduce exactly (their tests assert
-    allclose against this).
+    allclose against this). ``causal`` is end-aligned (``Tq != Tk``
+    allowed); a row with nothing to attend to gives zeros and zero
+    gradients. ``scale`` defaults to ``D ** -0.5`` and must be a Python
+    number, not a traced value.
+
+    Matmul operands keep ``q.dtype``, accumulation and the softmax are
+    float32 (module docstring). Reverse-mode differentiation takes the
+    closed form of ``_dense_bwd``, which keeps q, k, v, the output and the
+    float32 row log-sum-exp ``(B, H, Tq)`` between the passes and no
+    ``(B, H, Tq, Tk)`` tensor. Forward-mode differentiation (``jax.jvp``,
+    ``jacfwd``, ``linearize``) is not supported: ``jax.custom_vjp`` raises.
     """
     if scale is None:
         scale = q.shape[-1] ** -0.5
     with jax.named_scope(CORE_SCOPE):
-        qf = q.astype(jnp.float32)
-        kf = k.astype(jnp.float32)
-        # (B, H, Tq, Tk)
-        s = jnp.einsum("bqhd,bkhd->bhqk", qf, kf) * scale
-        if causal:
-            tq, tk = s.shape[-2], s.shape[-1]
-            mask = jnp.tril(jnp.ones((tq, tk), bool), k=tk - tq)
-            s = jnp.where(mask, s, NEG_INF)
-        p = jnp.exp(s - jnp.max(s, axis=-1, keepdims=True))
-        if causal:
-            # A fully-masked row (possible when Tq > Tk) must output zeros, not
-            # the uniform mean of V — match the blockwise op's guard below.
-            p = jnp.where(mask, p, 0.0)
-        p = p / jnp.maximum(jnp.sum(p, axis=-1, keepdims=True), 1e-30)
-        o = jnp.einsum("bhqk,bkhd->bqhd", p, v.astype(jnp.float32))
-        return o.astype(q.dtype)
+        return _dense_attention(q, k, v, causal, scale)
 
 
 class OnlineSoftmaxState(NamedTuple):

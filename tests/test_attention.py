@@ -6,6 +6,8 @@ parallel implementations must match it allclose with the token axis sharded
 8 ways. The ViT model trains a few steps and must be finite/learning.
 """
 
+from functools import partial
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -38,12 +40,19 @@ def seq_mesh():
     return make_mesh(("seq",))
 
 
-def _naive(q, k, v, causal=False):
-    s = jnp.einsum("bqhd,bkhd->bhqk", q, k) / np.sqrt(D)
+def _naive(q, k, v, causal=False, scale=None):
+    """Independent softmax attention (``jax.nn.softmax``, plain autodiff) in
+    the inputs' type. Causal masks are end-aligned; a row with nothing to
+    attend to gives zeros."""
+    scale = q.shape[-1] ** -0.5 if scale is None else scale
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k) * scale
     if causal:
-        mask = np.tril(np.ones((T, T), bool))
+        tq, tk = s.shape[-2:]
+        mask = np.tril(np.ones((tq, tk), bool), k=tk - tq)
         s = jnp.where(mask, s, -1e30)
     p = jax.nn.softmax(s, axis=-1)
+    if causal:
+        p = jnp.where(mask.any(axis=-1, keepdims=True), p, 0.0)
     return jnp.einsum("bhqk,bkhd->bqhd", p, v)
 
 
@@ -149,3 +158,125 @@ def test_sharded_flash_matches_dense_on_tp_mesh():
         )
         np.testing.assert_allclose(
             np.asarray(got), np.asarray(want), rtol=2e-5, atol=2e-5)
+
+
+# -- the dense op's closed-form backward (ops/attention.py::_dense_bwd) -------
+
+def _grad_inputs(tq, tk, dtype, seed=11):
+    ks = jax.random.split(jax.random.key(seed), 4)
+    q = jax.random.normal(ks[0], (2, tq, 3, 8), jnp.float32)
+    k, v = (jax.random.normal(kk, (2, tk, 3, 8), jnp.float32) for kk in ks[1:3])
+    w = jax.random.normal(ks[3], (2, tq, 3, 8), jnp.float32)  # the cotangent
+    return tuple(x.astype(dtype) for x in (q, k, v)), w
+
+
+def _weighted(fn, w, **kwargs):
+    """Scalar loss whose gradient is the VJP of ``fn`` against ``w``."""
+    def loss(q, k, v):
+        return jnp.sum(fn(q, k, v, **kwargs).astype(jnp.float32) * w)
+    return loss
+
+
+@pytest.mark.parametrize("scale", [None, 0.37])
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 1e-5),
+                                       (jnp.bfloat16, 2e-2)])
+@pytest.mark.parametrize("tq,tk", [(16, 16), (8, 24), (24, 8)])
+@pytest.mark.parametrize("causal", [False, True])
+def test_full_attention_grads_match_autodiff_of_naive(causal, tq, tk, dtype,
+                                                      tol, scale):
+    """dq, dk, dv of the hand-written VJP against autodiff of ``_naive`` on
+    the same values in float32. bf16: the operands and probabilities enter
+    the matmuls as bf16 (2^-8 relative each), hence the loose tolerance."""
+    (q, k, v), w = _grad_inputs(tq, tk, dtype)
+    out = full_attention(q, k, v, causal=causal, scale=scale)
+    got = jax.grad(_weighted(full_attention, w, causal=causal, scale=scale),
+                   (0, 1, 2))(q, k, v)
+    qf, kf, vf = (x.astype(jnp.float32) for x in (q, k, v))
+    want_out = _naive(qf, kf, vf, causal, scale)
+    want = jax.grad(_weighted(_naive, w, causal=causal, scale=scale),
+                    (0, 1, 2))(qf, kf, vf)
+    assert out.dtype == dtype and all(g.dtype == dtype for g in got)
+    for g, r in zip((out,) + got, (want_out,) + want):
+        g = np.asarray(g.astype(jnp.float32))
+        assert np.isfinite(g).all()
+        np.testing.assert_allclose(g, r, rtol=tol, atol=tol * np.abs(r).max())
+    if causal and tq > tk:
+        # Queries before the first key attend to nothing: zeros out, and
+        # neither they nor anything else gets a gradient through them.
+        dead = tq - tk
+        assert not np.asarray(out[:, :dead].astype(jnp.float32)).any()
+        assert not np.asarray(got[0][:, :dead].astype(jnp.float32)).any()
+
+
+def test_full_attention_grads_under_checkpoint(qkv):
+    """``jax.checkpoint`` replays the custom forward and takes the same
+    backward: gradients equal the unwrapped function's."""
+    q, k, v = qkv
+    w = jnp.cos(jnp.arange(q.size, dtype=jnp.float32)).reshape(q.shape)
+    plain = _weighted(full_attention, w, causal=True)
+    remat = _weighted(jax.checkpoint(partial(full_attention, causal=True)), w)
+    want = jax.grad(_weighted(_naive, w, causal=True), (0, 1, 2))(q, k, v)
+    got = jax.jit(jax.grad(remat, (0, 1, 2)))(q, k, v)
+    for g, p, r in zip(got, jax.grad(plain, (0, 1, 2))(q, k, v), want):
+        np.testing.assert_allclose(g, p, rtol=1e-6, atol=1e-6)
+        np.testing.assert_allclose(g, r, rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("remat", [False, True])
+def test_vit_grads_match_naive_attention(remat):
+    """The default attention inside ``VisionTransformer`` (float32, with and
+    without ``nn.remat`` round each block) against the same parameters with
+    the naive attention passed as ``attention_fn``."""
+    from pytorch_distributed_mnist_tpu.models.attention import (
+        VisionTransformer,
+    )
+
+    kwargs = dict(patch_size=7, embed_dim=32, depth=2, num_heads=4,
+                  compute_dtype=jnp.float32)
+    model = VisionTransformer(remat=remat, **kwargs)
+    naive = VisionTransformer(attention_fn=_naive, **kwargs)
+    x = jax.random.normal(jax.random.key(5), (4, 28, 28, 1), jnp.float32)
+    labels = jnp.arange(4) % 10
+    params = model.init(jax.random.key(6), x)
+
+    def loss(m):
+        def fn(p):
+            logp = jax.nn.log_softmax(m.apply(p, x))
+            return -jnp.mean(logp[jnp.arange(4), labels])
+        return jax.jit(jax.value_and_grad(fn))
+
+    (got_loss, got), (want_loss, want) = loss(model)(params), loss(naive)(params)
+    np.testing.assert_allclose(got_loss, want_loss, rtol=1e-6)
+    flat_want = dict(jax.tree_util.tree_leaves_with_path(want))
+    for path, g in jax.tree_util.tree_leaves_with_path(got):
+        r = flat_want[path]
+        np.testing.assert_allclose(
+            g, r, rtol=1e-4, atol=1e-5 * max(float(np.abs(r).max()), 1e-6),
+            err_msg=jax.tree_util.keystr(path))
+
+
+@pytest.mark.parametrize("causal", [False, True])
+def test_full_attention_keeps_no_softmax_intermediates(causal):
+    """What ``jax.vjp`` keeps for the backward: no mask and at most one
+    float32 tensor the size of the scores (the closed form keeps none; it
+    recomputes the probabilities from q, k and the row log-sum-exp). An
+    edit that falls back to autodiff of the softmax keeps the ``exp``, the
+    normalised probabilities and, when causal, ``pred`` masks, and fails
+    here without a chip."""
+    (q, k, v), _ = _grad_inputs(24, 40, jnp.float32)
+    (b, tq, h, _), tk = q.shape, k.shape[1]
+    _, vjp = jax.vjp(partial(full_attention, causal=causal), q, k, v)
+    kept = jax.tree_util.tree_leaves(vjp)
+    assert kept, "jax.vjp's function no longer shows what it closes over"
+    assert not [x.shape for x in kept if x.dtype == jnp.bool_]
+    score_sized = [x for x in kept if x.size >= b * h * tq * tk]
+    assert len(score_sized) <= 1, [(x.shape, x.dtype) for x in score_sized]
+    assert all(x.shape == (b, h, tq, tk) and x.dtype == jnp.float32
+               for x in score_sized)
+
+
+def test_full_attention_refuses_forward_mode(qkv):
+    """The docstring's promise: a ``custom_vjp`` has no JVP rule."""
+    q, k, v = qkv
+    with pytest.raises(TypeError, match="custom_vjp"):
+        jax.jvp(full_attention, (q, k, v), (q, k, v))
