@@ -11,6 +11,10 @@ Drives the main path once, through the entry points a user would call:
               --patch-size 1`` (784 tokens, width 64, 4 heads of 16) with
               every first-party kernel the CLI can select:
               ``--attention flash --loss fused --optimizer adam_pallas``;
+            * the token model, ``--model laguna --dataset
+              synthetic_tokens`` at its tiny preset on sequences of 1,024
+              tokens (8 steps of 8): window and full attention through
+              the flash kernels, top-4 of 16 experts by grouped matmuls;
   server    python -m pytorch_distributed_mnist_tpu serve      (server ->
             engine -> batcher -> pool) on the checkpoint the trainer just
             wrote, answering ``tools/loadgen.py --smoke`` and a batch of
@@ -60,6 +64,12 @@ DATA = ["--dataset", "synthetic", "--synthetic-train-size", str(TRAIN_IMAGES),
         "--synthetic-test-size", "1024", "--epochs", "1", "--seed", "1"]
 VIT = ["--model", "vit", "--patch-size", "1", "--attention", "flash",
        "--loss", "fused", "--optimizer", "adam_pallas"]
+# Later flags win: the token model's data replaces DATA's.
+LAGUNA_STEPS, LAGUNA_BATCH = 8, 8
+LAGUNA = ["--model", "laguna", "--dataset", "synthetic_tokens",
+          "--seq-len", "1024", "--batch-size", str(LAGUNA_BATCH),
+          "--synthetic-train-size", str(LAGUNA_STEPS * LAGUNA_BATCH),
+          "--synthetic-test-size", "16", "--lr", "1e-3"]
 # The trainer's own warnings that a compiled program was refused or
 # compiled twice (train/trainer.py): legitimate on a user's machine,
 # a failure here.
@@ -174,7 +184,8 @@ class Smoke:
 
     # -- trainer -----------------------------------------------------------
 
-    def train(self, name: str, model_flags: list, kernels: bool) -> dict:
+    def train(self, name: str, model_flags: list, kernels: bool,
+              steps: int = TRAIN_IMAGES // BATCH) -> dict:
         """Train, then ``-e --resume`` the checkpoint it wrote; returns
         the checkpoint dir and the eval it must reproduce."""
         ckpt = os.path.join(self.work, name)
@@ -211,7 +222,7 @@ class Smoke:
             raise SmokeFailure(f"train_{name}: no checkpoint at {saved}")
         programs = summary["compile_stats"]["programs"]
         self.say(f"train_{name}", summary, summary["compile_stats"]["totals"],
-                 f"steps={TRAIN_IMAGES // BATCH} "
+                 f"steps={steps} "
                  f"train_loss={row['train_loss']:.4f} "
                  f"test_acc={row['test_acc']:.4f} programs="
                  + ",".join(f"{p}:{_hit(r)}" for p, r in programs.items()))
@@ -451,6 +462,7 @@ def main() -> int:
     try:
         cnn = smoke.train("cnn", [], kernels=False)
         smoke.train("vit", VIT, kernels=True)
+        smoke.train("laguna", LAGUNA, kernels=True, steps=LAGUNA_STEPS)
         ref = smoke.reference(cnn)
         smoke.serve(cnn, ref, "f32")
         smoke.serve(cnn, ref, "int8")

@@ -139,7 +139,14 @@ def build_parser() -> argparse.ArgumentParser:
                         "sequence parallelism are library APIs, see "
                         "parallel/ring.py)")
     p.add_argument("--dataset", type=str, default="mnist",
-                   choices=["mnist", "fashion_mnist", "synthetic"])
+                   choices=["mnist", "fashion_mnist", "synthetic",
+                            "synthetic_tokens"],
+                   help="synthetic_tokens: a seeded corpus of packed "
+                        "token sequences (data/tokens.py) for a token "
+                        "model (--model laguna); --synthetic-*-size "
+                        "count sequences of --seq-len tokens")
+    p.add_argument("--seq-len", type=int, default=64,
+                   help="tokens a sequence, --dataset synthetic_tokens")
     p.add_argument("--download", action="store_true",
                    help="fetch + verify the dataset's IDX files into --root "
                         "when absent (reference :137-138 download=True; for "
@@ -483,9 +490,45 @@ def _finish_summary(summary: dict, metrics_sink) -> dict:
     return summary
 
 
+def _token_vocab(args):
+    """The vocabulary of ``--model`` where it reads tokens, else None."""
+    from pytorch_distributed_mnist_tpu.models.registry import (
+        model_field_default,
+    )
+
+    if not model_accepts(args.model, "vocab_size"):
+        return None
+    return model_field_default(args.model, "vocab_size")
+
+
+def _build_token_loaders(args, seed: int, mesh):
+    """The loaders of ``--dataset synthetic_tokens``: the same
+    ``MNISTDataLoader`` over packed sequences and next-token labels."""
+    from pytorch_distributed_mnist_tpu.data.tokens import (
+        synthetic_token_corpus,
+    )
+
+    seq_len = getattr(args, "seq_len", 64)
+    nproc, pid = data_replica_coords(mesh)
+    loaders = []
+    for train, n in ((True, args.synthetic_train_size),
+                     (False, args.synthetic_test_size)):
+        tokens, labels = synthetic_token_corpus(
+            n, seq_len, _token_vocab(args),
+            seed=seed + (0 if train else 1_000_003), vocab_seed=seed,
+            median_len=max(seq_len / 4, 2), min_len=min(16, seq_len))
+        loaders.append(MNISTDataLoader(
+            tokens, labels, batch_size=args.batch_size, train=train,
+            num_replicas=nproc, rank=pid, seed=seed, workers=args.workers,
+            shard=None if train else nproc > 1))
+    return loaders[0], loaders[1], True
+
+
 def _build_loaders(args, seed: int, mesh):
     supervision.set_phase("data_stage")
     supervision.maybe_fault("data_stage")
+    if args.dataset == "synthetic_tokens":
+        return _build_token_loaders(args, seed, mesh)
     name = "mnist" if args.dataset == "synthetic" else args.dataset
     synthesize = args.dataset == "synthetic"
     # Default False for programmatic callers that build args by hand.
@@ -1500,6 +1543,12 @@ def _run_body(args, epoch_callback=None) -> dict:
                 f"{args.model!r} does not accept one"
             )
         model_kwargs["patch_size"] = patch
+    tokens = args.dataset == "synthetic_tokens"
+    if tokens != (_token_vocab(args) is not None):
+        raise SystemExit(
+            f"--model {args.model} and --dataset {args.dataset} do not go "
+            f"together: a token model (laguna) reads --dataset "
+            f"synthetic_tokens, and nothing else does")
     moe_dispatch = getattr(args, "moe_dispatch", "dense")
     if getattr(args, "remat", False):
         if not model_accepts(args.model, "remat"):
@@ -1653,6 +1702,8 @@ def _run_body(args, epoch_callback=None) -> dict:
             # (the explicit-DP step, the overlapped-ZeRO step).
             mesh=None if args.trainer_mode == "explicit" or zero_overlap
             else mesh,
+            **({"input_shape": (1, getattr(args, "seq_len", 64))}
+               if tokens else {}),
         )
         if init_model is not None:
             state = state.replace(apply_fn=model.apply)

@@ -10,6 +10,7 @@ from pytorch_distributed_mnist_tpu.data.mnist import (
     write_idx,
 )
 from pytorch_distributed_mnist_tpu.data.sampler import DistributedShardSampler
+from pytorch_distributed_mnist_tpu.data.tokens import synthetic_token_corpus
 from pytorch_distributed_mnist_tpu.data.loader import MNISTDataLoader, make_global_batch
 
 __all__ = [
@@ -17,6 +18,7 @@ __all__ = [
     "MNIST_STD",
     "load_dataset",
     "synthetic_dataset",
+    "synthetic_token_corpus",
     "normalize_images",
     "parse_idx",
     "write_idx",

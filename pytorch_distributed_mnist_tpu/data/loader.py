@@ -41,8 +41,9 @@ class MNISTDataLoader:
 
     def __init__(
         self,
-        images: np.ndarray,  # float32 (N, 28, 28, 1), already normalized
-        labels: np.ndarray,  # int (N,)
+        images: np.ndarray,  # float32 (N, 28, 28, 1), already normalized;
+        # or token sequences (N, T) int32 (data/tokens.py)
+        labels: np.ndarray,  # int (N,), or (N, T) next-token labels
         batch_size: int,
         train: bool = True,
         num_replicas: int = 1,
@@ -133,7 +134,8 @@ class MNISTDataLoader:
         return {
             "image": jax.ShapeDtypeStruct((b,) + self.images.shape[1:],
                                           self.images.dtype),
-            "label": jax.ShapeDtypeStruct((b,), self.labels.dtype),
+            "label": jax.ShapeDtypeStruct((b,) + self.labels.shape[1:],
+                                          self.labels.dtype),
             "mask": jax.ShapeDtypeStruct((b,), np.float32),
         }
 
@@ -176,7 +178,8 @@ class MNISTDataLoader:
                 return {"image": images, "label": labels, "mask": mask}
         return {
             "image": self.images[m.reshape(-1)].reshape(m.shape + self.images.shape[1:]),
-            "label": self.labels[m.reshape(-1)].reshape(m.shape),
+            "label": self.labels[m.reshape(-1)].reshape(
+                m.shape + self.labels.shape[1:]),
             "mask": mask,
         }
 

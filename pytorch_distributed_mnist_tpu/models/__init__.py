@@ -4,13 +4,16 @@ The reference hard-codes a single model (``Net``, a ``Linear(784, 10)``,
 ``/root/reference/multi_proc_single_gpu.py:119-126``) and constructs it at a
 fixed call site (``:185``). Here the model is pluggable via a registry:
 ``linear`` is the exact reference-parity model, ``cnn`` is the small convnet
-required for the >=99% MNIST accuracy target (BASELINE.json north star).
+required for the >=99% MNIST accuracy target (BASELINE.json north star),
+``vit`` and ``moe_mlp`` carry attention and experts, and ``laguna`` is the
+decoder-only token model family (``models/decoder.py``).
 """
 
 from pytorch_distributed_mnist_tpu.models.linear import LinearNet
 from pytorch_distributed_mnist_tpu.models.cnn import ConvNet
 from pytorch_distributed_mnist_tpu.models.attention import VisionTransformer
-from pytorch_distributed_mnist_tpu.models.moe import MoEClassifier, SwitchMoE
+from pytorch_distributed_mnist_tpu.models.moe import MoEClassifier, SparseExperts, SwitchMoE
+from pytorch_distributed_mnist_tpu.models.decoder import Decoder
 from pytorch_distributed_mnist_tpu.models.registry import get_model, register_model, list_models, model_accepts
 
 __all__ = [
@@ -18,7 +21,9 @@ __all__ = [
     "ConvNet",
     "VisionTransformer",
     "MoEClassifier",
+    "SparseExperts",
     "SwitchMoE",
+    "Decoder",
     "get_model",
     "register_model",
     "list_models",

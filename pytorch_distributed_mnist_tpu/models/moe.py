@@ -1,15 +1,27 @@
 """Mixture-of-experts model family (expert parallelism vehicle).
 
-The reference has no experts — its model is one dense layer
-(``/root/reference/multi_proc_single_gpu.py:119-126``; SURVEY.md section 2c
-marks EP/MoE ABSENT). The framework carries a switch-style MoE layer anyway
-because expert parallelism is one of the mesh axes the N-D design supports:
-expert weights carry a leading ``num_experts`` dim that
-``moe_ep_rules`` (parallel/expert.py) shards on the ``expert`` mesh axis,
-and XLA turns the expert-summed combine einsum into an AllReduce over that
-axis — each device computes only its local experts' FLOPs.
+Two expert layers. Expert weights carry a leading expert dim that
+``moe_ep_rules`` (parallel/expert.py) shards on the ``expert`` mesh axis.
 
-Routing is top-1 (switch). Two dispatch modes behind one interface:
+``SparseExperts`` is the layer of the ``laguna`` decoder
+(models/decoder.py): a sigmoid router over ``num_experts``, ``top_k`` a
+token, SwiGLU experts and one shared expert. It is told which experts it
+holds (``experts_held = (first, count)``), routes over all of them and
+computes its own experts' part for the pairs that land here, none dropped,
+by grouped matmuls (``parallel/moe_dispatch.held_experts_forward``). With
+every expert held it is the whole layer; with a share it is one chip's part
+of an expert-parallel deployment, without the exchange.
+
+``SwitchMoE`` (``moe_mlp``) is the small classifier's layer: top-1 routing,
+ReLU experts with biases, a softmax gate. Its ``dispatch='dense'`` runs
+every expert on every token and is kept for what it pins: math that does
+not depend on the layout, which the expert-parallel equivalence tests rely
+on (XLA turns its expert-summed combine einsum into an AllReduce over the
+``expert`` axis). ``SparseExperts`` does not subsume it: the two differ in
+activation, biases and gate, and share the router's float32 policy only.
+
+``SwitchMoE`` routing is top-1 (switch). Two dispatch modes behind one
+interface:
 
 - ``dispatch='dense'`` (default): every expert's MLP runs on every token
   algebraically, the one-hot combine zeroes all but the routed expert, and
@@ -31,18 +43,98 @@ objective (pull it out with ``capture_intermediates``).
 
 from __future__ import annotations
 
-from typing import Optional
+import math
+from typing import Optional, Tuple
 
 import flax.linen as nn
+import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh
 
 from pytorch_distributed_mnist_tpu.models.registry import register_model
+from pytorch_distributed_mnist_tpu.ops.metrics import ROUTING_COLLECTION
 from pytorch_distributed_mnist_tpu.parallel.moe_dispatch import (
+    held_experts_forward,
     load_balance_loss,
     moe_capacity_forward,
+    route_topk,
     top1_mask_gate,
 )
+
+
+def residual_init(depth: int):
+    """Initialiser of a matrix that writes into the residual stream of a
+    model ``depth`` blocks deep: ``normal(0.02 / sqrt(2 depth))``, GPT-2's.
+    (With flax's default a decoder's attention, alike for every late token,
+    swamps the embedding at the seed and nearly every token picks the same
+    experts.)"""
+    return nn.initializers.normal(stddev=0.02 / math.sqrt(2 * depth))
+
+
+class SwiGLU(nn.Module):
+    """``down(silu(gate(x)) * up(x))``, no biases."""
+
+    width: int
+    depth: int  # of the model: residual_init
+    compute_dtype: jnp.dtype = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x: jnp.ndarray) -> jnp.ndarray:
+        def dense(n, name, **kw):
+            return nn.Dense(n, use_bias=False, dtype=self.compute_dtype,
+                            name=name, **kw)
+
+        h = nn.silu(dense(self.width, "gate")(x)) * dense(self.width, "up")(x)
+        return dense(x.shape[-1], "down",
+                     kernel_init=residual_init(self.depth))(h)
+
+
+class SparseExperts(nn.Module):
+    """Top-k-routed SwiGLU experts and a shared one: (..., C) -> (..., C).
+
+    ``F = scale * sum_{e in chosen, held here} w_e E_e(x) + E_shared(x)``
+    with ``w_e = s_e / sum_chosen s``, ``s = sigmoid(x W_r)`` over all
+    ``num_experts`` (module docstring). Router scores are float32 at the
+    highest matmul precision: the choice is discrete.
+    """
+
+    num_experts: int
+    top_k: int
+    width: int
+    shared_width: int
+    depth: int  # of the model: residual_init
+    experts_held: Optional[Tuple[int, int]] = None  # (first, count); all
+    routed_scale: float = 1.0
+    compute_dtype: jnp.dtype = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x: jnp.ndarray) -> jnp.ndarray:
+        first, count = self.experts_held or (0, self.num_experts)
+        c = x.shape[-1]
+        tokens = x.reshape(-1, c)
+        logits = nn.Dense(
+            self.num_experts, use_bias=False, dtype=jnp.float32,
+            precision=jax.lax.Precision.HIGHEST, name="router",
+        )(tokens.astype(jnp.float32))
+        with jax.named_scope("router"):
+            idx, weight = route_topk(
+                nn.sigmoid(logits), self.top_k, self.routed_scale)
+        # For a caller that compares this layer with another computation of
+        # it on the same choices (``mutable=['intermediates']``).
+        self.sow("intermediates", "choices", idx)
+        init = nn.initializers.lecun_normal(batch_axis=(0,))
+        w_gate = self.param("w_gate", init, (count, c, self.width))
+        w_up = self.param("w_up", init, (count, c, self.width))
+        w_down = self.param(
+            "w_down", residual_init(self.depth), (count, self.width, c))
+        routed, counters = held_experts_forward(
+            tokens.astype(self.compute_dtype), idx, weight,
+            w_gate, w_up, w_down, first=first)
+        if not self.is_initializing():  # init returns parameters only
+            self.sow(ROUTING_COLLECTION, "routing", counters)
+        shared = SwiGLU(self.shared_width, self.depth, self.compute_dtype,
+                        name="shared")(x)
+        return routed.reshape(x.shape).astype(shared.dtype) + shared
 
 
 class SwitchMoE(nn.Module):

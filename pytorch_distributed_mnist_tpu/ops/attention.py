@@ -39,21 +39,25 @@ NEG_INF = -1e30  # softmax mask value; avoids -inf NaN propagation in exp
 CORE_SCOPE = "attn_core"
 
 
-def _masked_scores(q, k, causal, scale):
+def _masked_scores(q, k, causal, scale, window=None):
     """``scale * q k^T`` in float32, ``(B, H, Tq, Tk)``; what a causal query
-    may not see is ``NEG_INF`` (end-aligned, so ``Tq != Tk`` is allowed)."""
+    may not see is ``NEG_INF`` (end-aligned, so ``Tq != Tk`` is allowed).
+    With ``window`` a query sees itself and the ``window - 1`` keys before
+    it."""
     s = jnp.einsum(
         "bqhd,bkhd->bhqk", q, k, preferred_element_type=jnp.float32
     ) * scale
     if causal:
         tq, tk = s.shape[-2], s.shape[-1]
         mask = jnp.tril(jnp.ones((tq, tk), bool), k=tk - tq)
+        if window is not None:
+            mask &= ~jnp.tril(jnp.ones((tq, tk), bool), k=tk - tq - window)
         s = jnp.where(mask, s, NEG_INF)
     return s
 
 
-def _dense_fwd(q, k, v, causal, scale):
-    s = _masked_scores(q, k, causal, scale)
+def _dense_fwd(q, k, v, causal, scale, window=None):
+    s = _masked_scores(q, k, causal, scale, window)
     m = jnp.max(s, axis=-1, keepdims=True)
     if causal:
         # A fully-masked row (possible when Tq > Tk) must output zeros, not
@@ -70,18 +74,18 @@ def _dense_fwd(q, k, v, causal, scale):
     return o, (q, k, v, o, lse)
 
 
-@partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def _dense_attention(q, k, v, causal, scale):
-    return _dense_fwd(q, k, v, causal, scale)[0]
+@partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _dense_attention(q, k, v, causal, scale, window=None):
+    return _dense_fwd(q, k, v, causal, scale, window)[0]
 
 
-def _dense_bwd(causal, scale, residuals, do):
+def _dense_bwd(causal, scale, window, residuals, do):
     """Closed form of softmax attention's VJP: with ``p = softmax(s)``,
     ``ds = scale * p * (dp - rowsum(do * o))``. The probabilities are not
     kept; they are recomputed in float32 from the same operands and the
     row log-sum-exp, so they are the forward's values."""
     q, k, v, o, lse = residuals
-    s = _masked_scores(q, k, causal, scale)
+    s = _masked_scores(q, k, causal, scale, window)
     p = jnp.exp(s - lse[..., None])  # a masked entry is exp(NEG_INF): 0
     delta = jnp.einsum(
         "bqhd,bqhd->bhq", do, o, preferred_element_type=jnp.float32)
@@ -108,6 +112,7 @@ def full_attention(
     *,
     causal: bool = False,
     scale: Optional[float] = None,
+    window: Optional[int] = None,
 ) -> jnp.ndarray:
     """Dense softmax attention, ``(B, T, H, D)`` in and out.
 
@@ -116,7 +121,11 @@ def full_attention(
     allclose against this). ``causal`` is end-aligned (``Tq != Tk``
     allowed); a row with nothing to attend to gives zeros and zero
     gradients. ``scale`` defaults to ``D ** -0.5`` and must be a Python
-    number, not a traced value.
+    number, not a traced value. ``window`` (with ``causal``): a query sees
+    itself and the ``window - 1`` keys before it. ``k`` and ``v`` may hold
+    fewer heads than ``q`` (grouped key-value heads; query head ``h`` reads
+    head ``h // (H_q / H_kv)``): they are repeated here, which the flash
+    kernels avoid (``ops/pallas/flash.py``).
 
     Matmul operands keep ``q.dtype``, accumulation and the softmax are
     float32 (module docstring). Reverse-mode differentiation takes the
@@ -127,8 +136,13 @@ def full_attention(
     """
     if scale is None:
         scale = q.shape[-1] ** -0.5
+    if window is not None and not causal:
+        raise ValueError("window needs causal=True")
+    group = q.shape[2] // k.shape[2]
+    if group > 1:
+        k, v = (jnp.repeat(x, group, axis=2) for x in (k, v))
     with jax.named_scope(CORE_SCOPE):
-        return _dense_attention(q, k, v, causal, scale)
+        return _dense_attention(q, k, v, causal, scale, window)
 
 
 class OnlineSoftmaxState(NamedTuple):

@@ -15,7 +15,9 @@ import jax.numpy as jnp
 
 
 def cross_entropy_per_example(logits: jnp.ndarray, labels: jnp.ndarray) -> jnp.ndarray:
-    """Per-example softmax cross-entropy with integer labels, shape (B,).
+    """Per-example softmax cross-entropy with integer labels: the class axis
+    is the last of ``logits``, and the result has the shape of ``labels``
+    (``(B,)`` for images, ``(B, T)`` for token sequences).
 
     Computed in float32 regardless of the model's compute dtype: the
     log-sum-exp reduction is the numerically delicate part, and float32 here
@@ -30,7 +32,8 @@ def cross_entropy_per_example(logits: jnp.ndarray, labels: jnp.ndarray) -> jnp.n
     """
     logits = jax.lax.optimization_barrier(logits.astype(jnp.float32))
     logz = jax.nn.logsumexp(logits, axis=-1)
-    label_logits = jnp.take_along_axis(logits, labels[:, None], axis=-1)[:, 0]
+    label_logits = jnp.take_along_axis(
+        logits, labels[..., None], axis=-1)[..., 0]
     # CE = -log p >= 0 analytically; XLA:TPU's fused exp/log approximations
     # can drift a saturated logsumexp a few 1e-4 below the max logit, which
     # would surface as a (confusing) negative loss. Clamp at the true bound.
@@ -72,6 +75,10 @@ def _fused_per_example(logits: jnp.ndarray, labels: jnp.ndarray) -> jnp.ndarray:
         fused_cross_entropy_per_example,
     )
 
+    if labels.ndim > 1:  # the kernel takes (rows, classes)
+        flat = _fused_per_example(
+            logits.reshape(-1, logits.shape[-1]), labels.reshape(-1))
+        return flat.reshape(labels.shape)
     if _MESH is None or _MESH.size == 1:
         return fused_cross_entropy_per_example(logits, labels)
     size = _MESH.shape[_MESH_AXIS]
@@ -91,6 +98,23 @@ def _fused_per_example(logits: jnp.ndarray, labels: jnp.ndarray) -> jnp.ndarray:
     )(logits, labels)
 
 
+def example_weights(labels: jnp.ndarray, mask: jnp.ndarray | None):
+    """What each label counts for, or ``None`` where all count alike.
+
+    Labels of shape ``(B,)`` (images): ``mask`` as it is, so nothing
+    changes for them. Labels with further axes (token sequences, ``(B,
+    T)``): a position labelled below zero (``data.tokens.IGNORE``: the last
+    of a sequence has no next token) counts nothing, and ``mask`` (0/1 per
+    example) applies to all positions of its example."""
+    if labels.ndim == 1:
+        return mask
+    weights = (labels >= 0).astype(jnp.float32)
+    if mask is not None:
+        weights = weights * mask.astype(jnp.float32).reshape(
+            mask.shape + (1,) * (labels.ndim - mask.ndim))
+    return weights
+
+
 def masked_mean(per_ex: jnp.ndarray, mask: jnp.ndarray | None) -> jnp.ndarray:
     """Mean (or masked mean) over per-example losses — the ONE place the
     reduction semantics live, shared by both loss impls so they cannot
@@ -105,9 +129,14 @@ def cross_entropy(
     logits: jnp.ndarray, labels: jnp.ndarray, mask: jnp.ndarray | None = None
 ) -> jnp.ndarray:
     """Mean softmax cross-entropy; with ``mask`` (0/1 per example), a masked
-    mean so padded examples (eval batch padding) contribute nothing."""
+    mean so padded examples (eval batch padding) contribute nothing.
+    ``logits`` carry the classes on their last axis and any number of
+    leading axes, which ``labels`` share (``example_weights``)."""
+    weights = example_weights(labels, mask)
+    if labels.ndim > 1:
+        labels = jnp.maximum(labels, 0)  # an ignored label indexes class 0
     if _IMPL == "fused":
         per_ex = _fused_per_example(logits, labels)
     else:
         per_ex = cross_entropy_per_example(logits, labels)
-    return masked_mean(per_ex, mask)
+    return masked_mean(per_ex, weights)

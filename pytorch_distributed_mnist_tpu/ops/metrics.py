@@ -16,9 +16,23 @@ read it out (SURVEY.md section 3.2).
 
 from __future__ import annotations
 
-from typing import NamedTuple
+from typing import NamedTuple, Optional
 
 import jax.numpy as jnp
+
+from pytorch_distributed_mnist_tpu.ops.loss import example_weights
+
+# Entries of ``MetricState.routing``, what an expert layer counts of one
+# step (``parallel/moe_dispatch.held_experts_forward``), summed over layers
+# and steps: (token, choice) pairs that landed on an expert held here,
+# pairs routed in all, pairs dropped (named a held expert and were not
+# served: ``moe_dispatch._served``), the fullest held expert's tokens over
+# the mean, and the number of summands (layers x steps).
+ROUTING_COUNTERS = ("landed", "routed", "dropped", "max_over_mean",
+                    "summands")
+# The flax collection the expert layers sow them into (``models/moe.py``);
+# the train step asks for it where ``TrainState.counters`` says so.
+ROUTING_COLLECTION = "counters"
 
 
 class MetricState(NamedTuple):
@@ -27,11 +41,18 @@ class MetricState(NamedTuple):
     loss_sum: jnp.ndarray  # f32 scalar: sum of per-example losses
     correct: jnp.ndarray  # f32 scalar: number of correct predictions
     count: jnp.ndarray  # f32 scalar: number of examples seen
+    # f32 (len(ROUTING_COUNTERS),) where the model has expert layers that
+    # count their routing, else None (no leaf: the programs of the other
+    # models are what they were).
+    routing: Optional[jnp.ndarray] = None
 
 
-def metrics_init() -> MetricState:
+def metrics_init(routing: bool = False) -> MetricState:
     zero = jnp.zeros((), jnp.float32)
-    return MetricState(zero, zero, zero)
+    return MetricState(
+        zero, zero, zero,
+        jnp.zeros((len(ROUTING_COUNTERS),), jnp.float32) if routing
+        else None)
 
 
 def metrics_update(
@@ -49,7 +70,11 @@ def metrics_update(
     ``mask`` (0/1 per example) excludes eval-padding examples from all three
     counters, so padded samples are never double-counted — the reference
     never pads (its test loader just emits a ragged final batch).
+
+    Token batches (``logits`` (B, T, V), ``labels`` (B, T)) count
+    positions, less those ``ops.loss.example_weights`` leaves out.
     """
+    mask = example_weights(labels, mask)
     if mask is None:
         n = jnp.asarray(labels.shape[0], jnp.float32)
         hit = (jnp.argmax(logits, axis=-1) == labels).astype(jnp.float32)
@@ -61,12 +86,25 @@ def metrics_update(
         loss_sum=state.loss_sum + loss.astype(jnp.float32) * n,
         correct=state.correct + jnp.sum(hit),
         count=state.count + n,
+        routing=state.routing,
     )
 
 
+def add_routing(state: MetricState, counters) -> MetricState:
+    """``state`` with one step's routing counters (or ``None``) added."""
+    if counters is None:
+        return state
+    return state._replace(
+        routing=counters if state.routing is None
+        else state.routing + counters)
+
+
 def metrics_merge(a: MetricState, b: MetricState) -> MetricState:
-    """Combine two accumulators (e.g. across devices after a psum gather)."""
-    return MetricState(a.loss_sum + b.loss_sum, a.correct + b.correct, a.count + b.count)
+    """Combine two accumulators (e.g. across devices after a psum gather,
+    or one step's into an epoch's)."""
+    return add_routing(
+        MetricState(a.loss_sum + b.loss_sum, a.correct + b.correct,
+                    a.count + b.count, a.routing), b.routing)
 
 
 class Average:

@@ -13,20 +13,38 @@ is the standard two-pass flash recipe over that residual:
   dK_j    = scale * sum_i dS_ij^T Q_i                (kernel 2: grid over j)
 
 so gradients also run at flash memory cost — no ``jax.vjp`` of a dense
-reference anywhere (earlier revisions recomputed a (T, T) matrix in the
-backward, which forfeited the memory win for training). Oracle for all
-three kernels: ``full_attention`` under ``jax.vjp``, asserted in interpret
-mode by tests/test_pallas_kernels.py.
+reference anywhere. Oracle for all three kernels: ``full_attention`` under
+``jax.vjp``, asserted in interpret mode by tests/test_pallas_kernels.py and
+tests/test_flash_window.py, and on the chip by tests_tpu/.
 
-The reference repo has no attention at all
-(``/root/reference/multi_proc_single_gpu.py:119-126``; SURVEY.md section 2c
-marks every sequence strategy ABSENT) — this op family exists because
-long-context is first-class in the TPU design: ``ring_attention_local``
-(parallel/ring.py) accepts any per-block attention update, and this kernel
-is what a production config uses inside each ring step.
+What the kernels take (``flash_attention``'s docstring has the contract):
 
-Layout: ``(B, T, H, D)``; kernels run per (batch*head) with both matmuls
-per tile on the MXU in f32 accumulation.
+- self-attention, ``causal`` or not, and with ``causal`` a ``window``: a
+  query sees itself and the ``window - 1`` keys before it. Tiles that lie
+  wholly above the diagonal or wholly behind the window are *skipped*, not
+  masked: each program loops over the key (or query) blocks of its band
+  only, and masks only the tiles the diagonal, the window's edge or the
+  padding cuts through;
+- grouped key-value heads: ``k`` and ``v`` may hold ``H / G`` heads; query
+  head ``h`` reads head ``h // G`` through the block index, so the repeated
+  keys and values are never written anywhere. ``dk`` and ``dv`` come out of
+  the kernel per query head and are summed over the group by XLA;
+- any head size. Where it is a multiple of 128 the kernels read
+  ``(B, T, H*D)`` as it leaves the projection, a 128-lane column block a
+  head, with no transpose; otherwise (the ViT's 16 to 64) the arrays are
+  put head-major first, ``(B, H, T, D)``, because a block's last dimension
+  has to be a multiple of 128 lanes or the whole array's;
+- operands in the type they arrive in (bf16 in the training cells, f32 in
+  the tests), every matmul accumulated in float32, scores and softmax in
+  float32, the probabilities cast to the operands' type only where they
+  enter a matmul.
+
+Each program holds one head's whole ``(T, D)`` keys and values (the
+backward's second kernel: queries and output gradients) in VMEM: 2 MB each
+at T = 8192, D = 128 in bf16, fetched once per head (per key-value head in
+the forward: the block index does not change inside a group).
+
+Layout of the public function: ``(B, T, H, D)``.
 """
 
 from __future__ import annotations
@@ -43,19 +61,117 @@ from pytorch_distributed_mnist_tpu.ops.pallas.backend import should_interpret
 
 __all__ = ["flash_attention", "sharded_flash_attention"]
 
+LANES = 128
+# Scoped VMEM a program may use: two buffers each of a head's whole keys
+# and values (8 MB at T = 8192, D = 128, bf16; 16 MB in f32) and a few
+# (block, block) float32 tiles. The v5e has 128 MiB.
+VMEM_LIMIT_BYTES = 64 * 1024 * 1024
+_NT = (((1,), (1,)), ((), ()))  # a @ b.T
+_NN = (((1,), (0,)), ((), ()))  # a @ b
 
-def _keep_mask(iq, jk, block_q, block_k, t_real, causal):
-    """(BQ, BK) validity: in-range q row, in-range k col, causal triangle.
+
+def _dot(a, b, dims):
+    """A tile matmul accumulated in float32. Float32 operands take the
+    ambient ``jax.default_matmul_precision`` (the tests' ``highest``);
+    16-bit operands are one MXU pass whatever it says, which Mosaic
+    refuses to be told otherwise."""
+    precision = (jax.lax.Precision.DEFAULT
+                 if jnp.dtype(a.dtype).itemsize < 4 else None)
+    return jax.lax.dot_general(a, b, dims, precision=precision,
+                               preferred_element_type=jnp.float32)
+
+
+def _visible(iq, jk, block, t_real, causal, window, keys_first=False):
+    """Which (query, key) pairs of tile (iq, jk) count: both in range, the
+    key not after the query (``causal``) and fewer than ``window`` before
+    it. ``(block, block)`` bool, queries along the rows, or along the
+    columns where ``keys_first`` (the transposed tiles of ``_dkv_kernel``).
 
     The causal form is start-aligned (qi >= ki), identical to the dense
-    oracle's end-aligned tril only when Tq == Tk — which ``flash_attention``
-    asserts, since the same residuals/padding already require it."""
-    qi = iq * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
-    ki = jk * block_k + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
+    oracle's end-aligned mask because Tq == Tk, which ``flash_attention``
+    asserts."""
+    shape = (block, block)
+    q_axis, k_axis = (1, 0) if keys_first else (0, 1)
+    qi = iq * block + jax.lax.broadcasted_iota(jnp.int32, shape, q_axis)
+    ki = jk * block + jax.lax.broadcasted_iota(jnp.int32, shape, k_axis)
     keep = (qi < t_real) & (ki < t_real)
     if causal:
-        keep &= qi >= ki
+        keep &= ki <= qi
+    if window is not None:
+        keep &= ki > qi - window
     return keep
+
+
+def _key_blocks(iq, block, n, t_real, causal, window):
+    """Key blocks of query block ``iq``: ``[lo, hi)`` holds every visible
+    key; inside it ``[a, b)`` are the blocks every query of the block sees
+    whole, which need no mask. Integers, traced where ``iq`` is."""
+    q0 = iq * block
+    q1 = q0 + block - 1
+    lo, hi, a = 0, n, 0
+    b = t_real // block  # key blocks without padding
+    if causal:
+        hi = jnp.minimum(iq + 1, n)
+        b = jnp.minimum(b, (q0 + 1) // block)  # last key not after q0
+    if window is not None:
+        lo = jnp.maximum(q0 - window + 1, 0) // block
+        a = (jnp.maximum(q1 - window + 1, 0) + block - 1) // block
+    # A block with padded query rows is masked throughout.
+    b = jnp.where((iq + 1) * block <= t_real, b, 0)
+    a = jnp.clip(a, lo, hi)
+    return lo, a, jnp.clip(b, a, hi), hi
+
+
+def _query_blocks(jk, block, n, t_real, causal, window):
+    """The same for key block ``jk``: the query blocks that see any of its
+    keys, and those among them that see all of them unmasked."""
+    k0 = jk * block
+    k1 = k0 + block - 1
+    lo, hi, a = 0, n, 0
+    b = t_real // block  # query blocks without padding
+    if causal:
+        lo = jnp.minimum(jk, n)
+        a = (k1 + block - 1) // block  # first block wholly at or after k1
+    if window is not None:
+        hi = jnp.minimum((k1 + window - 1) // block + 1, n)
+        # last row of block i, i*block + block - 1, still sees k0
+        b = jnp.minimum(b, (k0 + window - block) // block + 1)
+    b = jnp.where((jk + 1) * block <= t_real, b, 0)
+    a = jnp.clip(a, lo, hi)
+    return lo, a, jnp.clip(b, a, hi), hi
+
+
+def _banded_loop(ranges, body, carry):
+    """``body(j, carry, masked)`` over ``[lo, a)`` masked, ``[a, b)`` not,
+    ``[b, hi)`` masked: three loops, so the unmasked body is compiled
+    without the mask's iota, compares and selects."""
+    lo, a, b, hi = ranges
+    for start, stop, masked in ((lo, a, True), (a, b, False), (b, hi, True)):
+        carry = jax.lax.fori_loop(
+            start, stop, functools.partial(body, masked=masked), carry)
+    return carry
+
+
+def _eye(n):
+    return (jax.lax.broadcasted_iota(jnp.int32, (n, n), 0)
+            == jax.lax.broadcasted_iota(jnp.int32, (n, n), 1))
+
+
+def _col_to_row(col):
+    """(n, 1) -> (1, n) with a select and a reduction over sublanes: the
+    row statistics are kept lane-major in HBM (n floats, not n x 128), and
+    this costs n^2 selects once a block against ~10 n^2 a loop step."""
+    return jnp.sum(jnp.where(_eye(col.shape[0]), col, 0.0), axis=0,
+                   keepdims=True)
+
+
+def _row_to_col(row):
+    return jnp.sum(jnp.where(_eye(row.shape[1]), row, 0.0), axis=1,
+                   keepdims=True)
+
+
+def _rows(ref, j, block):
+    return ref[pl.ds(pl.multiple_of(j * block, block), block), :]
 
 
 # --------------------------------------------------------------------------
@@ -63,125 +179,146 @@ def _keep_mask(iq, jk, block_q, block_k, t_real, causal):
 # --------------------------------------------------------------------------
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k: int,
-                causal: bool, scale: float, block_q: int, t_real: int):
-    """One (batch*head, q-block) program: stream K/V blocks, online softmax.
+def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block: int,
+                causal: bool, window, scale: float, t_real: int):
+    """One (batch, head, q-block) program: stream the key blocks of the
+    band, online softmax.
 
     Emits both the normalized output block and the row logsumexp
     ``lse = m + log(l)`` — the single residual the backward kernels need to
     reconstruct any P tile.
     """
-    q = q_ref[0].astype(jnp.float32) * scale  # (BQ, D)
-    t = k_ref.shape[1]
-    nk = t // block_k
-    iq = pl.program_id(1)
-    masked = causal or t_real < t
+    iq = pl.program_id(2)
+    q = q_ref[...]  # (BQ, D)
+    d = q.shape[-1]
 
-    def body(j, carry):
+    def body(j, carry, masked):
         o, m, l = carry
-        k_blk = k_ref[0, pl.ds(j * block_k, block_k), :].astype(jnp.float32)
-        v_blk = v_ref[0, pl.ds(j * block_k, block_k), :].astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, k_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )  # (BQ, BK)
+        k_blk, v_blk = _rows(k_ref, j, block), _rows(v_ref, j, block)
+        s = _dot(q, k_blk, _NT) * scale  # (BQ, BK) float32
         if masked:
-            s = jnp.where(
-                _keep_mask(iq, j, block_q, block_k, t_real, causal), s, NEG_INF
-            )
+            keep = _visible(iq, j, block, t_real, causal, window)
+            s = jnp.where(keep, s, NEG_INF)
         m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
         p = jnp.exp(s - m_new)
         if masked:
-            p = jnp.where(s <= NEG_INF / 2, 0.0, p)
-        corr = jnp.where(m <= NEG_INF / 2, 0.0, jnp.exp(m - m_new))
+            p = jnp.where(keep, p, 0.0)
+        corr = jnp.exp(m - m_new)  # m == m_new == NEG_INF: 1, times 0
         l = l * corr + jnp.sum(p, axis=-1, keepdims=True)
-        pv = jax.lax.dot_general(
-            p, v_blk, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        return o * corr + pv, m_new, l
+        return o * corr + _dot(p.astype(v_blk.dtype), v_blk, _NN), m_new, l
 
-    d = q_ref.shape[-1]
-    o = jnp.zeros((block_q, d), jnp.float32)
-    m = jnp.full((block_q, 1), NEG_INF, jnp.float32)
-    l = jnp.zeros((block_q, 1), jnp.float32)
-    o, m, l = jax.lax.fori_loop(0, nk, body, (o, m, l))
-    o_ref[0] = (o / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
-    # lse is carried as (BQ, 1): Mosaic requires the last two block dims be
-    # (8, 128)-tile friendly or equal to the array dims, which a flat (1, BQ)
-    # row block violates on real TPU (BQ lands in the sublane slot).
-    lse_ref[0] = jnp.where(l > 0, m + jnp.log(jnp.maximum(l, 1e-30)), NEG_INF)
+    o, m, l = _banded_loop(
+        _key_blocks(iq, block, k_ref.shape[0] // block, t_real, causal,
+                    window),
+        body,
+        (jnp.zeros((block, d), jnp.float32),
+         jnp.full((block, 1), NEG_INF, jnp.float32),
+         jnp.zeros((block, 1), jnp.float32)))
+    o_ref[...] = (o / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
+    lse = jnp.where(l > 0, m + jnp.log(jnp.maximum(l, 1e-30)), NEG_INF)
+    lse_ref[...] = _col_to_row(lse)
 
 
 def _block_sizes(t: int, block: int | None = None):
     # Pad T up to a tile-friendly block multiple (never shrink the block to
     # a divisor of T — a prime T would degrade to block 1); padded K
     # positions are masked inside the kernels, padded Q rows sliced off.
-    # Default block 128 = the MXU tile. No flash-vs-dense ratio is
-    # measured at any T on today's code. Bigger tiles at long T are a
-    # plausible win (amortized loop/pipeline overhead; s/p scratch is
-    # block^2 f32, 256 KB at 256 — well inside VMEM) but UNMEASURED: the
-    # on-chip sweep (tools/sweep_flash.py) exists to decide it. Until
-    # then the default stays the MXU tile and the hypothesis is reachable
-    # via the explicit ``block=`` override.
+    # Default: 512 from T = 512 up (a (512, 512) float32 score tile is
+    # 1 MB; fewer, larger loop steps, and a window of 512 then touches two
+    # key blocks a query block), the MXU tile 128 from T = 128 up, and
+    # below that T itself rounded up to the sublane count.
     if block is None:
-        block = 128 if t >= 128 else ((t + 7) // 8) * 8
+        block = 512 if t >= 512 else 128 if t >= 128 else ((t + 7) // 8) * 8
     t_pad = ((t + block - 1) // block) * block
     return block, t_pad
 
 
-def _to_heads(x, b, t, h, d, t_pad):
-    """(B, T, H, D) -> (B*H, Tp, D): one grid row per batch-head pair."""
-    x = x.transpose(0, 2, 1, 3).reshape(b * h, t, d)
-    if t_pad != t:
-        x = jnp.pad(x, ((0, 0), (0, t_pad - t), (0, 0)))
-    return x
+class _Layout:
+    """How ``(B, T, H, D)`` arrays reach the kernels and how a program's
+    ``(rows, D)`` block of head ``h`` is addressed (module docstring)."""
+
+    def __init__(self, d: int, t: int, t_pad: int):
+        self.d, self.t, self.t_pad = d, t, t_pad
+        self.lane_blocked = d % LANES == 0
+
+    def pack(self, x):
+        b, t, h, d = x.shape
+        x = (x.reshape(b, t, h * d) if self.lane_blocked
+             else x.transpose(0, 2, 1, 3))
+        if self.t_pad != t:
+            pad = [(0, 0)] * x.ndim
+            pad[-2] = (0, self.t_pad - t)
+            x = jnp.pad(x, pad)
+        return x
+
+    def unpack(self, x):
+        x = x[..., :self.t, :]
+        if self.lane_blocked:
+            return x.reshape(x.shape[0], self.t, -1, self.d)
+        return x.transpose(0, 2, 1, 3)
+
+    def shape(self, b: int, h: int):
+        return ((b, self.t_pad, h * self.d) if self.lane_blocked
+                else (b, h, self.t_pad, self.d))
+
+    def spec(self, rows: int, *, blocked: bool, group: int = 1):
+        """Block ``i`` of ``rows`` rows (or, not ``blocked``, the whole
+        sequence) of head ``h // group``, for a grid (b, h, i)."""
+        if self.lane_blocked:
+            return pl.BlockSpec(
+                (None, rows, self.d),
+                lambda b, h, i: (b, i if blocked else 0, h // group))
+        return pl.BlockSpec(
+            (None, None, rows, self.d),
+            lambda b, h, i: (b, h // group, i if blocked else 0, 0))
 
 
-def _from_heads(x, b, t, h, d):
-    return x[:, :t].reshape(b, h, t, d).transpose(0, 2, 1, 3)
+def _row_stat_spec(n_blocks: int, block: int, *, blocked: bool):
+    """Spec of a per-row float32 statistic (lse, delta) kept lane-major as
+    ``(B, H, T/block, 1, block)``: one ``(1, block)`` row a block, or all
+    ``n_blocks`` of a head."""
+    if blocked:
+        return pl.BlockSpec((None, None, None, 1, block),
+                            lambda b, h, i: (b, h, i, 0, 0))
+    return pl.BlockSpec((None, None, n_blocks, 1, block),
+                        lambda b, h, i: (b, h, 0, 0, 0))
 
 
-def _flash_forward(q, k, v, causal: bool, scale: float, interpret: bool,
-                   block_override: int | None = None):
+def _params():
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "arbitrary"),
+        vmem_limit_bytes=VMEM_LIMIT_BYTES)
+
+
+def _flash_forward(q, k, v, causal: bool, window, scale: float,
+                   interpret: bool, block_override: int | None = None):
     b, t, h, d = q.shape
+    group = h // k.shape[2]
     block, t_pad = _block_sizes(t, block_override)
-    qh = _to_heads(q, b, t, h, d, t_pad)
-    kh = _to_heads(k, b, t, h, d, t_pad)
-    vh = _to_heads(v, b, t, h, d, t_pad)
+    n = t_pad // block
+    lay = _Layout(d, t, t_pad)
     kernel = functools.partial(
-        _fwd_kernel, block_k=block, causal=causal,
-        scale=scale, block_q=block, t_real=t,
-    )
-    # NOTE: each program holds the full (Tp, D) K and V in VMEM, which caps
-    # the sequence around T ~ 16k at D=64 f32 (~16 MB VMEM budget). Past
-    # that, stream K/V through a third grid dimension — the online-softmax
-    # carry already supports it; the ring (parallel/ring.py) also divides T
-    # by the seq-axis size per device before this kernel sees it.
+        _fwd_kernel, block=block, causal=causal, window=window,
+        scale=scale, t_real=t)
     out, lse = pl.pallas_call(
         kernel,
-        grid=(b * h, t_pad // block),
+        grid=(b, h, n),
         in_specs=[
-            pl.BlockSpec((1, block, d), lambda i, j: (i, j, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, t_pad, d), lambda i, j: (i, 0, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, t_pad, d), lambda i, j: (i, 0, 0),
-                         memory_space=pltpu.VMEM),
+            lay.spec(block, blocked=True),
+            lay.spec(t_pad, blocked=False, group=group),
+            lay.spec(t_pad, blocked=False, group=group),
         ],
-        out_specs=(
-            pl.BlockSpec((1, block, d), lambda i, j: (i, j, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, block, 1), lambda i, j: (i, j, 0),
-                         memory_space=pltpu.VMEM),
-        ),
+        out_specs=(lay.spec(block, blocked=True),
+                   _row_stat_spec(n, block, blocked=True)),
         out_shape=(
-            jax.ShapeDtypeStruct((b * h, t_pad, d), q.dtype),
-            jax.ShapeDtypeStruct((b * h, t_pad, 1), jnp.float32),
+            jax.ShapeDtypeStruct(lay.shape(b, h), q.dtype),
+            jax.ShapeDtypeStruct((b, h, n, 1, block), jnp.float32),
         ),
+        compiler_params=_params(),
         interpret=interpret,
-    )(qh, kh, vh)
-    return _from_heads(out, b, t, h, d), out, lse
+        name="flash_fwd",
+    )(lay.pack(q), lay.pack(k), lay.pack(v))
+    return lay.unpack(out), lse
 
 
 # --------------------------------------------------------------------------
@@ -190,136 +327,127 @@ def _flash_forward(q, k, v, causal: bool, scale: float, interpret: bool,
 
 
 def _dq_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref, dq_ref, *,
-               block_k: int, causal: bool, scale: float, block_q: int,
-               t_real: int):
-    """Grid (B*H, q-block): stream K/V, accumulate this q-block's dQ."""
-    q = q_ref[0].astype(jnp.float32)          # (BQ, D)
-    do = do_ref[0].astype(jnp.float32)        # (BQ, D)
-    lse = lse_ref[0]                          # (BQ, 1)
-    delta = delta_ref[0]                      # (BQ, 1)
-    t = k_ref.shape[1]
-    nk = t // block_k
-    iq = pl.program_id(1)
+               block: int, causal: bool, window, scale: float, t_real: int):
+    """Grid (B, H, q-block): stream the band's K/V, accumulate this
+    q-block's dQ."""
+    iq = pl.program_id(2)
+    q, do = q_ref[...], do_ref[...]           # (BQ, D)
+    lse = _row_to_col(lse_ref[...])           # (BQ, 1)
+    delta = _row_to_col(delta_ref[...])       # (BQ, 1)
 
-    def body(j, dq):
-        k_blk = k_ref[0, pl.ds(j * block_k, block_k), :].astype(jnp.float32)
-        v_blk = v_ref[0, pl.ds(j * block_k, block_k), :].astype(jnp.float32)
-        s = scale * jax.lax.dot_general(
-            q, k_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )  # (BQ, BK)
-        keep = _keep_mask(iq, j, block_q, block_k, t_real, causal)
-        p = jnp.where(keep, jnp.exp(s - lse), 0.0)
-        dp = jax.lax.dot_general(
-            do, v_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )  # (BQ, BK)
-        ds = p * (dp - delta)
-        return dq + jax.lax.dot_general(
-            ds, k_blk, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+    def body(j, dq, masked):
+        k_blk, v_blk = _rows(k_ref, j, block), _rows(v_ref, j, block)
+        p = jnp.exp(_dot(q, k_blk, _NT) * scale - lse)  # (BQ, BK)
+        if masked:
+            p = jnp.where(
+                _visible(iq, j, block, t_real, causal, window), p, 0.0)
+        ds = p * (_dot(do, v_blk, _NT) - delta)
+        return dq + _dot(ds.astype(k_blk.dtype), k_blk, _NN)
 
-    d = q_ref.shape[-1]
-    dq = jax.lax.fori_loop(0, nk, body, jnp.zeros((block_q, d), jnp.float32))
-    dq_ref[0] = (scale * dq).astype(dq_ref.dtype)
+    dq = _banded_loop(
+        _key_blocks(iq, block, k_ref.shape[0] // block, t_real, causal,
+                    window),
+        body, jnp.zeros(q.shape, jnp.float32))
+    dq_ref[...] = (scale * dq).astype(dq_ref.dtype)
 
 
 def _dkv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref,
-                dk_ref, dv_ref, *, block_k: int, causal: bool, scale: float,
-                block_q: int, t_real: int):
-    """Grid (B*H, k-block): stream Q/dO rows, accumulate dK and dV."""
-    k_blk = k_ref[0].astype(jnp.float32)      # (BK, D)
-    v_blk = v_ref[0].astype(jnp.float32)      # (BK, D)
-    t = q_ref.shape[1]
-    nq = t // block_q
-    jk = pl.program_id(1)
+                dk_ref, dv_ref, *, block: int, causal: bool, window,
+                scale: float, t_real: int):
+    """Grid (B, H, k-block): stream the band's Q/dO rows, accumulate one
+    query head's share of dK and dV. The tiles are transposed, keys along
+    the rows, so that the per-query statistics broadcast as the lane-major
+    rows they are stored as and all four matmuls are plain or
+    transposed-right."""
+    jk = pl.program_id(2)
+    k_blk, v_blk = k_ref[...], v_ref[...]     # (BK, D)
 
-    def body(i, carry):
+    def body(i, carry, masked):
         dk, dv = carry
-        q = q_ref[0, pl.ds(i * block_q, block_q), :].astype(jnp.float32)
-        do = do_ref[0, pl.ds(i * block_q, block_q), :].astype(jnp.float32)
-        lse = lse_ref[0, pl.ds(i * block_q, block_q), :]      # (BQ, 1)
-        delta = delta_ref[0, pl.ds(i * block_q, block_q), :]  # (BQ, 1)
-        s = scale * jax.lax.dot_general(
-            q, k_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )  # (BQ, BK)
-        keep = _keep_mask(i, jk, block_q, block_k, t_real, causal)
-        p = jnp.where(keep, jnp.exp(s - lse), 0.0)
-        dv = dv + jax.lax.dot_general(
-            p, do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )  # (BK, D)
-        dp = jax.lax.dot_general(
-            do, v_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )  # (BQ, BK)
-        ds = p * (dp - delta)
-        dk = dk + jax.lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )  # (BK, D)
-        return dk, dv
+        q, do = _rows(q_ref, i, block), _rows(do_ref, i, block)
+        lse, delta = lse_ref[i], delta_ref[i]  # (1, BQ)
+        p = jnp.exp(_dot(k_blk, q, _NT) * scale - lse)  # (BK, BQ)
+        if masked:
+            p = jnp.where(
+                _visible(i, jk, block, t_real, causal, window,
+                         keys_first=True), p, 0.0)
+        dv = dv + _dot(p.astype(do.dtype), do, _NN)
+        ds = p * (_dot(v_blk, do, _NT) - delta)
+        return dk + _dot(ds.astype(q.dtype), q, _NN), dv
 
-    d = k_ref.shape[-1]
-    zero = jnp.zeros((block_k, d), jnp.float32)
-    dk, dv = jax.lax.fori_loop(0, nq, body, (zero, zero))
-    dk_ref[0] = (scale * dk).astype(dk_ref.dtype)
-    dv_ref[0] = dv.astype(dv_ref.dtype)
+    zero = jnp.zeros(k_blk.shape, jnp.float32)
+    dk, dv = _banded_loop(
+        _query_blocks(jk, block, q_ref.shape[0] // block, t_real, causal,
+                      window),
+        body, (zero, zero))
+    dk_ref[...] = (scale * dk).astype(dk_ref.dtype)
+    dv_ref[...] = dv.astype(dv_ref.dtype)
 
 
-def _flash_backward(q, k, v, o_heads, lse, g, causal: bool, scale: float,
+def _sum_over_group(x, group: int, dtype):
+    """Per-query-head dK or dV ``(B, T, H, D)`` -> ``(B, T, H/G, D)``."""
+    if group == 1:
+        return x
+    b, t, h, d = x.shape
+    return jnp.sum(x.reshape(b, t, h // group, group, d), axis=3,
+                   dtype=jnp.float32).astype(dtype)
+
+
+def _flash_backward(q, k, v, o, lse, g, causal: bool, window, scale: float,
                     interpret: bool, block_override: int | None = None):
     b, t, h, d = q.shape
+    group = h // k.shape[2]
     block, t_pad = _block_sizes(t, block_override)
-    qh = _to_heads(q, b, t, h, d, t_pad)
-    kh = _to_heads(k, b, t, h, d, t_pad)
-    vh = _to_heads(v, b, t, h, d, t_pad)
-    doh = _to_heads(g, b, t, h, d, t_pad)
-    # delta = rowsum(dO * O): tiny elementwise op, fine in XLA. o_heads is
-    # the forward kernel's padded (B*H, Tp, D) output, reused as-is. Kept
-    # as (B*H, Tp, 1) like lse so row blocks are Mosaic-tileable.
-    delta = jnp.sum(doh * o_heads.astype(jnp.float32), axis=-1,
-                    keepdims=True)  # (B*H, Tp, 1)
+    n = t_pad // block
+    lay = _Layout(d, t, t_pad)
+    qp, kp, vp, dop = lay.pack(q), lay.pack(k), lay.pack(v), lay.pack(g)
+    # delta = rowsum(dO * O): tiny elementwise op, fine in XLA; kept
+    # lane-major like lse.
+    delta = jnp.einsum("bthd,bthd->bht", g, o,
+                       preferred_element_type=jnp.float32)
+    if t_pad != t:
+        delta = jnp.pad(delta, ((0, 0), (0, 0), (0, t_pad - t)))
+    delta = delta.reshape(b, h, n, 1, block)
 
-    common = dict(block_k=block, causal=causal, scale=scale,
-                  block_q=block, t_real=t)
-    seq_spec = pl.BlockSpec((1, block, d), lambda i, j: (i, j, 0),
-                            memory_space=pltpu.VMEM)
-    row_spec = pl.BlockSpec((1, block, 1), lambda i, j: (i, j, 0),
-                            memory_space=pltpu.VMEM)
-    full_spec = pl.BlockSpec((1, t_pad, d), lambda i, j: (i, 0, 0),
-                             memory_space=pltpu.VMEM)
-    full_row = pl.BlockSpec((1, t_pad, 1), lambda i, j: (i, 0, 0),
-                            memory_space=pltpu.VMEM)
-    grid = (b * h, t_pad // block)
+    common = dict(block=block, causal=causal, window=window, scale=scale,
+                  t_real=t)
+    blk = lay.spec(block, blocked=True)
+    blk_kv = lay.spec(block, blocked=True, group=group)
+    whole = lay.spec(t_pad, blocked=False)
+    whole_kv = lay.spec(t_pad, blocked=False, group=group)
+    stat = _row_stat_spec(n, block, blocked=True)
+    stat_whole = _row_stat_spec(n, block, blocked=False)
+    grid = (b, h, n)
 
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, **common),
         grid=grid,
-        in_specs=[seq_spec, seq_spec, row_spec, row_spec, full_spec, full_spec],
-        out_specs=seq_spec,
-        out_shape=jax.ShapeDtypeStruct((b * h, t_pad, d), q.dtype),
+        in_specs=[blk, blk, stat, stat, whole_kv, whole_kv],
+        out_specs=blk,
+        out_shape=jax.ShapeDtypeStruct(lay.shape(b, h), q.dtype),
+        compiler_params=_params(),
         interpret=interpret,
-    )(qh, doh, lse, delta, kh, vh)
+        name="flash_bwd_dq",
+    )(qp, dop, lse, delta, kp, vp)
 
     dk, dv = pl.pallas_call(
         functools.partial(_dkv_kernel, **common),
         grid=grid,
-        in_specs=[seq_spec, seq_spec, full_spec, full_spec, full_row, full_row],
-        out_specs=(seq_spec, seq_spec),
+        in_specs=[blk_kv, blk_kv, whole, whole, stat_whole, stat_whole],
+        out_specs=(blk, blk),
         out_shape=(
-            jax.ShapeDtypeStruct((b * h, t_pad, d), k.dtype),
-            jax.ShapeDtypeStruct((b * h, t_pad, d), v.dtype),
+            jax.ShapeDtypeStruct(lay.shape(b, h), k.dtype),
+            jax.ShapeDtypeStruct(lay.shape(b, h), v.dtype),
         ),
+        compiler_params=_params(),
         interpret=interpret,
-    )(kh, vh, qh, doh, lse, delta)
+        name="flash_bwd_dkv",
+    )(kp, vp, qp, dop, lse, delta)
 
     return (
-        _from_heads(dq, b, t, h, d),
-        _from_heads(dk, b, t, h, d),
-        _from_heads(dv, b, t, h, d),
+        lay.unpack(dq),
+        _sum_over_group(lay.unpack(dk), group, k.dtype),
+        _sum_over_group(lay.unpack(dv), group, v.dtype),
     )
 
 
@@ -328,44 +456,48 @@ def _flash_backward(q, k, v, o_heads, lse, g, causal: bool, scale: float,
 # --------------------------------------------------------------------------
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
-def _flash(q, k, v, causal, scale, block):
-    out, _, _ = _flash_forward(
-        q, k, v, causal, scale, should_interpret(), block)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _flash(q, k, v, causal, window, scale, block):
+    out, _ = _flash_forward(
+        q, k, v, causal, window, scale, should_interpret(), block)
     return out
 
 
-def _flash_fwd(q, k, v, causal, scale, block):
-    out, o_heads, lse = _flash_forward(
-        q, k, v, causal, scale, should_interpret(), block
-    )
-    return out, (q, k, v, o_heads, lse)
+def _flash_fwd(q, k, v, causal, window, scale, block):
+    out, lse = _flash_forward(
+        q, k, v, causal, window, scale, should_interpret(), block)
+    return out, (q, k, v, out, lse)
 
 
-def _flash_bwd(causal, scale, block, residuals, g):
-    q, k, v, o_heads, lse = residuals
+def _flash_bwd(causal, window, scale, block, residuals, g):
+    q, k, v, out, lse = residuals
     return _flash_backward(
-        q, k, v, o_heads, lse, g, causal, scale, should_interpret(), block
-    )
+        q, k, v, out, lse, g, causal, window, scale, should_interpret(),
+        block)
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
 def flash_attention(q, k, v, *, causal: bool = False,
+                    window: int | None = None,
                     scale: float | None = None, block: int | None = None):
     """Flash attention on ``(B, T, H, D)``; drop-in for ``full_attention``.
 
     Fully differentiable with fused Pallas forward and backward kernels
     (no (T, T) materialization in either pass); on the CPU backend the
     kernels run in interpreter mode so tests are hermetic. Self-attention
-    shapes only: Tq must equal Tk (the kernel's start-aligned causal mask and the dense
-    oracle's end-aligned mask agree exactly there).
+    shapes only: Tq must equal Tk (the kernel's start-aligned causal mask
+    and the dense oracle's end-aligned mask agree exactly there).
 
-    ``block`` overrides the q/k tile edge (multiple of 8; default 128 —
-    the MXU tile. The override exists for the on-chip block sweep,
-    tools/sweep_flash.py, which decides whether long sequences get a
-    bigger default).
+    ``k`` and ``v`` may hold fewer heads than ``q`` (grouped key-value
+    heads: ``H_q`` a multiple of ``H_kv``, query head ``h`` reads head
+    ``h // (H_q / H_kv)``). ``window`` (with ``causal``): a query sees
+    itself and the ``window - 1`` keys before it; blocks outside the band
+    are not computed.
+
+    ``block`` overrides the q/k tile edge (multiple of 8; default 512 from
+    T = 512 up, 128 from T = 128 up).
     """
     if q.shape[1] != k.shape[1]:
         raise ValueError(
@@ -373,21 +505,27 @@ def flash_attention(q, k, v, *, causal: bool = False,
             f"Tq={q.shape[1]}, Tk={k.shape[1]} — use full_attention for "
             f"cross-attention shapes"
         )
+    if k.shape != v.shape or q.shape[2] % k.shape[2] \
+            or q.shape[::3] != k.shape[::3]:
+        raise ValueError(
+            f"flash_attention: q {q.shape} needs k and v of one shape "
+            f"(B, T, H_kv, D) with H_kv dividing H_q; got k {k.shape}, "
+            f"v {v.shape}")
+    if window is not None and (not causal or window < 1):
+        raise ValueError(
+            f"window={window} needs causal=True and window >= 1")
     if block is not None and (block < 8 or block % 8):
         raise ValueError(f"block must be a multiple of 8, got {block}")
     if block is not None and block > 512:
-        # VMEM-derived cap: the bwd kernel's f32 scratch grows as block^2
-        # (s/p tiles — 1 MB each at 512) plus several block x D operands;
-        # past 512 the working set approaches the ~16 MB/core VMEM and
-        # Mosaic fails with an opaque allocation error rather than this
-        # message. The sweep (tools/sweep_flash.py) tops out at 512 too.
+        # VMEM-derived cap: the kernels' f32 tiles grow as block^2 (s, p,
+        # ds — 1 MB each at 512) beside several block x D operands.
         raise ValueError(
             f"block must be <= 512 (block^2 f32 scratch exceeds VMEM "
             f"beyond that), got {block}")
     if scale is None:
         scale = q.shape[-1] ** -0.5
     with jax.named_scope(CORE_SCOPE):
-        return _flash(q, k, v, causal, float(scale), block)
+        return _flash(q, k, v, causal, window, float(scale), block)
 
 
 def sharded_flash_attention(q, k, v, *, mesh, batch_axis=None,

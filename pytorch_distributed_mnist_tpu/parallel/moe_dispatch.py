@@ -33,10 +33,12 @@ bf16 logit noise would make the routing layout-dependent).
 from __future__ import annotations
 
 import math
+from functools import partial
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
@@ -45,6 +47,8 @@ __all__ = [
     "build_dispatch",
     "moe_capacity_forward",
     "load_balance_loss",
+    "route_topk",
+    "held_experts_forward",
 ]
 
 
@@ -182,3 +186,223 @@ def moe_capacity_forward(
         out_specs=tok,
         check_vma=False,
     )(x, probs, w1, b1, w2, b2)
+
+
+# --------------------------------------------------------------------------
+# Top-k routing over all experts, computed for the experts held here
+# --------------------------------------------------------------------------
+#
+# What expert parallelism asks of one chip, without its exchange: the router
+# scores all ``E`` experts and picks ``k`` a token; this chip holds experts
+# ``[first, first + count)`` and computes their part of the result for the
+# (token, choice) pairs that name one of them. Nothing is dropped: the pairs
+# are sorted by expert into a buffer of ``N * k`` rows, which no routing can
+# overflow, and the three matmuls run grouped by expert over the rows that
+# hold a pair (``jax.lax.ragged_dot``: on a TPU, XLA's own grouped-matmul
+# kernel, whose grid follows the group sizes, so its cost follows the pairs
+# that landed here, not ``N * k`` and not ``count * N``).
+
+
+# The name of the chosen experts among a block's intermediate values, for a
+# recomputing caller's policy (``jax.checkpoint_policies
+# .save_only_these_names``): a choice is kept, never made again.
+CHOICE_NAME = "expert_choice"
+
+
+def route_topk(scores: jnp.ndarray, top_k: int, scale: float):
+    """(N, E) float32 router scores -> ``(idx, weight)``, both (N, k): the
+    ``k`` largest a token and their weights ``scale * s_e / sum_chosen s``,
+    normalised over all ``k`` chosen wherever their experts live.
+
+    The weights read the scores at ``idx`` and ``idx`` carries
+    ``CHOICE_NAME``: under per-block recomputation the backward pass
+    computes the scores again, and XLA rounds the recomputed block's
+    bfloat16 values at other places than the forward's, so a token whose
+    k-th and (k+1)-th scores are nearly tied would be given another expert
+    in the backward pass than the one its forward result came from (0.3%
+    of the pairs at 1,024 tokens; the routed leaves' gradients were 2-12%
+    off for it). With the choice kept, both passes see one routing."""
+    _, idx = lax.top_k(scores, top_k)
+    idx = checkpoint_name(idx.astype(jnp.int32), CHOICE_NAME)
+    vals = jnp.take_along_axis(scores, idx, axis=-1)
+    weight = vals / jnp.sum(vals, axis=-1, keepdims=True) * scale
+    return idx, weight
+
+
+def _sort_pairs(idx: jnp.ndarray, first: int, count: int):
+    """Order the ``N * k`` (token, choice) pairs by held expert, the pairs
+    of experts held elsewhere last. Returns ``order`` (slot -> pair),
+    ``inv`` (pair -> slot), ``sizes`` (count,) rows per held expert and
+    ``local`` (N, k) bool."""
+    local = (idx >= first) & (idx < first + count)
+    key = jnp.where(local, idx - first, count).reshape(-1)
+    pairs = jnp.arange(key.shape[0], dtype=jnp.int32)
+    sorted_key, order = lax.sort((key, pairs), num_keys=1, is_stable=True)
+    _, inv = lax.sort((order, pairs), num_keys=1)
+    starts = jnp.searchsorted(
+        sorted_key, jnp.arange(count + 1, dtype=key.dtype), side="left")
+    return order, inv, jnp.diff(starts).astype(jnp.int32), local
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _grouped_matmul(xs, w, sizes, valid, mask_out):
+    """``ragged_dot`` over the sorted rows. A grouped kernel leaves the rows
+    past the last group unwritten: ``mask_out`` zeroes them (``valid``
+    (N * k, 1) marks the rows that hold a pair), and where the consumer
+    selects for itself it is left off, which saves a pass over the result.
+    Callers pass zero rows there. The transpose's rows are always zeroed:
+    they meet activations in a product."""
+    y = lax.ragged_dot(xs, w, sizes, preferred_element_type=xs.dtype)
+    return jnp.where(valid, y, 0) if mask_out else y
+
+
+def _grouped_fwd(xs, w, sizes, valid, mask_out):
+    return (_grouped_matmul(xs, w, sizes, valid, mask_out),
+            (xs, w, sizes, valid))
+
+
+def _grouped_bwd(mask_out, res, dy):
+    del mask_out
+    xs, w, sizes, valid = res
+    _, vjp = jax.vjp(lambda a, b: lax.ragged_dot(
+        a, b, sizes, preferred_element_type=xs.dtype), xs, w)
+    dxs, dw = vjp(dy)
+    return jnp.where(valid, dxs, 0), dw, None, None
+
+
+_grouped_matmul.defvjp(_grouped_fwd, _grouped_bwd)
+
+
+def _to_slots(x, token):
+    """Row ``token[j]`` of ``x`` (N, C) for every slot ``j``; slots that
+    hold no local pair name row N, a row of zeros appended here, so the
+    gather needs no select after it."""
+    return jnp.concatenate([x, jnp.zeros_like(x[:1])])[token]
+
+
+def _to_tokens(y, inv, local):
+    """``out[n] = sum over the local pairs (n, c) of y[slot of (n, c)]``,
+    float32. The select sits after the gather, inside the sum's fusion,
+    because the slots of pairs held elsewhere hold undefined rows."""
+    n, k = local.shape
+    picked = y[inv].reshape(n, k, -1)
+    return jnp.sum(jnp.where(local[..., None], picked, 0), axis=1,
+                   dtype=jnp.float32)
+
+
+@jax.custom_vjp
+def _dispatch(x, token, inv, local):
+    """``x`` (N, C) to the sorted slots, (N * k, C). Its transpose is a
+    gather too, ``_to_tokens``: every pair of a token has one slot."""
+    return _to_slots(x, token)
+
+
+def _dispatch_fwd(x, token, inv, local):
+    return _to_slots(x, token), (inv, local)
+
+
+def _dispatch_bwd(res, dxs):
+    inv, local = res
+    return _to_tokens(dxs, inv, local).astype(dxs.dtype), None, None, None
+
+
+_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
+@jax.custom_vjp
+def _combine(y, token, inv, local):
+    """The sorted slots' rows ``y`` (N * k, C) summed back to their tokens,
+    (N, C) float32: ``_dispatch``'s transpose, and the other way round."""
+    return _to_tokens(y, inv, local)
+
+
+def _combine_fwd(y, token, inv, local):
+    return _to_tokens(y, inv, local), (token, jnp.zeros((), y.dtype))
+
+
+def _combine_bwd(res, g):
+    token, like = res
+    return _to_slots(g.astype(like.dtype), token), None, None, None
+
+
+_combine.defvjp(_combine_fwd, _combine_bwd)
+
+
+@jax.custom_vjp
+def _sorted(values, order, inv):
+    """``values[order]`` for a permutation ``order`` with inverse ``inv``:
+    the transpose is the gather ``[inv]``, not a scatter-add."""
+    return values[order]
+
+
+_sorted.defvjp(lambda values, order, inv: (values[order], inv),
+               lambda inv, g: (g[inv], None, None))
+
+
+def _served(expert, token, inv, sizes):
+    """(N, k) bool: the pairs whose result the combine reads from a row
+    that the grouped matmuls computed from the pair's own token with the
+    pair's own expert. Read off the arrays the three steps index by, as
+    they do: the combine reads pair ``(n, c)`` from slot ``inv[n, c]``;
+    the dispatch filled that slot from row ``token[slot]``; a grouped
+    matmul gives row ``r`` the weights of the group ``g`` with ``sum
+    sizes[:g] <= r < sum sizes[:g + 1]`` and leaves the rows past the last
+    group unwritten. ``expert`` (N, k) counts from the first held one."""
+    n, k = expert.shape
+    slot = inv.reshape(n, k)
+    group = jnp.searchsorted(jnp.cumsum(sizes), slot, side="right",
+                             method="compare_all")
+    own_row = token[slot] == jnp.arange(n, dtype=token.dtype)[:, None]
+    return own_row & (group == expert)
+
+
+def held_experts_forward(x, idx, weight, w_gate, w_up, w_down, *,
+                         first: int):
+    """The held experts' part of a top-k expert layer.
+
+    ``x`` (N, C) tokens; ``idx``, ``weight`` (N, k) from :func:`route_topk`
+    over all experts; ``w_gate``, ``w_up`` (count, C, F) and ``w_down``
+    (count, F, C) the SwiGLU weights of experts ``first .. first + count``.
+    Returns ``(out, counters)``: ``out`` (N, C) float32 is
+    ``sum_{e chosen and held} weight_e * E_e(x)``; ``counters`` is the
+    float32 vector ``[pairs landed here, pairs routed (N * k), pairs
+    dropped, tokens of the fullest held expert over the mean, 1]``
+    (summed over layers and steps by the caller; the last entry counts the
+    summands). A pair that names a held expert is dropped unless it was
+    served (:func:`_served`).
+
+    A pair's weight multiplies its expert's hidden row (F wide) before the
+    down projection, not the result (C wide): the same product, on a
+    quarter of the bytes. Every move between tokens and slots, forward and
+    backward, is a gather.
+    """
+    n, k = idx.shape
+    count = w_gate.shape[0]
+    with jax.named_scope("dispatch"):
+        order, inv, sizes, local = _sort_pairs(idx, first, count)
+        landed = jnp.sum(sizes)
+        valid = jnp.arange(n * k, dtype=jnp.int32) < landed
+        token = jnp.where(valid, order // k, n)
+        xs = _dispatch(x, token, inv, local)
+        w_sorted = _sorted(
+            jnp.where(local, weight, 0.0).reshape(-1), order, inv)
+        valid = valid[:, None]
+    with jax.named_scope("experts"):
+        gate = _grouped_matmul(xs, w_gate.astype(x.dtype), sizes, valid, True)
+        up = _grouped_matmul(xs, w_up.astype(x.dtype), sizes, valid, True)
+        hidden = (jax.nn.silu(gate.astype(jnp.float32))
+                  * up.astype(jnp.float32) * w_sorted[:, None])
+        y = _grouped_matmul(hidden.astype(x.dtype), w_down.astype(x.dtype),
+                            sizes, valid, False)
+    with jax.named_scope("combine"):
+        out = _combine(y, token, inv, local)
+    sizes_f = sizes.astype(jnp.float32)
+    served = _served(idx - first, token, inv, sizes)
+    counters = jnp.stack([
+        landed.astype(jnp.float32),
+        jnp.float32(n * k),
+        jnp.sum((local & ~served).astype(jnp.float32)),
+        jnp.max(sizes_f) / jnp.maximum(jnp.mean(sizes_f), 1e-9),
+        jnp.float32(1.0),
+    ])
+    return out, counters

@@ -33,6 +33,10 @@ class TrainState:
     opt_state: Any  # optax state (holds hyperparams.learning_rate)
     apply_fn: Callable = flax.struct.field(pytree_node=False)
     tx: optax.GradientTransformation = flax.struct.field(pytree_node=False)
+    # True where the model's expert layers sow routing counters
+    # (``ops/metrics.py`` ROUTING_COLLECTION): the train step then asks for that
+    # collection and carries its sum out in ``MetricState.routing``.
+    counters: bool = flax.struct.field(pytree_node=False, default=False)
 
     def apply_gradients(self, grads):
         # The scope names these ops in a profile (README, "Profiling a run").
@@ -104,6 +108,24 @@ def create_train_state(
     """Initialize params (float32) and optimizer state for ``model``.
     ``mesh``: see :func:`make_optimizer`."""
     params = model.init(rng, jnp.zeros(input_shape, jnp.float32))
+    return train_state_from_params(
+        model, params, lr, optimizer, momentum, weight_decay, mesh)
+
+
+def train_state_from_params(
+    model,
+    params,
+    lr: float = 1e-3,
+    optimizer: str = "adam",
+    momentum: float = 0.9,
+    weight_decay: float = 1e-4,
+    mesh=None,
+) -> TrainState:
+    """The state :func:`create_train_state` makes, from parameters that
+    exist already: a caller that needs the device's memory between
+    initialising the weights and holding the optimizer's moments (the
+    benchmark's reference check of a model that fills the chip) makes the
+    two in turn."""
     tx = make_optimizer(lr, optimizer, momentum, weight_decay, mesh=mesh)
     return TrainState(
         step=jnp.zeros((), jnp.int32),
@@ -111,4 +133,5 @@ def create_train_state(
         opt_state=tx.init(params),
         apply_fn=model.apply,
         tx=tx,
+        counters=bool(getattr(model, "counters", False)),
     )

@@ -24,16 +24,25 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from pytorch_distributed_mnist_tpu.ops.loss import cross_entropy
-from pytorch_distributed_mnist_tpu.ops.metrics import MetricState, metrics_init, metrics_update
+from pytorch_distributed_mnist_tpu.ops.loss import cross_entropy, example_weights
+from pytorch_distributed_mnist_tpu.ops.metrics import (
+    ROUTING_COLLECTION as COUNTERS,
+    add_routing,
+    metrics_init,
+    metrics_merge,
+    metrics_update,
+)
 
 
 def _forward_with_aux(state, params, images, aux_weight: float):
-    """Training forward returning ``(logits, aux)`` where ``aux`` is the
-    sum of the ``aux_loss`` entries the model sowed under
+    """Training forward returning ``(logits, aux, counters)`` where
+    ``aux`` is the sum of the ``aux_loss`` entries the model sowed under
     ``intermediates`` (the MoE router's load-balance term, models/moe.py)
     — 0.0 when ``aux_weight`` is 0, in which case the capture is skipped
-    entirely and the program is byte-identical to the plain path.
+    entirely and the program is byte-identical to the plain path — and
+    ``counters`` the sum of what its expert layers sowed under
+    ``counters`` where the state says it has such layers
+    (``TrainState.counters``), else ``None``.
 
     Only leaves whose key is literally ``aux_loss`` enter the objective;
     any other sown intermediate raises, so a future diagnostic sow can
@@ -43,13 +52,20 @@ def _forward_with_aux(state, params, images, aux_weight: float):
     guarantees (``drop_last=train``, data/loader.py: the ragged tail is
     dropped, never padded; only EVAL batches pad, and eval never runs
     this path)."""
-    if not aux_weight:
-        return state.apply_fn(params, images, train=True), 0.0
+    collections = (["intermediates"] if aux_weight else []) \
+        + ([COUNTERS] if _counts_routing(state) else [])
+    if not collections:
+        return state.apply_fn(params, images, train=True), 0.0, None
     logits, mods = state.apply_fn(
-        params, images, train=True, mutable=["intermediates"]
+        params, images, train=True, mutable=collections
     )
-    aux = jnp.float32(0.0)
-    for path, leaf in jax.tree_util.tree_leaves_with_path(mods):
+    counters = None
+    if COUNTERS in collections:
+        counters = sum(jax.tree_util.tree_leaves(mods.get(COUNTERS, {})))
+        counters = jax.lax.stop_gradient(counters)
+    aux = jnp.float32(0.0) if aux_weight else 0.0
+    for path, leaf in jax.tree_util.tree_leaves_with_path(
+            mods.get("intermediates", {})):
         names = [getattr(k, "key", getattr(k, "name", None)) for k in path]
         if "aux_loss" not in names:
             raise ValueError(
@@ -58,7 +74,7 @@ def _forward_with_aux(state, params, images, aux_weight: float):
                 f"'aux_loss' entries may join the training objective"
             )
         aux = aux + jnp.sum(leaf)
-    return logits, aux
+    return logits, aux, counters
 
 
 def _train_step(state, batch, aux_weight: float = 0.0):
@@ -70,19 +86,19 @@ def _train_step(state, batch, aux_weight: float = 0.0):
     mask = batch.get("mask")
 
     def loss_fn(params):
-        logits, aux = _forward_with_aux(
+        logits, aux, counters = _forward_with_aux(
             state, params, batch["image"], aux_weight)
         with jax.named_scope("loss"):
             ce = cross_entropy(logits, batch["label"], mask)
-        return ce + aux_weight * aux, (ce, logits)
+        return ce + aux_weight * aux, (ce, logits, counters)
 
-    (_, (loss, logits)), grads = jax.value_and_grad(
+    (_, (loss, logits, counters)), grads = jax.value_and_grad(
         loss_fn, has_aux=True)(state.params)
     new_state = state.apply_gradients(grads)
     with jax.named_scope("loss"):
         metrics = metrics_update(
             metrics_init(), loss, logits, batch["label"], mask)
-    return new_state, metrics
+    return new_state, add_routing(metrics, counters)
 
 
 def make_accum_train_step_fn(accum: int, aux_weight: float = 0.0):
@@ -119,34 +135,37 @@ def make_accum_train_step_fn(accum: int, aux_weight: float = 0.0):
         def body(carry, mb):
             g_acc, m_acc = carry
             mask = mb.get("mask")
-            n = (jnp.sum(mask.astype(jnp.float32)) if mask is not None
+            weights = example_weights(mb["label"], mask)
+            n = (jnp.sum(weights.astype(jnp.float32))
+                 if weights is not None
                  else jnp.asarray(float(mb["label"].shape[0])))
 
             def loss_fn(params):
-                logits, aux = _forward_with_aux(
+                logits, aux, counters = _forward_with_aux(
                     state, params, mb["image"], aux_weight)
                 # per-example SUM: micro-means weighted by real count so
                 # the accumulated gradient equals the full-batch gradient
                 # even when eval-style masks straddle micro-batches.
                 with jax.named_scope("loss"):
                     ce_sum = cross_entropy(logits, mb["label"], mask) * n
-                return ce_sum + aux_weight * aux * n, (ce_sum, logits)
+                return ce_sum + aux_weight * aux * n, (
+                    ce_sum, logits, counters)
 
-            (_, (loss_sum_mb, logits)), g = jax.value_and_grad(
+            (_, (loss_sum_mb, logits, counters)), g = jax.value_and_grad(
                 loss_fn, has_aux=True
             )(state.params)
             g_acc = jax.tree_util.tree_map(jnp.add, g_acc, g)
             with jax.named_scope("loss"):
                 loss_mean = loss_sum_mb / jnp.maximum(n, 1.0)
-                m_acc = metrics_update(
-                    m_acc, loss_mean, logits, mb["label"], mask)
+                m_acc = add_routing(metrics_update(
+                    m_acc, loss_mean, logits, mb["label"], mask), counters)
             return (g_acc, m_acc), None
 
         zeros = jax.tree_util.tree_map(
             lambda p: jnp.zeros(jnp.shape(p), jnp.result_type(p)), state.params
         )
         (grads_sum, metrics), _ = lax.scan(
-            body, (zeros, metrics_init()), micro
+            body, (zeros, metrics_init(_counts_routing(state))), micro
         )
         total = jnp.maximum(metrics.count, 1.0)
         grads = jax.tree_util.tree_map(lambda g: g / total, grads_sum)
@@ -268,11 +287,13 @@ def accumulate_metrics(acc, m):
     """Fold one step's MetricState into a running accumulator — the scan
     bodies' shared reduction, public so the overlapped-ZeRO epoch
     (``parallel/zero_overlap.py``) accumulates with the identical op."""
-    return MetricState(
-        acc.loss_sum + m.loss_sum,
-        acc.correct + m.correct,
-        acc.count + m.count,
-    )
+    return metrics_merge(acc, m)
+
+
+def _counts_routing(state) -> bool:
+    """Whether a train step on ``state`` returns routing counters, which
+    a scan's metric carry then has to hold from its first step."""
+    return bool(getattr(state, "counters", False))
 
 
 _accumulate = accumulate_metrics
@@ -297,7 +318,8 @@ def _make_epoch(mesh, axis, state_sharding, step_fn, train, indexed):
                 st, m = step_fn(st, batch_of(x))
                 return (st, _accumulate(acc, m)), None
 
-            (state, acc), _ = lax.scan(body, (state, metrics_init()), xs)
+            (state, acc), _ = lax.scan(
+                body, (state, metrics_init(_counts_routing(state))), xs)
             return state, acc
 
         def body(acc, x):

@@ -57,7 +57,7 @@ from pytorch_distributed_mnist_tpu.train.steps import (
     make_train_step,
     precompile,
 )
-from pytorch_distributed_mnist_tpu.utils.profiling import phase
+from pytorch_distributed_mnist_tpu.utils.profiling import phase, routing_log
 
 
 def _meters(ms: Optional[MetricState]) -> Tuple[Average, Accuracy]:
@@ -67,10 +67,15 @@ def _meters(ms: Optional[MetricState]) -> Tuple[Average, Accuracy]:
     matching the reference meters' zero-division guard (``:37-39, 55-57``).
     """
     loss, acc = Average(), Accuracy()
-    count = 0 if ms is None else int(ms.count)
+    if ms is None:
+        return loss, acc
+    ms = jax.device_get(ms)  # the sync; the routing counters ride along
+    count = int(ms.count)
     if count:
         loss.update(float(ms.loss_sum) / count, count)
         acc.update(int(ms.correct), count)
+    if ms.routing is not None:
+        routing_log.record(ms.routing)
     return loss, acc
 
 

@@ -174,6 +174,58 @@ class StagingLog:
 staging_log = StagingLog()
 
 
+class RoutingLog:
+    """What the expert layers counted of their routing, pass by pass.
+
+    A model with top-k expert layers that hold a share of the experts
+    (``models/moe.py SparseExperts``) counts, in every layer and step, the
+    (token, choice) pairs that landed on an expert held here, the pairs
+    routed in all, the pairs dropped (those that named a held expert and
+    were not served; the layer drops none) and the fullest held expert's
+    tokens over the mean. The
+    counts ride out of the pass's program in ``MetricState.routing``
+    (``ops/metrics.py ROUTING_COUNTERS``) and the trainer records them
+    here when it reads the pass's metrics: no host sync of their own.
+    """
+
+    def __init__(self) -> None:
+        self._lock = threading.Lock()
+        self.reset()
+
+    def reset(self) -> None:
+        with self._lock:
+            self._sums = None
+            self._passes = 0
+
+    def record(self, counters) -> None:
+        """One pass's summed counters, ``(len(ROUTING_COUNTERS),)``."""
+        with self._lock:
+            values = [float(x) for x in counters]
+            self._sums = values if self._sums is None else [
+                a + b for a, b in zip(self._sums, values)]
+            self._passes += 1
+
+    def summary(self) -> Dict:
+        """``{}`` when nothing was recorded (a model without such layers),
+        else the sums and the two ratios the benchmark reads."""
+        from pytorch_distributed_mnist_tpu.ops.metrics import (
+            ROUTING_COUNTERS,
+        )
+
+        with self._lock:
+            if self._sums is None:
+                return {}
+            out = dict(zip(ROUTING_COUNTERS, self._sums))
+            out["passes"] = self._passes
+            out["local_pair_share"] = out["landed"] / max(out["routed"], 1.0)
+            out["load_max_over_mean"] = (
+                out["max_over_mean"] / max(out["summands"], 1.0))
+            return out
+
+
+routing_log = RoutingLog()
+
+
 def comm_overlap_fraction(step_ms: float, compute_ms: float,
                           comm_ms: float) -> Optional[float]:
     """How much of a step's measured communication cost is hidden behind

@@ -153,3 +153,36 @@ def test_no_kernel_was_interpreted():
 
     counts = pallas_lowerings.snapshot()
     assert counts["interpret"] == 0 and counts["mosaic"] > 0, counts
+
+
+# The decoder's attention at its head size and grouping, T = 1,024 (two
+# blocks of 512, so the window's band and the causal triangle both skip a
+# block): bf16 operands as the training cell passes them, against the dense
+# masked oracle. (H_q, H_kv, window).
+WINDOW_CASES = [(8, 1, 512), (6, 1, None), (16, 2, 200)]
+
+
+@pytest.mark.parametrize("heads,kv_heads,window", WINDOW_CASES)
+def test_flash_window_grouped_heads_on_tpu(heads, kv_heads, window):
+    t, d = 1024, 128
+    ks = jax.random.split(jax.random.key(1), 3)
+    q = jax.random.normal(ks[0], (2, t, heads, d), jnp.bfloat16)
+    k = jax.random.normal(ks[1], (2, t, kv_heads, d), jnp.bfloat16)
+    v = jax.random.normal(ks[2], (2, t, kv_heads, d), jnp.bfloat16)
+
+    def loss(f):
+        return lambda *a: jnp.sum(jnp.sin(
+            f(*a, causal=True, window=window).astype(jnp.float32)))
+
+    out = flash_attention(q, k, v, causal=True, window=window)
+    ref = full_attention(q, k, v, causal=True, window=window)
+    assert out.dtype == jnp.bfloat16
+    assert float(jnp.max(jnp.abs(out.astype(jnp.float32)
+                                 - ref.astype(jnp.float32)))) < 3e-2
+
+    grads = jax.grad(loss(flash_attention), argnums=(0, 1, 2))(q, k, v)
+    grads_ref = jax.grad(loss(full_attention), argnums=(0, 1, 2))(q, k, v)
+    for g, gr in zip(grads, grads_ref):
+        gr = gr.astype(jnp.float32)
+        err = float(jnp.max(jnp.abs(g.astype(jnp.float32) - gr)))
+        assert err < 0.03 * float(jnp.max(jnp.abs(gr))) + 1e-2
