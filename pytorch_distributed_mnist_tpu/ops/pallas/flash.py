@@ -24,7 +24,25 @@ What the kernels take (``flash_attention``'s docstring has the contract):
   wholly above the diagonal or wholly behind the window are *skipped*, not
   masked: each program loops over the key (or query) blocks of its band
   only, and masks only the tiles the diagonal, the window's edge or the
-  padding cuts through;
+  padding cuts through. Where the window is a multiple of the block (and
+  the length is unpadded) those two tiles of a query block are evaluated
+  as *one*: with local row ``r`` and column ``c`` the diagonal tile keeps
+  ``c <= r`` and the tile at the window's edge, ``window / block`` key
+  blocks back, keeps ``c > r``, together one tile of pairs. The folded
+  tile's scores go through one max, ``exp`` and sum (no running max, no
+  rescale at ``window == block``), and each needed pair is evaluated once
+  (1.03 evaluations a needed pair at T = 8192, block and window 512, for
+  2.00; ``tile_counts``). It is put together by quadrants of half a block
+  (``_fold_nt``, ``_fold_nn``): the lower left is the diagonal block's
+  whole, the upper right the edge block's, and the two on the diagonal are
+  selected from both by a mask of two local iotas, so that of either
+  block's product only the three quadrants that hold needed pairs are
+  multiplied. The backward's kernels fold the same way (``dkv``: key block
+  ``j`` with query blocks ``j`` and ``j + window / block``, the row
+  statistics selected with them). The query blocks with no edge block (the
+  first ``window / block``) and the key blocks with no later query block
+  keep the masked tiles; any other window, and every padded length,
+  compiles the masked schedule alone;
 - grouped key-value heads: ``k`` and ``v`` may hold ``H / G`` heads; query
   head ``h`` reads head ``h // G`` through the block index, so the repeated
   keys and values are never written anywhere. ``dk`` and ``dv`` come out of
@@ -53,13 +71,15 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from pytorch_distributed_mnist_tpu.ops.attention import CORE_SCOPE, NEG_INF
 from pytorch_distributed_mnist_tpu.ops.pallas.backend import should_interpret
+from pytorch_distributed_mnist_tpu.utils.profiling import flash_schedules
 
-__all__ = ["flash_attention", "sharded_flash_attention"]
+__all__ = ["flash_attention", "sharded_flash_attention", "tile_counts"]
 
 LANES = 128
 # Scoped VMEM a program may use: two buffers each of a head's whole keys
@@ -102,27 +122,28 @@ def _visible(iq, jk, block, t_real, causal, window, keys_first=False):
     return keep
 
 
-def _key_blocks(iq, block, n, t_real, causal, window):
+def _key_blocks(iq, block, n, t_real, causal, window, xp=jnp):
     """Key blocks of query block ``iq``: ``[lo, hi)`` holds every visible
     key; inside it ``[a, b)`` are the blocks every query of the block sees
-    whole, which need no mask. Integers, traced where ``iq`` is."""
+    whole, which need no mask. Integers, traced where ``iq`` is; with
+    ``xp=np`` and a Python ``iq`` plain numbers (``tile_counts``)."""
     q0 = iq * block
     q1 = q0 + block - 1
     lo, hi, a = 0, n, 0
     b = t_real // block  # key blocks without padding
     if causal:
-        hi = jnp.minimum(iq + 1, n)
-        b = jnp.minimum(b, (q0 + 1) // block)  # last key not after q0
+        hi = xp.minimum(iq + 1, n)
+        b = xp.minimum(b, (q0 + 1) // block)  # last key not after q0
     if window is not None:
-        lo = jnp.maximum(q0 - window + 1, 0) // block
-        a = (jnp.maximum(q1 - window + 1, 0) + block - 1) // block
+        lo = xp.maximum(q0 - window + 1, 0) // block
+        a = (xp.maximum(q1 - window + 1, 0) + block - 1) // block
     # A block with padded query rows is masked throughout.
-    b = jnp.where((iq + 1) * block <= t_real, b, 0)
-    a = jnp.clip(a, lo, hi)
-    return lo, a, jnp.clip(b, a, hi), hi
+    b = xp.where((iq + 1) * block <= t_real, b, 0)
+    a = xp.clip(a, lo, hi)
+    return lo, a, xp.clip(b, a, hi), hi
 
 
-def _query_blocks(jk, block, n, t_real, causal, window):
+def _query_blocks(jk, block, n, t_real, causal, window, xp=jnp):
     """The same for key block ``jk``: the query blocks that see any of its
     keys, and those among them that see all of them unmasked."""
     k0 = jk * block
@@ -130,15 +151,75 @@ def _query_blocks(jk, block, n, t_real, causal, window):
     lo, hi, a = 0, n, 0
     b = t_real // block  # query blocks without padding
     if causal:
-        lo = jnp.minimum(jk, n)
+        lo = xp.minimum(jk, n)
         a = (k1 + block - 1) // block  # first block wholly at or after k1
     if window is not None:
-        hi = jnp.minimum((k1 + window - 1) // block + 1, n)
+        hi = xp.minimum((k1 + window - 1) // block + 1, n)
         # last row of block i, i*block + block - 1, still sees k0
-        b = jnp.minimum(b, (k0 + window - block) // block + 1)
-    b = jnp.where((jk + 1) * block <= t_real, b, 0)
-    a = jnp.clip(a, lo, hi)
-    return lo, a, jnp.clip(b, a, hi), hi
+        b = xp.minimum(b, (k0 + window - block) // block + 1)
+    b = xp.where((jk + 1) * block <= t_real, b, 0)
+    a = xp.clip(a, lo, hi)
+    return lo, a, xp.clip(b, a, hi), hi
+
+
+def _fold_width(t, block, n, window):
+    """``window / block`` where a banded call folds (module docstring): the
+    window a multiple of the block, the length unpadded, and some query
+    block far enough in to have an edge block. Else 0, and the call
+    compiles the masked schedule alone."""
+    if window is None or window % block or t != n * block:
+        return 0
+    width = window // block
+    return width if width < n else 0
+
+
+def _on_diagonal(block, keys_first=False):
+    """The pairs a diagonal tile keeps, key not after query, by local row
+    and column: ``(block, block)`` bool, queries along the rows or, where
+    ``keys_first``, along the columns. Where the window is a multiple of
+    the block, the tile at the window's edge keeps exactly the others."""
+    r = jax.lax.broadcasted_iota(jnp.int32, (block, block), 0)
+    c = jax.lax.broadcasted_iota(jnp.int32, (block, block), 1)
+    return r <= c if keys_first else c <= r
+
+
+def _fold_nt(x, y_lo, y_up, low):
+    """The folded ``(block, block)`` tile of ``x y^T``: its entries at and
+    below the diagonal from ``y_lo``, those above from ``y_up``. By
+    quadrants of half a block: the lower left is ``y_lo``'s whole, the
+    upper right ``y_up``'s, and only the two on the diagonal are selected,
+    by ``low`` (``(block / 2, block / 2)``; it says on which side the
+    diagonal itself falls). The halves of the two products that a select
+    over the whole tile would drop are never multiplied: 6 quarter tiles
+    for 8."""
+    h = x.shape[0] // 2
+    left = _dot(x, y_lo[:h], _NT)    # columns [0, h): every row reads y_lo
+    right = _dot(x, y_up[h:], _NT)   # columns [h, block): every row y_up
+    top_left = jnp.where(low, left[:h], _dot(x[:h], y_up[:h], _NT))
+    bottom_right = jnp.where(low, _dot(x[h:], y_lo[h:], _NT), right[h:])
+    return jnp.concatenate(
+        [jnp.concatenate([top_left, left[h:]], axis=0),
+         jnp.concatenate([right[:h], bottom_right], axis=0)], axis=1)
+
+
+def _fold_nn(p, y_lo, y_up, low):
+    """``p_lo y_lo + p_up y_up`` for a folded tile ``p`` (``_fold_nt``'s
+    layout): its entries at and below the diagonal meet ``y_lo`` and the
+    others ``y_up``, by the same quadrants and again in 6 quarter tiles."""
+    h = p.shape[0] // 2
+    dtype = y_lo.dtype
+    top_left, bottom_right = p[:h, :h], p[h:, h:]
+    to_lo = jnp.concatenate(
+        [jnp.where(low, top_left, 0.0), p[h:, :h]], axis=0)
+    to_up = jnp.concatenate(
+        [p[:h, h:], jnp.where(low, 0.0, bottom_right)], axis=0)
+    return (_dot(to_lo.astype(dtype), y_lo[:h], _NN)
+            + _dot(to_up.astype(dtype), y_up[h:], _NN)
+            + jnp.concatenate(
+                [_dot(jnp.where(low, 0.0, top_left).astype(dtype),
+                      y_up[:h], _NN),
+                 _dot(jnp.where(low, bottom_right, 0.0).astype(dtype),
+                      y_lo[h:], _NN)], axis=0))
 
 
 def _banded_loop(ranges, body, carry):
@@ -180,9 +261,10 @@ def _rows(ref, j, block):
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block: int,
-                causal: bool, window, scale: float, t_real: int):
+                causal: bool, window, scale: float, t_real: int, fold: int):
     """One (batch, head, q-block) program: stream the key blocks of the
-    band, online softmax.
+    band, online softmax; where the call folds (``fold`` blocks a window),
+    a query block that has an edge block starts from its folded tile.
 
     Emits both the normalized output block and the row logsumexp
     ``lse = m + log(l)`` — the single residual the backward kernels need to
@@ -207,16 +289,42 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block: int,
         l = l * corr + jnp.sum(p, axis=-1, keepdims=True)
         return o * corr + _dot(p.astype(v_blk.dtype), v_blk, _NN), m_new, l
 
-    o, m, l = _banded_loop(
-        _key_blocks(iq, block, k_ref.shape[0] // block, t_real, causal,
-                    window),
-        body,
-        (jnp.zeros((block, d), jnp.float32),
-         jnp.full((block, 1), NEG_INF, jnp.float32),
-         jnp.zeros((block, 1), jnp.float32)))
-    o_ref[...] = (o / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
-    lse = jnp.where(l > 0, m + jnp.log(jnp.maximum(l, 1e-30)), NEG_INF)
-    lse_ref[...] = _col_to_row(lse)
+    def finish(o, m, l):
+        o_ref[...] = (o / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
+        lse = jnp.where(l > 0, m + jnp.log(jnp.maximum(l, 1e-30)), NEG_INF)
+        lse_ref[...] = _col_to_row(lse)
+
+    def banded():
+        finish(*_banded_loop(
+            _key_blocks(iq, block, k_ref.shape[0] // block, t_real, causal,
+                        window),
+            body,
+            (jnp.zeros((block, d), jnp.float32),
+             jnp.full((block, 1), NEG_INF, jnp.float32),
+             jnp.zeros((block, 1), jnp.float32))))
+
+    def folded():
+        # Every row holds ``block`` pairs: a plain softmax, no rescale.
+        low = _on_diagonal(block // 2)
+        k_d, v_d = _rows(k_ref, iq, block), _rows(v_ref, iq, block)
+        k_e, v_e = (_rows(k_ref, iq - fold, block),
+                    _rows(v_ref, iq - fold, block))
+        s = _fold_nt(q, k_d, k_e, low) * scale
+        m = jnp.max(s, axis=-1, keepdims=True)
+        p = jnp.exp(s - m)
+        l = jnp.sum(p, axis=-1, keepdims=True)
+        carry = _fold_nn(p, v_d, v_e, low), m, l
+        if fold > 1:  # the whole blocks between edge and diagonal
+            carry = jax.lax.fori_loop(
+                iq - fold + 1, iq, functools.partial(body, masked=False),
+                carry)
+        finish(*carry)
+
+    if fold:
+        pl.when(iq < fold)(banded)
+        pl.when(iq >= fold)(folded)
+    else:
+        banded()
 
 
 def _block_sizes(t: int, block: int | None = None):
@@ -224,13 +332,47 @@ def _block_sizes(t: int, block: int | None = None):
     # a divisor of T — a prime T would degrade to block 1); padded K
     # positions are masked inside the kernels, padded Q rows sliced off.
     # Default: 512 from T = 512 up (a (512, 512) float32 score tile is
-    # 1 MB; fewer, larger loop steps, and a window of 512 then touches two
-    # key blocks a query block), the MXU tile 128 from T = 128 up, and
-    # below that T itself rounded up to the sublane count.
+    # 1 MB; fewer, larger loop steps, and a window of 512 is then one
+    # folded tile a query block: its own key block and the one before it,
+    # each pair evaluated once), the MXU tile 128 from T = 128 up, and
+    # below that T itself rounded up to the sublane count. 256 ran slower
+    # on the v5e for both kinds of layer, folded or not (PERF.md).
     if block is None:
         block = 512 if t >= 512 else 128 if t >= 128 else ((t + 7) // 8) * 8
     t_pad = ((t + block - 1) // block) * block
     return block, t_pad
+
+
+def tile_counts(t: int, block: int | None, causal: bool, window) -> dict:
+    """What one sequence and head of a call costs in tiles, by the ranges
+    the kernels loop over: ``needed_pairs`` (query, key) pairs the mask
+    keeps, ``evaluated_pairs`` the tiles' ``block * block`` each, and the
+    tiles by body: ``plain`` (no mask), ``masked`` (``_visible``),
+    ``folded`` (diagonal and edge in one). By query block, as the forward
+    and ``flash_bwd_dq`` go; ``flash_bwd_dkv`` visits the same tiles by
+    key block."""
+    block, t_pad = _block_sizes(t, block)
+    n = t_pad // block
+    fold = _fold_width(t, block, n, window)
+    plain = masked = folded = 0
+    for iq in range(n):
+        if fold and iq >= fold:
+            folded += 1
+            plain += fold - 1
+        else:
+            lo, a, b, hi = (int(x) for x in _key_blocks(
+                iq, block, n, t, causal, window, xp=np))
+            plain += b - a
+            masked += (a - lo) + (hi - b)
+    if not causal:
+        needed = t * t
+    elif window is None or window >= t:
+        needed = t * (t + 1) // 2
+    else:
+        needed = window * (window + 1) // 2 + (t - window) * window
+    return {"needed_pairs": needed,
+            "evaluated_pairs": (plain + masked + folded) * block * block,
+            "plain": plain, "masked": masked, "folded": folded}
 
 
 class _Layout:
@@ -297,9 +439,10 @@ def _flash_forward(q, k, v, causal: bool, window, scale: float,
     block, t_pad = _block_sizes(t, block_override)
     n = t_pad // block
     lay = _Layout(d, t, t_pad)
+    flash_schedules.record(tile_counts(t, block, causal, window))
     kernel = functools.partial(
         _fwd_kernel, block=block, causal=causal, window=window,
-        scale=scale, t_real=t)
+        scale=scale, t_real=t, fold=_fold_width(t, block, n, window))
     out, lse = pl.pallas_call(
         kernel,
         grid=(b, h, n),
@@ -327,7 +470,8 @@ def _flash_forward(q, k, v, causal: bool, window, scale: float,
 
 
 def _dq_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref, dq_ref, *,
-               block: int, causal: bool, window, scale: float, t_real: int):
+               block: int, causal: bool, window, scale: float, t_real: int,
+               fold: int):
     """Grid (B, H, q-block): stream the band's K/V, accumulate this
     q-block's dQ."""
     iq = pl.program_id(2)
@@ -344,22 +488,46 @@ def _dq_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref, dq_ref, *,
         ds = p * (_dot(do, v_blk, _NT) - delta)
         return dq + _dot(ds.astype(k_blk.dtype), k_blk, _NN)
 
-    dq = _banded_loop(
-        _key_blocks(iq, block, k_ref.shape[0] // block, t_real, causal,
-                    window),
-        body, jnp.zeros(q.shape, jnp.float32))
-    dq_ref[...] = (scale * dq).astype(dq_ref.dtype)
+    def finish(dq):
+        dq_ref[...] = (scale * dq).astype(dq_ref.dtype)
+
+    def banded():
+        finish(_banded_loop(
+            _key_blocks(iq, block, k_ref.shape[0] // block, t_real, causal,
+                        window),
+            body, jnp.zeros(q.shape, jnp.float32)))
+
+    def folded():
+        low = _on_diagonal(block // 2)
+        k_d, v_d = _rows(k_ref, iq, block), _rows(v_ref, iq, block)
+        k_e, v_e = (_rows(k_ref, iq - fold, block),
+                    _rows(v_ref, iq - fold, block))
+        p = jnp.exp(_fold_nt(q, k_d, k_e, low) * scale - lse)
+        ds = p * (_fold_nt(do, v_d, v_e, low) - delta)
+        dq = _fold_nn(ds, k_d, k_e, low)
+        if fold > 1:
+            dq = jax.lax.fori_loop(
+                iq - fold + 1, iq, functools.partial(body, masked=False), dq)
+        finish(dq)
+
+    if fold:
+        pl.when(iq < fold)(banded)
+        pl.when(iq >= fold)(folded)
+    else:
+        banded()
 
 
 def _dkv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref,
                 dk_ref, dv_ref, *, block: int, causal: bool, window,
-                scale: float, t_real: int):
+                scale: float, t_real: int, fold: int):
     """Grid (B, H, k-block): stream the band's Q/dO rows, accumulate one
     query head's share of dK and dV. The tiles are transposed, keys along
     the rows, so that the per-query statistics broadcast as the lane-major
     rows they are stored as and all four matmuls are plain or
-    transposed-right."""
+    transposed-right. Folded, key block ``jk`` meets query block ``jk``
+    through the diagonal and ``jk + fold`` through the window's edge."""
     jk = pl.program_id(2)
+    n = q_ref.shape[0] // block
     k_blk, v_blk = k_ref[...], v_ref[...]     # (BK, D)
 
     def body(i, carry, masked):
@@ -375,13 +543,40 @@ def _dkv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref,
         ds = p * (_dot(v_blk, do, _NT) - delta)
         return dk + _dot(ds.astype(q.dtype), q, _NN), dv
 
-    zero = jnp.zeros(k_blk.shape, jnp.float32)
-    dk, dv = _banded_loop(
-        _query_blocks(jk, block, q_ref.shape[0] // block, t_real, causal,
-                      window),
-        body, (zero, zero))
-    dk_ref[...] = (scale * dk).astype(dk_ref.dtype)
-    dv_ref[...] = dv.astype(dv_ref.dtype)
+    def finish(dk, dv):
+        dk_ref[...] = (scale * dk).astype(dk_ref.dtype)
+        dv_ref[...] = dv.astype(dv_ref.dtype)
+
+    def banded():
+        zero = jnp.zeros(k_blk.shape, jnp.float32)
+        finish(*_banded_loop(
+            _query_blocks(jk, block, n, t_real, causal, window),
+            body, (zero, zero)))
+
+    def folded():
+        # Keys along the rows: below the diagonal is the edge block's.
+        diag = _on_diagonal(block, keys_first=True)
+        low = jnp.logical_not(_on_diagonal(block // 2, keys_first=True))
+        q_d, do_d = _rows(q_ref, jk, block), _rows(do_ref, jk, block)
+        q_e, do_e = (_rows(q_ref, jk + fold, block),
+                     _rows(do_ref, jk + fold, block))
+        lse = jnp.where(diag, lse_ref[jk], lse_ref[jk + fold])
+        delta = jnp.where(diag, delta_ref[jk], delta_ref[jk + fold])
+        p = jnp.exp(_fold_nt(k_blk, q_e, q_d, low) * scale - lse)
+        dv = _fold_nn(p, do_e, do_d, low)
+        ds = p * (_fold_nt(v_blk, do_e, do_d, low) - delta)
+        carry = _fold_nn(ds, q_e, q_d, low), dv
+        if fold > 1:
+            carry = jax.lax.fori_loop(
+                jk + 1, jk + fold, functools.partial(body, masked=False),
+                carry)
+        finish(*carry)
+
+    if fold:
+        pl.when(jk + fold >= n)(banded)
+        pl.when(jk + fold < n)(folded)
+    else:
+        banded()
 
 
 def _sum_over_group(x, group: int, dtype):
@@ -409,8 +604,9 @@ def _flash_backward(q, k, v, o, lse, g, causal: bool, window, scale: float,
         delta = jnp.pad(delta, ((0, 0), (0, 0), (0, t_pad - t)))
     delta = delta.reshape(b, h, n, 1, block)
 
+    flash_schedules.record(tile_counts(t, block, causal, window))
     common = dict(block=block, causal=causal, window=window, scale=scale,
-                  t_real=t)
+                  t_real=t, fold=_fold_width(t, block, n, window))
     blk = lay.spec(block, blocked=True)
     blk_kv = lay.spec(block, blocked=True, group=group)
     whole = lay.spec(t_pad, blocked=False)

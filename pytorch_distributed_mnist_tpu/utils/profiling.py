@@ -298,7 +298,8 @@ def device_report() -> dict:
     ``/healthz`` reply, so nothing is read without its device:
     ``platform``/``device_kind``/``device_count`` in jax's own words,
     ``input_backend`` (the host input path in use: ``native`` C++ or
-    ``numpy``), and ``pallas_lowerings`` (:class:`LoweringLog`)."""
+    ``numpy``), ``pallas_lowerings`` (:class:`LoweringLog`) and
+    ``flash_schedules`` (:class:`ScheduleLog`)."""
     from pytorch_distributed_mnist_tpu.data import native
 
     devices = jax.devices()
@@ -306,7 +307,8 @@ def device_report() -> dict:
             "device_kind": devices[0].device_kind,
             "device_count": len(devices),
             "input_backend": "native" if native.available() else "numpy",
-            "pallas_lowerings": pallas_lowerings.snapshot()}
+            "pallas_lowerings": pallas_lowerings.snapshot(),
+            "flash_schedules": flash_schedules.snapshot()}
 
 
 class LoweringLog:
@@ -333,6 +335,40 @@ class LoweringLog:
 # Process-wide for the same reason as compile_log: kernels are traced from
 # whatever thread compiles the program that contains them.
 pallas_lowerings = LoweringLog()
+
+
+class ScheduleLog:
+    """The tile schedules of the flash attention calls traced in this
+    process (``ops/pallas/flash.py`` records ``tile_counts`` once a
+    forward and once a backward, beside its ``pallas_lowerings`` entry):
+    how many there were, how many of them fold the band's two half-masked
+    tiles into one, and over those the pairs the tiles evaluate a pair
+    the mask keeps — 1 is a kernel that evaluates no pair in vain."""
+
+    def __init__(self) -> None:
+        self._lock = threading.Lock()
+        self._sites = self._folded_sites = 0
+        self._needed = self._evaluated = 0
+
+    def record(self, counts: Dict[str, int]) -> None:
+        with self._lock:
+            self._sites += 1
+            if counts["folded"]:
+                self._folded_sites += 1
+                self._needed += counts["needed_pairs"]
+                self._evaluated += counts["evaluated_pairs"]
+
+    def snapshot(self) -> Dict:
+        with self._lock:
+            return {
+                "sites": self._sites,
+                "folded_sites": self._folded_sites,
+                "folded_evaluated_over_needed": (
+                    round(self._evaluated / self._needed, 4)
+                    if self._needed else None)}
+
+
+flash_schedules = ScheduleLog()
 
 
 class CompileLog:

@@ -2,7 +2,10 @@
 on the CPU, against the dense masked oracle (``full_attention``): forward
 and backward, both layouts (head size 128: lane-blocked; smaller:
 head-major), blocks smaller than, equal to and larger than the window,
-padded lengths, and the block ranges that decide what is skipped."""
+padded lengths, the block ranges that decide what is skipped, and the
+folded schedule of a window that is a multiple of the block (the band's
+diagonal and edge tiles evaluated as one): its results, what it covers,
+its counts, and which calls it leaves alone."""
 
 import jax
 import jax.numpy as jnp
@@ -32,9 +35,20 @@ CASES = [
     (32, 2, 1, 128, 8, 16),    # head size 128: the lane-blocked layout
     (40, 2, 2, 128, None, 16), # lane-blocked and padded
 ]
+# Windows that are multiples of the block at an unpadded length: the folded
+# schedule (as are the first and third of CASES).
+FOLD_CASES = [
+    (64, 4, 2, 16, 16, 8),     # window == 2 x block: a plain tile between
+    (96, 2, 2, 16, 48, 16),    # 3 x block
+    (64, 6, 2, 16, 16, 16),    # head-major, three query heads a kv head
+    (64, 4, 2, 128, 16, 16),   # lane-blocked, grouped, window == block
+    (64, 8, 2, 128, 32, 16),   # lane-blocked, four a group, 2 x block
+    (16, 2, 1, 16, 16, 16),    # T of one block: no edge block anywhere
+    (32, 2, 1, 16, 32, 8),     # window == T: no query block has an edge
+]
 
 
-@pytest.mark.parametrize("t,h,kv,d,window,block", CASES)
+@pytest.mark.parametrize("t,h,kv,d,window,block", CASES + FOLD_CASES)
 def test_flash_window_forward_matches_dense(t, h, kv, d, window, block):
     q, k, v = _qkv(t, h, kv, d)
     got = flash_attention(q, k, v, causal=True, window=window, block=block)
@@ -42,7 +56,7 @@ def test_flash_window_forward_matches_dense(t, h, kv, d, window, block):
     np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)
 
 
-@pytest.mark.parametrize("t,h,kv,d,window,block", CASES)
+@pytest.mark.parametrize("t,h,kv,d,window,block", CASES + FOLD_CASES)
 def test_flash_window_backward_matches_dense(t, h, kv, d, window, block):
     q, k, v = _qkv(t, h, kv, d, seed=1)
 
@@ -77,6 +91,30 @@ def test_flash_bf16_operands_keep_their_type():
     assert [g.dtype for g in grads] == [jnp.bfloat16] * 3
 
 
+@pytest.mark.parametrize("t,h,kv,d,window,block",
+                         [(64, 4, 2, 16, 16, 16), (64, 4, 2, 128, 32, 16)])
+def test_flash_folded_bf16_matches_dense(t, h, kv, d, window, block):
+    q, k, v = (x.astype(jnp.bfloat16) for x in _qkv(t, h, kv, d, seed=4))
+    assert flash.tile_counts(t, block, True, window)["folded"]
+
+    def loss(f, **kw):
+        return lambda *a: jnp.sum(jnp.sin(f(
+            *a, causal=True, window=window, **kw).astype(jnp.float32)))
+
+    got = flash_attention(q, k, v, causal=True, window=window, block=block)
+    want = full_attention(q, k, v, causal=True, window=window)
+    assert got.dtype == jnp.bfloat16
+    np.testing.assert_allclose(got.astype(jnp.float32),
+                               want.astype(jnp.float32), atol=2e-2)
+    grads = jax.grad(loss(flash_attention, block=block), (0, 1, 2))(q, k, v)
+    wants = jax.grad(loss(full_attention), (0, 1, 2))(q, k, v)
+    for g, w in zip(grads, wants):
+        assert g.dtype == jnp.bfloat16
+        w = np.asarray(w.astype(jnp.float32))
+        np.testing.assert_allclose(g.astype(jnp.float32), w,
+                                   atol=0.03 * np.abs(w).max() + 1e-2)
+
+
 def test_window_mask_at_its_edges():
     """Query t sees keys t-window+1 .. t: the dense oracle's mask row."""
     from pytorch_distributed_mnist_tpu.ops.attention import (
@@ -92,39 +130,184 @@ def test_window_mask_at_its_edges():
             range(max(0, t - 3), t + 1))
 
 
-@pytest.mark.parametrize("t_real", [64, 50])
-@pytest.mark.parametrize("window", [None, 8, 24])
-@pytest.mark.parametrize("block", [8, 16])
-def test_block_ranges_cover_exactly_the_band(t_real, window, block):
-    """Every visible pair lies in [lo, hi); no pair of a block in [a, b)
-    is masked; blocks outside [lo, hi) hold no visible pair."""
-    n = -(-t_real // block)
+def _band(t_real, n, block, window):
     qi = np.arange(n * block)[:, None]
     ki = np.arange(n * block)[None, :]
     vis = (qi < t_real) & (ki < t_real) & (ki <= qi)
     if window is not None:
         vis &= ki > qi - window
+    return vis
+
+
+@pytest.mark.parametrize("t_real", [64, 50])
+@pytest.mark.parametrize("window", [None, 8, 16, 24, 32])
+@pytest.mark.parametrize("block", [8, 16])
+def test_block_ranges_cover_exactly_the_band(t_real, window, block):
+    """Every visible pair lies in [lo, hi); no pair of a block in [a, b)
+    is masked; blocks outside [lo, hi) hold no visible pair. And the
+    schedule the kernels run, by query block and by key block (folded
+    tiles where the call folds, else plain and masked ones), evaluates
+    every visible pair exactly once and no other pair of a masked or
+    folded tile."""
+    n = -(-t_real // block)
+    vis = _band(t_real, n, block, window)
+    fold = flash._fold_width(t_real, block, n, window)
+    assert bool(fold) == (window is not None and window % block == 0
+                          and t_real % block == 0)
+    low = np.arange(block)[None, :] <= np.arange(block)[:, None]  # c <= r
     for fn, by_query in ((flash._key_blocks, True),
                          (flash._query_blocks, False)):
+        seen = np.zeros(vis.shape, int)
+
+        def tile(i, j):  # of query block i and key block j
+            q, k = (i, j) if by_query else (j, i)
+            rows = slice(q * block, (q + 1) * block)
+            cols = slice(k * block, (k + 1) * block)
+            return vis[rows, cols], seen[rows, cols]
+
         for i in range(n):
             lo, a, b, hi = (int(x) for x in fn(
                 jnp.int32(i), block, n, t_real, True, window))
+            assert (lo, a, b, hi) == tuple(int(x) for x in fn(
+                i, block, n, t_real, True, window, xp=np))
             assert 0 <= lo <= a <= b <= hi <= n
             for j in range(n):
-                tile = (vis[i * block:(i + 1) * block,
-                            j * block:(j + 1) * block] if by_query else
-                        vis[j * block:(j + 1) * block,
-                            i * block:(i + 1) * block])
                 if not lo <= j < hi:
-                    assert not tile.any(), (fn.__name__, i, j)
+                    assert not tile(i, j)[0].any(), (fn.__name__, i, j)
                 if a <= j < b:
-                    assert tile.all(), (fn.__name__, i, j)
+                    assert tile(i, j)[0].all(), (fn.__name__, i, j)
+            # What the kernel of this block runs (``pl.when`` in each).
+            other = i - fold if by_query else i + fold
+            if fold and 0 <= other < n:
+                # the folded tile: the diagonal block's pairs at or below
+                # the diagonal, the edge block's above it
+                assert tile(i, i)[0][low].all()
+                assert not tile(i, i)[0][~low].any()
+                assert tile(i, other)[0][~low].all()
+                assert not tile(i, other)[0][low].any()
+                tile(i, i)[1][low] += 1
+                tile(i, other)[1][~low] += 1
+                for j in range(min(i, other) + 1, max(i, other)):
+                    assert tile(i, j)[0].all()
+                    tile(i, j)[1][...] += 1
+            else:
+                for j in range(lo, hi):
+                    if a <= j < b:
+                        tile(i, j)[1][...] += 1
+                    else:  # masked: what ``_visible`` keeps
+                        tile(i, j)[1][tile(i, j)[0]] += 1
+        np.testing.assert_array_equal(seen, vis.astype(int))
     # A window layer at block == window touches at most two key blocks.
     if window == block:
         for i in range(n):
             lo, _, _, hi = (int(x) for x in flash._key_blocks(
                 jnp.int32(i), block, n, t_real, True, window))
             assert hi - lo <= 2
+
+
+# (T, block, causal, window)
+COUNT_CASES = [
+    (64, 8, True, 8), (64, 8, True, 16), (64, 8, True, 24), (64, 16, True, 8),
+    (50, 16, True, 16), (64, 16, True, None), (48, 16, False, None),
+    (16, 16, True, 16), (64, 16, True, 64), (40, 8, True, 12),
+]
+
+
+@pytest.mark.parametrize("t,block,causal,window", COUNT_CASES)
+def test_tile_counts_match_a_count_over_the_mask(t, block, causal, window):
+    n = -(-t // block)
+    vis = _band(t, n, block, window) if causal else (
+        (np.arange(n * block)[:, None] < t) & (np.arange(n * block) < t))
+    tiles = vis.reshape(n, block, n, block).transpose(0, 2, 1, 3)
+    nonempty = int(tiles.any(axis=(2, 3)).sum())
+    whole = int(tiles.all(axis=(2, 3)).sum())
+    got = flash.tile_counts(t, block, causal, window)
+    fold = flash._fold_width(t, block, n, window)
+    assert got["needed_pairs"] == int(vis.sum())
+    assert got["folded"] == (max(n - fold, 0) if fold else 0)
+    # A fold makes one tile of two half-masked ones; nothing empty is run.
+    assert got["plain"] + got["masked"] + 2 * got["folded"] == nonempty
+    assert got["evaluated_pairs"] == block * block * (
+        got["plain"] + got["masked"] + got["folded"])
+    if t % block == 0:  # a padded block's whole tiles are run masked
+        assert got["plain"] == whole
+    # The kernel that goes by key block runs the same tiles.
+    by_key = [tuple(int(x) for x in flash._query_blocks(
+        j, block, n, t, causal, window, xp=np)) for j in range(n)]
+    folded = [j for j in range(n) if fold and j + fold < n]
+    assert len(folded) == got["folded"]
+    assert sum((hi - lo) for j, (lo, a, b, hi) in enumerate(by_key)
+               if j not in folded) + fold * len(folded) \
+        == got["plain"] + got["masked"] + got["folded"]
+    if not fold:  # what the ranges alone give, as before the fold
+        ranges = [tuple(int(x) for x in flash._key_blocks(
+            jnp.int32(i), block, n, t, causal, window)) for i in range(n)]
+        assert got["plain"] == sum(b - a for _, a, b, _ in ranges)
+        assert got["masked"] == sum(
+            (a - lo) + (hi - b) for lo, a, b, hi in ranges)
+
+
+def test_tile_counts_at_the_cell_shape():
+    """T 8,192, block 512 (the default), window 512: 1.03 evaluated pairs
+    a needed pair, 2.00 before the fold; the need is the benchmark's."""
+    from benchmark import flash_cost
+
+    got = flash.tile_counts(8192, None, True, 512)
+    assert got["needed_pairs"] == flash_cost.causal_pairs(8192, 512)
+    assert (got["plain"], got["masked"], got["folded"]) == (0, 1, 15)
+    assert got["evaluated_pairs"] / got["needed_pairs"] < 1.05
+    unfolded = 31 * 512 * 512  # two masked tiles a query block but the first
+    assert round(unfolded / got["needed_pairs"], 2) == 2.0
+    full = flash.tile_counts(8192, None, True, None)
+    assert full["needed_pairs"] == flash_cost.causal_pairs(8192)
+    assert (full["plain"], full["masked"], full["folded"]) == (120, 16, 0)
+
+
+# No window; a window that is no multiple of the block; a padded length.
+UNFOLDED = [(64, None, 16), (64, 12, 16), (50, 16, 16)]
+
+
+@pytest.mark.parametrize("t,window,block", UNFOLDED + [(64, 16, 16)])
+def test_only_a_window_of_whole_blocks_changes_the_program(
+        t, window, block, monkeypatch):
+    """The three calls the fold leaves alone trace the very program they
+    trace with the fold taken out; a window of whole blocks does not."""
+    q, k, v = _qkv(t, 2, 1, 16, b=1)
+
+    def program():
+        return str(jax.make_jaxpr(jax.grad(lambda *a: jnp.sum(
+            flash_attention(*a, causal=True, window=window, block=block)),
+            (0, 1, 2)))(q, k, v))
+
+    folds = (t, window, block) not in UNFOLDED
+    assert bool(flash.tile_counts(t, block, True, window)["folded"]) == folds
+    with_fold = program()
+    monkeypatch.setattr(flash, "_fold_width", lambda *a: 0)
+    without = program()
+    assert (with_fold != without) == folds
+    assert ("cond[" in with_fold) == folds  # the kernels' ``pl.when``
+
+
+def test_a_traced_call_records_its_schedule():
+    from pytorch_distributed_mnist_tpu.utils.profiling import (
+        device_report,
+        flash_schedules,
+    )
+
+    q, k, v = _qkv(64, 2, 1, 16, b=1)
+    before = flash_schedules.snapshot()
+    jax.make_jaxpr(lambda *a: flash_attention(*a, causal=True))(q, k, v)
+    after = flash_schedules.snapshot()
+    assert after["sites"] == before["sites"] + 1
+    assert after["folded_sites"] == before["folded_sites"]
+    # forward and backward of a folded call: two sites more
+    jax.make_jaxpr(jax.grad(lambda *a: jnp.sum(flash_attention(
+        *a, causal=True, window=16, block=16))))(q, k, v)
+    folded = flash_schedules.snapshot()
+    assert folded["sites"] == after["sites"] + 2
+    assert folded["folded_sites"] == after["folded_sites"] + 2
+    assert 1.0 <= folded["folded_evaluated_over_needed"] < 2.0
+    assert device_report()["flash_schedules"] == folded
 
 
 def test_flash_rejects_a_window_without_causal():

@@ -236,6 +236,8 @@ def test_metrics_file(tmp_path):
         == (device.platform, device.device_kind, jax.device_count())
     assert summary["input_backend"] in ("native", "numpy")
     assert set(summary["pallas_lowerings"]) == {"mosaic", "interpret"}
+    assert set(summary["flash_schedules"]) == {
+        "sites", "folded_sites", "folded_evaluated_over_needed"}
     assert "train_epoch" in summary["compile_stats"]["programs"]
     assert "history" not in summary  # the epoch rows above already say it
     for key in ("platform", "device_kind", "device_count"):

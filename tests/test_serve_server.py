@@ -107,6 +107,7 @@ def test_predict_healthz_stats(server):
         == (device.platform, device.device_kind, jax.device_count())
     assert health["input_backend"] in ("native", "numpy")
     assert set(health["pallas_lowerings"]) == {"mosaic", "interpret"}
+    assert "folded_sites" in health["flash_schedules"]
 
     reply = srv.post("/predict", {"images": images.tolist()})
     assert len(reply["predictions"]) == 5

@@ -158,17 +158,25 @@ def test_no_kernel_was_interpreted():
 # The decoder's attention at its head size and grouping, T = 1,024 (two
 # blocks of 512, so the window's band and the causal triangle both skip a
 # block): bf16 operands as the training cell passes them, against the dense
-# masked oracle. (H_q, H_kv, window).
-WINDOW_CASES = [(8, 1, 512), (6, 1, None), (16, 2, 200)]
+# masked oracle. The last case is the training cell's own window layer, 64
+# query heads on 8 key-value heads, at T = 2,048 (8,192 is too large for
+# the dense oracle in one piece): four blocks, so the folded schedule's
+# first block (no edge), folded blocks and, in ``flash_bwd_dkv``, last
+# block (no later query block) all occur; the one before it folds at block
+# 128, where a quadrant is half a vector register wide.
+# (B, T, H_q, H_kv, window).
+WINDOW_CASES = [(2, 1024, 8, 1, 512), (2, 1024, 6, 1, None),
+                (2, 1024, 16, 2, 200), (2, 256, 4, 2, 128),
+                (1, 2048, 64, 8, 512)]
 
 
-@pytest.mark.parametrize("heads,kv_heads,window", WINDOW_CASES)
-def test_flash_window_grouped_heads_on_tpu(heads, kv_heads, window):
-    t, d = 1024, 128
+@pytest.mark.parametrize("b,t,heads,kv_heads,window", WINDOW_CASES)
+def test_flash_window_grouped_heads_on_tpu(b, t, heads, kv_heads, window):
+    d = 128
     ks = jax.random.split(jax.random.key(1), 3)
-    q = jax.random.normal(ks[0], (2, t, heads, d), jnp.bfloat16)
-    k = jax.random.normal(ks[1], (2, t, kv_heads, d), jnp.bfloat16)
-    v = jax.random.normal(ks[2], (2, t, kv_heads, d), jnp.bfloat16)
+    q = jax.random.normal(ks[0], (b, t, heads, d), jnp.bfloat16)
+    k = jax.random.normal(ks[1], (b, t, kv_heads, d), jnp.bfloat16)
+    v = jax.random.normal(ks[2], (b, t, kv_heads, d), jnp.bfloat16)
 
     def loss(f):
         return lambda *a: jnp.sum(jnp.sin(
