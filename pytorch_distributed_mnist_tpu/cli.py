@@ -439,7 +439,7 @@ def build_parser() -> argparse.ArgumentParser:
                         "lever for --resume auto workflows. "
                         "JAX_COMPILATION_CACHE_DIR, when set, wins over "
                         "this flag; the default is <checkout>/.xla_cache "
-                        "(shared with bench.py and chip_smoke.py). Pass "
+                        "(shared with benchmark/run.py and chip_smoke.py). Pass "
                         "an empty string to disable caching entirely")
     p.add_argument("--no-precompile", action="store_true",
                    help="skip the AOT precompile: by default every program "
@@ -924,12 +924,8 @@ def _resume_supervised(args, state):
         raise exc
 
 
-def run(args, epoch_callback=None) -> dict:
+def run(args) -> dict:
     """Per-process SPMD lifecycle; returns a summary dict for tests/benchmarks.
-
-    ``epoch_callback(epoch, history_row) -> bool`` (optional) fires after
-    each epoch's train+eval+checkpoint; returning True stops the loop early
-    (tools/northstar.py uses this to stop at the target accuracy).
 
     The whole body runs under the agreed-exit protocol
     (``runtime/supervision.py``): ANY host-local failure — data staging,
@@ -939,7 +935,7 @@ def run(args, epoch_callback=None) -> dict:
     instead of blocking forever in a timeout-less collective.
     """
     try:
-        return _run_body(args, epoch_callback)
+        return _run_body(args)
     except BaseException as exc:
         # deliver_poison is a no-op for single-process runs, for
         # KeyboardInterrupt, for already-agreed failures (PeerFailure /
@@ -963,7 +959,7 @@ def run(args, epoch_callback=None) -> dict:
         raise
 
 
-def _run_body(args, epoch_callback=None) -> dict:
+def _run_body(args) -> dict:
     # Must run before ANY jax call that initializes the backend (including
     # jax.process_index in log0) — jax.distributed.initialize refuses to run
     # after backend init, the analog of init_process_group-before-CUDA order.
@@ -1907,8 +1903,6 @@ def _run_body(args, epoch_callback=None) -> dict:
                     "dataset": ("synthetic" if dataset_synthesized
                                 else args.dataset),
                 })
-            if epoch_callback is not None and epoch_callback(epoch, history[-1]):
-                break
             if epoch + 1 < args.epochs:
                 # The elastic grow rendezvous (no-op outside an
                 # --elastic-grow supervisor): after this epoch's
@@ -1936,10 +1930,9 @@ def _run_body(args, epoch_callback=None) -> dict:
     if staging["stages"]:
         # The input-plane story in one line: what feeding the chip cost
         # and how much of it the pipeline hid behind compute.
-        log0(f"input plane: {staging['feed_images_per_sec']:,.0f} "
-             f"feed images/sec (host {staging['host_ms']:.0f} ms + H2D "
+        log0(f"input plane: host {staging['host_ms']:.0f} ms + H2D "
              f"{staging['h2d_ms']:.0f} ms over {staging['stages']} "
-             f"stages, {staging['pipelined_stages']} pipelined), "
+             f"stages ({staging['pipelined_stages']} pipelined), "
              f"consumer blocked {staging['consumer_wait_ms']:.0f} ms, "
              f"overlap {staging['overlap_fraction']:.0%}")
     compile_stats = compile_log.stats()

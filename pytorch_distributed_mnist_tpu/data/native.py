@@ -30,8 +30,8 @@ _LIB_NAME = "libtpumnist_native.so"
 
 def _find_library() -> Optional[str]:
     if os.environ.get("TPUMNIST_NATIVE", "") == "0":
-        # Explicit fallback switch: equivalence tests and the input bench
-        # time the pure-NumPy path in a process that HAS the library.
+        # Explicit fallback switch: equivalence tests run the pure-NumPy
+        # path in a process that HAS the library.
         return None
     # TPUMNIST_ is the house env prefix (compile cache, faults,
     # timeouts); the historical TPU_MNIST_ spelling keeps working.
@@ -54,8 +54,8 @@ _lib = None
 #: Negative-cache sentinel: pad_into/cast_f32 run PER DISPATCHED BATCH
 #: on the serve hot path, so a fallback environment must not re-walk
 #: the filesystem probe (env reads + two stat()s) on every batch.
-#: ``_lib = None`` stays the one reset switch (tests and the input
-#: bench's in-process A/B flip rely on it) — it clears this cache too.
+#: ``_lib = None`` stays the one reset switch (tests rely on it) — it
+#: clears this cache too.
 _MISSING = object()
 
 

@@ -43,7 +43,7 @@ Correctness rules, in the house style:
 Every stage records into a :class:`~pytorch_distributed_mnist_tpu.
 utils.profiling.StagingLog` when one is attached: host-gather ms, H2D
 ms, and how long the consumer actually blocked — the overlap evidence
-``bench.py --mode input`` and the cli summary surface.
+the cli summary and ``benchmark/layers/input_wait_share.py`` read.
 """
 
 from __future__ import annotations

@@ -465,101 +465,3 @@ def make_param_gather(mesh: Mesh):
     return jax.jit(lambda params: params,
                    out_shardings=NamedSharding(mesh, P()))
 
-
-def make_comm_only_program(state, mesh: Mesh, axis: str = "data",
-                           bucket_mb: float = 4.0,
-                           bucket_mb_dcn: Optional[float] = None,
-                           tier: Optional[str] = None):
-    """Jitted ``params -> scalar`` running EXACTLY the step's collective
-    sequence — the bucket-fenced gradient reduce-scatters (ICI tier),
-    on a hierarchical mesh the bucket-fenced cross-slice shard
-    all-reduces (DCN tier), and the bucket-fenced shard allgathers — on
-    param-shaped values with no model compute in between. ``bench.py
-    --mode zero`` times this as the step's communication cost; the
-    returned scalar folds every result in so nothing is
-    dead-code-eliminated.
-
-    ``tier`` isolates ONE tier of a hierarchical mesh for the bench's
-    per-tier breakdown: ``'ici'`` runs only the intra-slice RS + AG,
-    ``'dcn'`` only the cross-slice shard all-reduces (the shard slice
-    itself is a local copy, not communication). ``tier`` on a flat mesh
-    is an error — a flat mesh has no tiers to isolate.
-    """
-    shard_axis, outer_axis, _all_axes = _tier_axes(mesh, axis)
-    if tier not in (None, "ici", "dcn"):
-        raise ValueError(f"tier must be None, 'ici' or 'dcn', got {tier!r}")
-    if tier is not None and outer_axis is None:
-        raise ValueError(
-            f"tier={tier!r} needs a hierarchical ('dcn', 'ici') mesh; "
-            f"this flat mesh has no tiers")
-    axis_size = mesh.shape[shard_axis]
-    param_leaves, ptree = jax.tree_util.tree_flatten(state.params)
-    del ptree
-    dims = _shard_dims(param_leaves, axis_size, shard_axis)
-    plan = bucket_plan(param_leaves, bucket_mb)
-    dcn_plan = (_dcn_bucket_plan(param_leaves, dims, axis_size,
-                                 bucket_mb_dcn or bucket_mb)
-                if outer_axis is not None else None)
-
-    def body(params):
-        flat = jax.tree_util.tree_flatten(params)[0]
-        shards: List = [None] * len(flat)
-        token = jnp.zeros((), jnp.float32)
-        if tier == "dcn":
-            # The DCN tier alone: slice each leaf down to this rank's
-            # shard locally (a copy, not communication) so the timed
-            # collectives move exactly the shard bytes the real
-            # schedule sends across slices.
-            idx = lax.axis_index(shard_axis)
-            for i, leaf in enumerate(flat):
-                d = dims[i]
-                if d is None:
-                    shards[i] = leaf
-                else:
-                    size = leaf.shape[d] // axis_size
-                    shards[i] = lax.dynamic_slice_in_dim(
-                        leaf, idx * size, size, axis=d)
-        else:
-            for bucket in plan:
-                fenced, token = _fenced(
-                    tuple(flat[i] for i in bucket), token)
-                for leaf, i in zip(fenced, bucket):
-                    d = dims[i]
-                    shards[i] = lax.psum(leaf, shard_axis) if d is None \
-                        else lax.psum_scatter(
-                            leaf, shard_axis, scatter_dimension=d,
-                            tiled=True)
-                token = _chain(token, jnp.sum(shards[bucket[0]]))
-        if outer_axis is not None and tier != "ici":
-            for bucket in dcn_plan:
-                fenced, token = _fenced(
-                    tuple(shards[i] for i in bucket), token)
-                for leaf, i in zip(fenced, bucket):
-                    shards[i] = lax.psum(leaf, outer_axis)
-                token = _chain(token, jnp.sum(shards[bucket[0]]))
-        acc = jnp.zeros((), jnp.float32)
-        if tier == "dcn":
-            # No allgather on this tier — fold the reduced shards. The
-            # per-rank folds differ across ici shards, so one scalar
-            # psum makes the P() output well-defined (negligible next
-            # to the timed shard all-reduces).
-            for s in shards:
-                acc = acc + jnp.sum(s).astype(jnp.float32)
-            return lax.psum(acc, shard_axis)
-        for bucket in plan:
-            fenced, token = _fenced(tuple(shards[i] for i in bucket), token)
-            for leaf, i in zip(fenced, bucket):
-                d = dims[i]
-                full = leaf if d is None else lax.all_gather(
-                    leaf, shard_axis, axis=d, tiled=True)
-                acc = acc + jnp.sum(full).astype(jnp.float32)
-            token = _chain(token, acc)
-        return acc
-
-    sharded = jax.shard_map(
-        body, mesh=mesh,
-        in_specs=(jax.tree_util.tree_map(lambda _: P(), state.params),),
-        out_specs=P(),
-        check_vma=False,
-    )
-    return jax.jit(sharded)

@@ -50,8 +50,8 @@ REQUESTS — the north-star's "serves heavy traffic" capability. Pieces:
   (one model plane per ``--model-set`` entry, requests routed on their
   ``model`` field).
 
-Drive it with ``tools/loadgen.py``; measure it with
-``python bench.py --mode serve``.
+Drive it with ``tools/loadgen.py``. No cell of ``BENCHMARK.json``
+measures it yet (``PERF.md`` section 7).
 """
 
 from pytorch_distributed_mnist_tpu.serve.batcher import MicroBatcher, Overloaded

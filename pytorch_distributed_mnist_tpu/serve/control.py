@@ -46,8 +46,8 @@ there is not enough of it, and how much capacity there should be:
   virtual time is floored to the grant clock on re-entry).
 
 Pure stdlib on purpose — no jax import: policy must be unit-testable
-with stubs and importable from the analyzer fixtures, the chaos twins,
-and ``bench.py`` without touching a backend.
+with stubs and importable from the analyzer fixtures and the chaos
+twins without touching a backend.
 """
 
 from __future__ import annotations

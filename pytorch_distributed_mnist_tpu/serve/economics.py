@@ -19,9 +19,8 @@ layer in front of the batcher (server) and in front of the fleet
   entry unreachable atomically — no per-entry scan, stale entries are
   lazily dropped on next touch or evicted by LRU pressure.
 - :class:`CostModel` — per-bucket measured step cost. Seeded from the
-  bucket geometry (the bench's per-bucket timings establish the same
-  shape — see DESIGN.md §7n for provenance), refreshed at serve time by
-  a cheap online EWMA over the batcher's measured batch walls. Prices
+  bucket geometry (see DESIGN.md §7n for provenance), refreshed at
+  serve time by a cheap online EWMA over the batcher's measured batch walls. Prices
   are normalized so the smallest bucket costs ~1.0; a cache hit prices
   at :data:`HIT_COST` (~0) so duplicate-heavy clients stop starving
   compute-heavy ones under cost-accounted quotas.
@@ -189,9 +188,8 @@ class ResponseCache:
 class CostModel:
     """Per-bucket step cost in normalized cost units.
 
-    Seeded from the bucket geometry (cost proportional to bucket rows —
-    the shape the bench's per-bucket timings measure on every box this
-    repo has run on), then refreshed by an online EWMA over the
+    Seeded from the bucket geometry (cost proportional to bucket rows),
+    then refreshed by an online EWMA over the
     batcher's measured batch walls: ``observe(rows, wall_s)`` per
     completed batch, ``price(rows)`` per admission decision. Prices are
     normalized to the smallest bucket (~1.0), so quota rates configured

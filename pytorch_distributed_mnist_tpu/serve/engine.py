@@ -471,8 +471,8 @@ class InferenceEngine:
     def fused_program_name(self, bucket: int) -> str:
         """The fused program's ``CompileLog`` name: the ``.fused`` tag
         rides the bucket segment (``serve_forward_b{bucket}.fused@{name}``)
-        so every ``serve_forward_`` prefix filter — /stats' compile
-        block, the bench recompile verdicts — covers both planes."""
+        so every ``serve_forward_`` prefix filter (/stats' compile
+        block) covers both planes."""
         base = f"serve_forward_b{bucket}.fused"
         return f"{base}@{self.name}" if self.name else base
 

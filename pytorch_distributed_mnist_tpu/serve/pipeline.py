@@ -31,9 +31,7 @@ its own chip:
   k+1 runs batch N-1, and steady-state throughput approaches the
   SLOWEST stage's clock rather than the sum of stages. Window 1
   degenerates to strict fill-and-drain (every batch pays the full chain
-  latency serially) — the ``bench.py --mode serve``
-  ``pipeline_serving.stage_overlap_speedup`` measurement is exactly
-  window >= stages vs window 1.
+  latency serially).
 
 Hot-reload swaps are COORDINATED across stages: ``swap_params`` splits
 and places every stage's slice off-lock, then installs the whole
@@ -51,8 +49,8 @@ quarantine/regroup of the WHOLE chain (a pipeline with a dead stage can
 serve nothing — the pool's group machinery is already chain-shaped),
 and the reload fan-out all work unchanged. Registered as serve mode
 ``pipeline`` via ``register_serve_mode``, which is what routes the boot
-gate, the divisibility walk, ``/stats``, and the bench through it
-without special-casing.
+gate, the divisibility walk and ``/stats`` through it without
+special-casing.
 """
 
 from __future__ import annotations
@@ -447,38 +445,6 @@ class PipelineEngine:
     def predict_with_epoch(self, images) -> Tuple[np.ndarray, Optional[int]]:
         logits, epoch = self.logits_with_epoch(images)
         return np.argmax(logits, axis=-1), epoch
-
-    # -- bench instrumentation --------------------------------------------
-
-    def stage_step_ms(self, bucket: int, reps: int = 5) -> dict:
-        """Per-stage SYNCHRONOUS step walls (stage name -> best-of-reps
-        ms) at one bucket: each stage's program run alone on its chip
-        with a blocking fetch, zero activations in flight. This is the
-        bench's occupancy probe — under full streaming the pipe's clock
-        is the SLOWEST stage's wall, and every other stage idles the
-        difference (``utils/profiling.py::stage_occupancy`` turns these
-        into the occupancy fractions) — not a serving-path measurement.
-        """
-        import time
-
-        with self._lock:
-            stage_params = list(self._stage_params)
-        walls: dict = {}
-        x = np.zeros((bucket,) + self.input_shape,
-                     self._precision_spec.input_dtype)
-        x = jax.device_put(x, self._stages[0].sharding)
-        for stage, params in zip(self._stages, stage_params):
-            if stage.index:
-                x = jax.device_put(x, stage.sharding)
-            jax.block_until_ready(stage.run(params, x))  # warm transfer
-            best = float("inf")
-            for _ in range(reps):
-                t0 = time.perf_counter()
-                y = jax.block_until_ready(stage.run(params, x))
-                best = min(best, time.perf_counter() - t0)
-            walls[stage.name.rsplit(".", 1)[-1]] = round(best * 1e3, 3)
-            x = y
-        return walls
 
 
 def make_pipeline_template(model, rng):

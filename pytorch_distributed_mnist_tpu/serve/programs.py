@@ -97,7 +97,7 @@ class ServeMode:
     the-mesh (SPMD) lowering, so a mode whose programs are NOT one mesh
     program — MPMD pipeline serving (``serve/pipeline.py``) compiles one
     independent program PER chip — still rides every generic path
-    (layout gate, divisibility walk, pool groups, ``/stats``, bench)
+    (layout gate, divisibility walk, pool groups, ``/stats``)
     without special-casing:
 
     - ``engine_factory``: builds the group's engine instead of the
@@ -166,14 +166,6 @@ def serve_modes() -> List[str]:
     return [REPLICATED] + sorted(_MODES)
 
 
-def get_serve_mode(mode: str) -> ServeMode:
-    """The registered :class:`ServeMode` for ``mode`` (raises with the
-    registry's vocabulary for unknown names; ``replicated`` has no
-    ServeMode object and is rejected here too — callers branch on it
-    BEFORE reaching for mode hooks)."""
-    return _get_mode(mode)
-
-
 def staged_mode(mode: str) -> bool:
     """Whether ``mode`` is a registered STAGED (pipeline-of-programs)
     mode — the ``/stats`` ``pipeline_stages`` field and the per-chip
@@ -198,15 +190,6 @@ def make_serve_template(mode: str, model, rng):
     from pytorch_distributed_mnist_tpu.train.state import create_train_state
 
     return create_train_state(model, rng)
-
-
-def registered_mode_models() -> List[tuple]:
-    """Every (mode, model) pair with a rule table, sorted — what the
-    bench's sharded block iterates, so a mode added through
-    ``register_serve_mode`` joins the throughput comparison and the
-    per-bucket x mode recompile verdict without editing bench.py."""
-    return [(name, model) for name, mode in sorted(_MODES.items())
-            for model in sorted(mode.rules_by_model)]
 
 
 def servable_modes(model_name: str) -> List[str]:
@@ -515,7 +498,7 @@ _PRECISIONS: Dict[str, ServePrecision] = {}
 def register_precision(spec: ServePrecision) -> ServePrecision:
     """Register a serving precision (the extension point mirroring
     :func:`register_serve_mode`: a new quantization scheme becomes a
-    ``--serve-precision`` choice and a bench sweep column by adding one
+    ``--serve-precision`` choice by adding one
     :class:`ServePrecision`, no engine/pool/server change)."""
     if spec.name in _PRECISIONS:
         raise ValueError(f"serve precision {spec.name!r} already registered")

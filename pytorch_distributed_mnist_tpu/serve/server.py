@@ -41,7 +41,7 @@ device submission):
 
 The deliberately boring transport (no asyncio, no framework dep) is the
 point: the serving smarts live in engine/batcher/reload, which are all
-driveable in-process by tests and by ``bench.py --mode serve``.
+driveable in-process by tests.
 """
 
 from __future__ import annotations
@@ -336,7 +336,7 @@ def build_parser() -> argparse.ArgumentParser:
                         "block disappear")
     p.add_argument("--price-admission", action="store_true",
                    help="cost-priced admission: each request is priced "
-                        "in measured step-cost units (per-bucket bench "
+                        "in measured step-cost units (per-bucket geometry "
                         "seed refreshed by an online EWMA at serve "
                         "time) instead of counting 1 per request — "
                         "queue watermarks, per-client quotas and "
@@ -722,7 +722,7 @@ class _Handler(BaseHTTPRequestHandler):
             cache_block["collapsed"] = plane.batcher.collapsed
             stats["cache"] = cache_block
         if ctx.price_admission and plane.batcher.cost_model is not None:
-            # Cost-table provenance: per-bucket prices (bench seed
+            # Cost-table provenance: per-bucket prices (geometry seed
             # refreshed by the serve-time EWMA) admission accounts in.
             stats["cost_model"] = plane.batcher.cost_model.snapshot()
         if plane.canary is not None:
@@ -1009,7 +1009,7 @@ class _Handler(BaseHTTPRequestHandler):
             # — admission control (503 below) is the server's. Under
             # --price-admission the bucket drains in measured cost
             # units: a cache hit is ~free, a big-bucket miss costs its
-            # bench/EWMA price (row count estimated from JSON nesting —
+            # seeded/EWMA price (row count estimated from JSON nesting —
             # cheap; the engine still decides the real shape below).
             cost = 1.0
             if ctx.price_admission:
@@ -1655,7 +1655,7 @@ def _build_plane(args, model_name: str, checkpoint_dir: str, *,
             current_path=boot_path, validate_fn=_validate_reload,
             loader=fetcher.load,
         ).start()
-        watcher.fetcher = fetcher  # observability: chaos/bench read stats
+        watcher.fetcher = fetcher  # observability: the chaos twins read stats
 
     autoscaler = None
     if getattr(args, "autoscale", False):

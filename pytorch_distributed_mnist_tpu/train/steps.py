@@ -393,12 +393,10 @@ def make_train_epoch_indexed(
     drops: one (B, ...) batch materializes per tick instead of the staged
     (S, B, ...) epoch.
 
-    Host against device gather is not measured on today's code (the one
-    old chip line put this path ~10% slower on the MNIST CNN; ROADMAP
-    S3.2 re-measures it). Until then it is the documented
-    memory/host-bandwidth saver, not the default (``--epoch-gather
-    host``); ``bench.py``'s sorted-index secondary probes whether gather
-    locality (sort indices within a tick) matters.
+    Host against device gather is not measured on today's code: no cell
+    of ``BENCHMARK.json`` runs this path (ROADMAP D3). Until one does it
+    is the documented memory/host-bandwidth saver, not the default
+    (``--epoch-gather host``).
     """
     return _make_epoch(mesh, axis, state_sharding,
                        make_accum_train_step_fn(grad_accum, aux_weight),

@@ -1,6 +1,6 @@
 """Persistent XLA compile-cache wiring — ONE config-update path for every
-entry point (``cli.run``, ``serve``, ``bench.py``, ``chip_smoke.py``'s
-children, ``tests_tpu/``, the tools).
+entry point (``cli.run``, ``serve``, ``benchmark/run.py``'s runners,
+``chip_smoke.py``'s children, ``tests_tpu/``, the tools).
 
 First compilation of the jitted whole-epoch programs is the framework's
 startup tax, and a chip-tool call starts on a cold machine every time, so

@@ -98,19 +98,15 @@ class StagingLog:
       synchronous path (every staging millisecond stalls the consumer,
       and the inline path records its own wall as wait so the figure is
       honest by construction), approaching 1 when the feeder fully
-      hides staging behind compute;
-    - ``feed_images_per_sec`` = images / staging wall: the feed-only
-      throughput the input pipeline could sustain — the number a fast
-      chip starves on when it exceeds the step rate. Stage walls time
-      the ``device_put`` DISPATCH (JAX async dispatch returns before
-      the transfer lands), so this figure is an upper bound here;
-      ``bench.py --mode input`` re-derives its headline rate from a
-      completion-blocked wall.
+      hides staging behind compute. Stage walls time the
+      ``device_put`` DISPATCH (JAX async dispatch returns before the
+      transfer lands); the consumer's wait is what a reader should
+      trust (``benchmark/layers/input_wait_share.py`` reads it).
 
     Thread-safe: the feeder thread records stages while the consumer
     records waits. A process singleton (``staging_log``) follows the
-    ``compile_log`` pattern: cli/bench attach it per run and reset it
-    at entry.
+    ``compile_log`` pattern: ``cli.run`` and the benchmark's runners
+    attach it per run and reset it at entry.
     """
 
     def __init__(self) -> None:
@@ -147,9 +143,9 @@ class StagingLog:
             self._wait_ms += wait_ms
 
     def summary(self) -> Dict:
-        """Snapshot for cli summaries and the bench ``input_pipeline``
-        block; all-zero (with ``overlap_fraction`` 0.0) when nothing
-        was recorded."""
+        """Snapshot for cli summaries and the benchmark's runners;
+        all-zero (with ``overlap_fraction`` 0.0) when nothing was
+        recorded."""
         with self._lock:
             staging_ms = self._host_ms + self._h2d_ms
             overlap = 0.0
@@ -163,14 +159,11 @@ class StagingLog:
                 "consumer_wait_ms": round(self._wait_ms, 1),
                 "overlap_fraction": round(overlap, 4),
                 "images": self._images,
-                "feed_images_per_sec": round(
-                    self._images / max(staging_ms / 1e3, 1e-9), 1)
-                if self._images else 0.0,
             }
 
 
 # Singleton for the same reason as compile_log: one run, one input-plane
-# story. cli.run and bench reset() it at entry.
+# story. cli.run and the benchmark's runners reset() it at entry.
 staging_log = StagingLog()
 
 
@@ -224,73 +217,6 @@ class RoutingLog:
 
 
 routing_log = RoutingLog()
-
-
-def comm_overlap_fraction(step_ms: float, compute_ms: float,
-                          comm_ms: float) -> Optional[float]:
-    """How much of a step's measured communication cost is hidden behind
-    its compute: ``1 - exposed/comm`` where ``exposed = max(step -
-    compute, 0)`` — the three walls measured independently (the full
-    step, a communication-free compute twin, a compute-free
-    communication twin). 1.0 means the step costs no more than its
-    compute (communication fully overlapped); 0.0 means every
-    communication millisecond extends the step (fully serialized).
-    Clamped to [0, 1] — the twins are separate measurements, so noise
-    can push the raw ratio past either edge. ``None`` when there is no
-    measurable communication (``comm_ms <= 0``) — a single-device world
-    has nothing to overlap, and 0/0 must not report as overlap.
-
-    Used by ``bench.py --mode zero``; unit-pinned in
-    ``tests/test_bench_zero.py``.
-    """
-    if comm_ms is None or comm_ms <= 0 or step_ms is None \
-            or compute_ms is None:
-        return None
-    exposed = max(float(step_ms) - float(compute_ms), 0.0)
-    return round(max(0.0, min(1.0, 1.0 - exposed / float(comm_ms))), 4)
-
-
-def per_tier_overlap_fractions(step_ms: float, compute_ms: float,
-                               comm_ms_by_tier: dict) -> dict:
-    """Per-tier overlap fractions for a multi-tier communication
-    schedule (the DCN x ICI two-tier ZeRO step, ``bench.py --mode
-    zero``): tier t's fraction is ``comm_overlap_fraction(step,
-    compute, comm_t)`` — the step's WHOLE exposed time charged against
-    that tier alone. Wall measurements cannot say WHICH tier's
-    milliseconds the step hid, so each entry is the guaranteed-hidden
-    lower bound: a tier scores above 0 only when the exposure is
-    smaller than its own comm (some of it must have been hidden no
-    matter how the exposure is attributed), and 1.0 only when the step
-    costs no more than its compute.
-
-    ``None`` entries propagate per tier (a zero-comm tier has nothing
-    to overlap). Unit-pinned in ``tests/test_bench_zero.py``.
-    """
-    return {tier: comm_overlap_fraction(step_ms, compute_ms, comm)
-            for tier, comm in comm_ms_by_tier.items()}
-
-
-def stage_occupancy(stage_step_ms: dict) -> dict:
-    """Per-stage occupancy of a streamed pipeline under full overlap:
-    each stage's synchronous step wall over the BOTTLENECK stage's.
-
-    A filled pipe retires one micro-batch per bottleneck-stage wall, so
-    the slowest stage reads 1.0 (always busy) and every other stage is
-    busy exactly its own wall's share of that clock and idles the rest —
-    the imbalance this reports is the capacity a stage re-balancer
-    (ROADMAP item 3) would recover. Empty/zero inputs return ``{}``:
-    occupancy of a pipe that does no work is not 1.0.
-
-    Used by ``bench.py --mode serve``'s ``pipeline_serving`` block;
-    unit-pinned in ``tests/test_serve_mpmd.py``.
-    """
-    if not stage_step_ms:
-        return {}
-    slowest = max(float(v) for v in stage_step_ms.values())
-    if slowest <= 0:
-        return {}
-    return {name: round(float(ms) / slowest, 4)
-            for name, ms in stage_step_ms.items()}
 
 
 def device_report() -> dict:
@@ -509,7 +435,7 @@ class CompileLog:
         return {"programs": programs, "totals": totals}
 
 
-# Process-wide singleton: entry points (cli/bench/tools) and the trainer's
+# Process-wide singleton: entry points (cli, benchmark, tools) and the trainer's
 # background precompile all feed one log, so a run's compile story lands in
 # one place. Tests reset() it between cases.
 compile_log = CompileLog()

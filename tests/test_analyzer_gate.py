@@ -1,7 +1,7 @@
 """Tier-1 gate: tpumnist-lint is clean over the codebase it guards.
 
 The contract (ISSUE 5): ``python -m tools.analyzer`` over
-``pytorch_distributed_mnist_tpu/``, ``tools/`` and ``bench.py`` exits 0
+``pytorch_distributed_mnist_tpu/`` and ``tools/`` exits 0
 with ZERO non-baselined findings; every baseline entry carries a
 justification; a stale baseline entry fails the gate; and deliberately
 re-introducing the zlib-strand bug (narrowing ``_try_load``'s except
@@ -29,8 +29,7 @@ from tools.analyzer import (  # noqa: E402
 pytestmark = pytest.mark.lint
 
 GATE_PATHS = [os.path.join(_REPO, p)
-              for p in ("pytorch_distributed_mnist_tpu", "tools")] \
-             + [os.path.join(_REPO, "bench.py")]
+              for p in ("pytorch_distributed_mnist_tpu", "tools")]
 
 # One full-tree analysis shared by every read-only assertion below (a
 # cold run costs ~7s of tier-1 wall on one core; four tests reading the

@@ -140,8 +140,8 @@ prog = jax.jit(step)
 def test_fires_on_jit_of_rs_step_with_config_default():
     """An overlapped-ZeRO step whose body takes a bool config flag
     (interpret/debug toggles) jitted without statics: each distinct
-    value re-traces the whole bucket chain — the recompile class the
-    zero bench's steady-state verdict exists to catch."""
+    value re-traces the whole bucket chain — the recompile class
+    tests/test_zero_overlap.py's steady-state cases exist to catch."""
     src = """
 import jax
 from jax import lax
@@ -214,8 +214,7 @@ prog = jax.jit(zero_step, static_argnames=("debug_buckets",))
 def test_fires_on_literal_into_compiled_mesh_bucket():
     """The sharded engine's bucket executables take (params, staged
     batch); a raw literal where the batch belongs re-keys a compile
-    through the jit fallback — the steady-state violation the per
-    bucket x mode bench verdict fails loudly on."""
+    through the jit fallback — a steady-state recompile."""
     src = """
 import jax
 

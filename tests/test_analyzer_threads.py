@@ -183,7 +183,7 @@ def arm(deadline, fn):
 
 
 def test_clean_on_comprehension_container_joined_in_loop():
-    """The bench/loadgen drive shape: a list comprehension of threads
+    """The loadgen drive shape: a list comprehension of threads
     reaped by a for loop over the container."""
     src = """
 import threading

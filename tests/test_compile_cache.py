@@ -335,11 +335,11 @@ def test_precompile_specs_match_staging():
 # -- shared wiring across entry points --------------------------------------
 
 
-def test_cli_and_bench_share_cache_wiring(cache_module_state, monkeypatch,
-                                          tmp_path):
-    """Acceptance: cli.run() and bench.py use the SAME persistent-cache
-    wiring — both route through utils/compile_cache.configure, no
-    duplicated config-update code.
+def test_cli_run_routes_its_flag_through_configure(
+        cache_module_state, monkeypatch, tmp_path):
+    """Acceptance: cli.run() passes its --compile-cache flag to
+    utils/compile_cache.configure, the one persistent-cache wiring every
+    entry point shares — no config-update code of its own.
 
     configure is stubbed to RECORD without applying (the suite runs with
     the cache off); the application side is covered by
@@ -349,18 +349,8 @@ def test_cli_and_bench_share_cache_wiring(cache_module_state, monkeypatch,
     monkeypatch.setattr(compile_cache, "configure",
                         lambda flag=None: calls.append(flag) or flag)
 
-    # bench side: configure_jax is the prologue every bench child runs.
-    # It names no directory of its own — no flag reaches configure(), so
-    # JAX_COMPILATION_CACHE_DIR (or the checkout default) decides.
-    import bench
-
-    bench.configure_jax()
-    assert calls == [None]
-
-    # cli side: run() passes its --compile-cache flag to the same function.
     from pytorch_distributed_mnist_tpu.cli import build_parser, run
 
-    calls.clear()
     args = build_parser().parse_args([
         "--dataset", "synthetic", "--model", "linear",
         "--batch-size", "64", "--synthetic-train-size", "128",
@@ -428,9 +418,9 @@ def test_warm_second_run_recompiles_zero_programs(tmp_path):
 
 
 def test_compile_report_renders_stats(tmp_path, capsys):
-    """tools/compile_report.py renders the compile_stats of bench-style
-    lines and --metrics-file run_summary rows, and exits nonzero when no
-    block exists."""
+    """tools/compile_report.py renders the compile_stats of any JSON line
+    that carries one, a --metrics-file's run_summary row among them, and
+    exits nonzero when no block exists."""
     sys.path.insert(0, os.path.join(REPO, "tools"))
     import compile_report
 
@@ -440,16 +430,15 @@ def test_compile_report_renders_stats(tmp_path, capsys):
         "persistent_cache_hit": False}},
         "totals": {"cache_hits": 0, "cache_misses": 1,
                    "backend_compiles": 1, "backend_compile_ms": 900.0}}
-    direct = tmp_path / "bench.json"
-    direct.write_text(json.dumps({
-        "metric": "m", "backend": "tpu", "compile_stats": stats}) + "\n")
+    direct = tmp_path / "plain.json"
+    direct.write_text(json.dumps({"compile_stats": stats}) + "\n")
     summary = tmp_path / "metrics.jsonl"
     summary.write_text(
         json.dumps({"epoch": 0, "train_loss": 1.0}) + "\n"
         + json.dumps({"kind": "run_summary", "platform": "tpu",
                       "compile_stats": stats}) + "\n")
     empty = tmp_path / "old.json"
-    empty.write_text(json.dumps({"metric": "m", "value": 1.0}) + "\n")
+    empty.write_text(json.dumps({"kind": "m", "value": 1.0}) + "\n")
 
     assert compile_report.main([str(direct), str(summary)]) == 0
     out = capsys.readouterr().out
@@ -457,23 +446,3 @@ def test_compile_report_renders_stats(tmp_path, capsys):
     assert "miss" in out
     assert compile_report.main([str(empty)]) == 1
     assert compile_report.main([]) == 1
-
-
-def test_bench_output_contains_compile_stats_block(tmp_path):
-    """Acceptance: bench.py child output carries the compile_stats block
-    with per-program compile ms and cache hit/miss."""
-    env = dict(os.environ, BENCH_FORCE_CPU="1",
-               JAX_COMPILATION_CACHE_DIR=str(tmp_path / "cache"))
-    proc = subprocess.run(
-        [sys.executable, os.path.join(REPO, "bench.py"), "--child", "1", "1"],
-        capture_output=True, text=True, timeout=600, env=env, cwd=REPO)
-    line = [l for l in proc.stdout.splitlines()
-            if l.strip().startswith("{")][-1]
-    result = json.loads(line)
-    assert result["ok"], result
-    # The child names the directory the standard variable placed.
-    assert result["compile_cache"] == str(tmp_path / "cache")
-    stats = result["compile_stats"]
-    rec = stats["programs"]["train_step"]
-    assert rec["wall_ms"] > 0
-    assert rec["persistent_cache_hit"] in (True, False)

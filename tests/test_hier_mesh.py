@@ -41,7 +41,6 @@ from pytorch_distributed_mnist_tpu.parallel.zero import shard_state_zero
 from pytorch_distributed_mnist_tpu.parallel.zero_overlap import (
     _dcn_bucket_plan,
     _shard_dims,
-    make_comm_only_program,
     make_overlap_train_epoch,
     make_overlap_train_step,
     make_param_gather,
@@ -412,32 +411,6 @@ def test_hier_state_layout_shards_over_ici_only():
             if entry is not None:
                 axes_used.add(entry)
     assert axes_used == {"ici"}
-
-
-# -- per-tier comm twins ------------------------------------------------------
-
-
-def test_comm_only_tier_programs():
-    hier = make_hier_mesh(2, devices=jax.devices()[:4])
-    model = get_model("linear", compute_dtype=jnp.float32)
-    z, _ = shard_state_zero(
-        create_train_state(model, jax.random.key(0)), hier, level=3)
-    full = make_param_gather(hier)(z.params)
-    for tier in (None, "ici", "dcn"):
-        prog = make_comm_only_program(z, hier, bucket_mb=0.5,
-                                      bucket_mb_dcn=0.25, tier=tier)
-        assert np.isfinite(float(prog(full))), tier
-
-
-def test_comm_only_tier_rejected_on_flat_mesh():
-    flat = make_mesh(("data",), devices=jax.devices()[:4])
-    model = get_model("linear", compute_dtype=jnp.float32)
-    z, _ = shard_state_zero(
-        create_train_state(model, jax.random.key(0)), flat, level=3)
-    with pytest.raises(ValueError, match="hierarchical"):
-        make_comm_only_program(z, flat, tier="ici")
-    with pytest.raises(ValueError, match="tier must be"):
-        make_comm_only_program(z, make_hier_mesh(2), tier="bogus")
 
 
 # -- cli: end to end ----------------------------------------------------------

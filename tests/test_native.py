@@ -297,8 +297,8 @@ def test_dequant_f32_rejects_other_dtypes():
 
 
 def test_tpumnist_native_zero_disables_library(monkeypatch):
-    """TPUMNIST_NATIVE=0 is the explicit in-process fallback switch the
-    input bench uses to time the NumPy path with the library present."""
+    """TPUMNIST_NATIVE=0 is the explicit in-process fallback switch: the
+    NumPy path runs with the library present."""
     monkeypatch.setenv("TPUMNIST_NATIVE", "0")
     monkeypatch.setattr(native, "_lib", None)
     assert not native.available()

@@ -181,9 +181,9 @@ def test_flash_attention_grad_matches_dense(causal, t):
 @pytest.mark.parametrize("block", [64, 256])
 @pytest.mark.parametrize("causal", [False, True])
 def test_flash_attention_block_override(block, causal):
-    """Numerics are block-size invariant (fwd AND bwd): the ``block``
-    override exists so tools/sweep_flash.py can tune the tile edge on
-    chip — any size must produce the same attention, including when the
+    """Numerics are block-size invariant (fwd AND bwd): whatever tile
+    edge the ``block`` override names, any size must produce the same
+    attention, including when the
     block exceeds T (256 > 192: single padded tile) and when it divides
     T unevenly (64 into 192)."""
     t = 192

@@ -471,7 +471,7 @@ def test_canary_flag_rejections_and_resize_refusal(tmp_path):
 
 def test_direct_quantized_serving_without_canary(tmp_path):
     """--serve-precision without --canary-fraction serves the quantized
-    plane directly (the trusted path the bench sweeps), with
+    plane directly (the trusted path), with
     serve_precision in /stats and NO canary block."""
     ckpt = tmp_path / "ckpt"
     state = _publish(ckpt, epoch=0, seed=10)

@@ -38,10 +38,7 @@ from pytorch_distributed_mnist_tpu.serve.programs import (
     serve_modes,
     validate_serve_mode,
 )
-from pytorch_distributed_mnist_tpu.utils.profiling import (
-    compile_log,
-    stage_occupancy,
-)
+from pytorch_distributed_mnist_tpu.utils.profiling import compile_log
 
 pytestmark = pytest.mark.serve
 
@@ -92,7 +89,7 @@ def test_split_stage_params_boundaries(pp_setup):
 
 def test_pipeline_registered_and_validates(pp_setup):
     """The registry sees the mode (boot gate vocabulary, argparse
-    choices, bench iteration) and the generic divisibility walk reduces
+    choices) and the generic divisibility walk reduces
     to depth % stages == 0 over the pipelined template tree."""
     _, template, _ = pp_setup
     assert "pipeline" in serve_modes()
@@ -288,31 +285,6 @@ def test_pipeline_pool_requires_model_object(pp_setup):
         EnginePool(model.apply, template.params,
                    devices=jax.local_devices()[:2], serve_mode="pipeline",
                    mesh_size=2, model_name="vit")  # model= missing
-
-
-# -- occupancy helper --------------------------------------------------------
-
-
-def test_stage_occupancy_units():
-    """The bottleneck stage reads 1.0, others their wall's share of the
-    bottleneck clock; degenerate inputs return {} (a pipe doing no work
-    has no occupancy)."""
-    occ = stage_occupancy({"s0": 2.0, "s1": 4.0, "s2": 1.0})
-    assert occ == {"s0": 0.5, "s1": 1.0, "s2": 0.25}
-    assert stage_occupancy({}) == {}
-    assert stage_occupancy({"s0": 0.0}) == {}
-
-
-def test_stage_step_ms_probe(pp_setup):
-    model, template, _ = pp_setup
-    eng = PipelineEngine(model, template.params, jax.local_devices()[:2],
-                         buckets=(8,))
-    eng.warmup()
-    walls = eng.stage_step_ms(8, reps=2)
-    assert sorted(walls) == ["s0", "s1"]
-    assert all(v > 0 for v in walls.values())
-    occ = stage_occupancy(walls)
-    assert max(occ.values()) == 1.0
 
 
 # -- analyzer cleanliness ----------------------------------------------------

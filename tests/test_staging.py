@@ -335,7 +335,6 @@ def test_staging_log_pipelined_records_feeder_stages():
     s = log.summary()
     assert s["stages"] == len(train)
     assert s["pipelined_stages"] == len(train)
-    assert s["feed_images_per_sec"] > 0
 
 
 # -- per-batch eval staging cache (satellite) --------------------------------

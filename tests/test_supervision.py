@@ -216,7 +216,7 @@ def test_fault_points_registry_matches_call_sites():
     analyzer, repo = _analyzer()
     result = analyzer.run_analysis(
         [os.path.join(repo, "pytorch_distributed_mnist_tpu"),
-         os.path.join(repo, "tools"), os.path.join(repo, "bench.py")],
+         os.path.join(repo, "tools")],
         checkers=["registry-drift"], baseline=None)
     assert result.ok, "\n".join(f.render() for f in result.findings)
     report = result.reports["registry-drift"]

@@ -23,7 +23,6 @@ from pytorch_distributed_mnist_tpu.models import get_model
 from pytorch_distributed_mnist_tpu.parallel.zero import shard_state_zero
 from pytorch_distributed_mnist_tpu.parallel.zero_overlap import (
     bucket_plan,
-    make_comm_only_program,
     make_overlap_train_epoch,
     make_overlap_train_step,
     make_param_gather,
@@ -33,6 +32,7 @@ from pytorch_distributed_mnist_tpu.train.steps import (
     make_train_epoch,
     make_train_step,
 )
+from pytorch_distributed_mnist_tpu.utils.profiling import CompileLog
 
 
 def _batch(seed, n=64):
@@ -211,15 +211,67 @@ def test_overlap_grad_accum_composition(mesh8):
     _assert_trees_close(ref.opt_state, z.opt_state)
 
 
-def test_comm_only_program_runs_collective_sequence(mesh8):
-    """The bench's comm twin compiles and returns a finite scalar (the
-    DCE anchor folding every reduce-scatter/allgather result)."""
+# -- steady state: a warm step compiles nothing more ---------------------------
+
+
+def _steady_state_compiles(step_once, steps=3):
+    """The XLA backend compiles that ``steps`` further calls of
+    ``step_once(i)`` cause after one warm call, counted by a ``CompileLog``
+    of its own. A step whose results come back in another layout than its
+    arguments had, or whose shapes drift, reads above 0."""
+    log = CompileLog()
+    try:
+        step_once(0)
+        with log.measure("steady"):
+            for i in range(1, steps + 1):
+                step_once(i)
+        return log.stats()["totals"]["backend_compiles"]
+    finally:
+        log.close()
+
+
+@pytest.mark.parametrize("level", [1, 3])
+@pytest.mark.parametrize("tiers", ["flat", "two_tier"])
+def test_overlap_step_steady_state_compiles_nothing(mesh8, tiers, level):
+    """After one warm step, further overlapped steps compile nothing: the
+    state and, at level 3, the gathered carry leave the step in the layout
+    it takes. On the flat mesh and on the two-tier one (state sharded over
+    ``ici``, replicated over ``dcn``) with a bucket budget for each tier."""
+    from pytorch_distributed_mnist_tpu.parallel.mesh import make_hier_mesh
+
+    mesh, buckets = (mesh8, {"bucket_mb": 0.5}) if tiers == "flat" else (
+        make_hier_mesh(2), {"bucket_mb": 0.5, "bucket_mb_dcn": 0.125})
     model = get_model("linear", compute_dtype=jnp.float32)
-    z = create_train_state(model, jax.random.key(0))
-    z, _ = shard_state_zero(z, mesh8, level=3)
-    full = make_param_gather(mesh8)(z.params)
-    comm = make_comm_only_program(z, mesh8, bucket_mb=0.5)
-    assert np.isfinite(float(comm(full)))
+    z, _ = shard_state_zero(
+        create_train_state(model, jax.random.key(0)), mesh, level=level)
+    step = make_overlap_train_step(z, mesh, level=level, **buckets)
+    carry = [z, make_param_gather(mesh)(z.params) if level == 3 else None]
+
+    def step_once(i):
+        if level == 3:
+            carry[0], carry[1], m = step(carry[0], carry[1], _batch(i))
+        else:
+            carry[0], m = step(carry[0], _batch(i))
+        jax.block_until_ready(m)
+
+    assert _steady_state_compiles(step_once) == 0
+
+
+@pytest.mark.parametrize("level", [1, 3])
+def test_propagation_step_steady_state_compiles_nothing(mesh8, level):
+    """The same for the propagation path (parallel/zero.py): the jitted
+    step returns the ZeRO state in the sharding it was given."""
+    model = get_model("linear", compute_dtype=jnp.float32)
+    state, sharding = shard_state_zero(
+        create_train_state(model, jax.random.key(0)), mesh8, level=level)
+    step = make_train_step(mesh8, state_sharding=sharding)
+    carry = [state]
+
+    def step_once(i):
+        carry[0], m = step(carry[0], _batch(i))
+        jax.block_until_ready(m)
+
+    assert _steady_state_compiles(step_once) == 0
 
 
 # -- CLI wiring --------------------------------------------------------------

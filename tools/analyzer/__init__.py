@@ -38,8 +38,7 @@ Run it::
 or from tests (the tier-1 gate)::
 
     from tools.analyzer import run_analysis
-    result = run_analysis(["pytorch_distributed_mnist_tpu", "tools",
-                           "bench.py"])
+    result = run_analysis(["pytorch_distributed_mnist_tpu", "tools"])
     assert result.ok, result.findings
 
 Pure stdlib; never imports the analyzed code.

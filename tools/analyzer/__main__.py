@@ -29,7 +29,7 @@ from tools.analyzer import (  # noqa: E402
 
 #: What the tier-1 gate analyzes when no paths are given (tools/lint.sh
 #: and tests/test_analyzer_gate.py pin the same set).
-DEFAULT_PATHS = ("pytorch_distributed_mnist_tpu", "tools", "bench.py")
+DEFAULT_PATHS = ("pytorch_distributed_mnist_tpu", "tools")
 
 
 def _git_changed_files():

@@ -174,7 +174,7 @@ def int_constants(node: ast.AST) -> List[int]:
 def module_name(path: str) -> str:
     """Dotted module name for a repo-relative posix path.
     ``pkg/serve/engine.py`` -> ``pkg.serve.engine``; ``pkg/__init__.py``
-    -> ``pkg``; ``bench.py`` -> ``bench``."""
+    -> ``pkg``; ``chip_smoke.py`` -> ``chip_smoke``."""
     p = path.replace("\\", "/")
     if p.endswith(".py"):
         p = p[: -len(".py")]

@@ -1,9 +1,9 @@
 """Checker: traced/lowered functions must be pure and AOT-stable.
 
 Serving and the AOT precompile path assert ZERO steady-state recompiles
-(serve/engine.py, bench.py) and the trainer calls compiled executables
-directly — which only holds if the traced program is a pure function of
-its array arguments. Host side effects inside a traced body either
+(serve/engine.py, the window of benchmark/run.py's runners) and the
+trainer calls compiled executables directly — which only holds if the
+traced program is a pure function of its array arguments. Host side effects inside a traced body either
 silently run once at trace time (print/logging/time/random: debugging
 lies, nondeterminism baked into the program) or force a host sync /
 retrace (``.item()``, ``float()``, ``np.asarray`` on a tracer).

@@ -3,11 +3,8 @@
 The compile-latency subsystem (utils/compile_cache.py + the trainer's AOT
 precompile) records, for every program, its compile wall ms, how many real
 XLA backend compiles ran, and whether the persistent cache served it. That
-lands in:
-
-- ``bench.py`` output lines (``compile_stats`` block);
-- the ``run_summary`` row of a training run's ``--metrics-file``;
-- ``tools/northstar.py`` output (``compile_stats`` + ``compile_cache``).
+lands in the ``run_summary`` row of a training run's ``--metrics-file``
+(``compile_stats`` block).
 
 This tool renders those blocks as a cold-vs-warm table.
 
@@ -63,12 +60,9 @@ def report(paths) -> int:
             if stats is None:
                 continue
             found += 1
-            label = (obj.get("metric") or obj.get("kind")
-                     or obj.get("target_acc") or "run")
-            backend = obj.get("backend") or obj.get("platform", "?")
-            when = obj.get("measured_at", "")
+            label = obj.get("kind") or "run"
             print(f"\n{os.path.relpath(path, REPO)} — {label} "
-                  f"[{backend}] {when}")
+                  f"[{obj.get('platform', '?')}]")
             print(f"  {'program':<24} {'compile ms':>10} {'XLA':>4} "
                   f"{'cache':>6}")
             for name, rec in sorted(stats["programs"].items()):

@@ -28,7 +28,7 @@ fi
 
 echo "== tpumnist-lint (tools/analyzer) =="
 python -m tools.analyzer "${analyzer_flags[@]+"${analyzer_flags[@]}"}" \
-  pytorch_distributed_mnist_tpu tools bench.py \
+  pytorch_distributed_mnist_tpu tools \
   || note $?
 
 if [ "${1:-}" = "--fast" ] || [ "${1:-}" = "--changed" ]; then
@@ -37,7 +37,7 @@ fi
 
 if command -v ruff >/dev/null 2>&1; then
   echo "== ruff check =="
-  ruff check --no-cache pytorch_distributed_mnist_tpu tools tests bench.py \
+  ruff check --no-cache pytorch_distributed_mnist_tpu tools tests \
     || note $?
 else
   echo "== ruff check: SKIP (ruff not installed) =="
