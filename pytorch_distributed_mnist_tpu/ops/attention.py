@@ -21,6 +21,17 @@ backward is written out (``jax.custom_vjp``), so it reads and writes the
 softmax; the blockwise form is exactly the online-softmax recurrence
 XLA:TPU fuses well — no materialized (T, T) matrix bigger than one
 (T_q_block, T_k_block) tile.
+
+Slices. The float32 scores are the largest thing the dense op holds, and
+what it costs follows where they live: a call whose scores the compiler
+can keep in the chip's fast memory passes over them there, a larger one
+streams them through HBM several times a pass. So the forward and the
+backward each work on slices of the batch, a slice being every ``n``-th
+example (``_over_slices``), with ``n`` read off the call's shape alone
+(``slice_count``): the fewest slices whose float32 scores are at most
+``SLICE_SCORE_BYTES`` each. A call under the constant, or one whose batch
+no divisor brings under it, is traced whole, with no loop. Each example's
+values are the same expressions either way.
 """
 
 from __future__ import annotations
@@ -31,12 +42,29 @@ from typing import NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 
+from pytorch_distributed_mnist_tpu.utils.profiling import (
+    dense_attention_slices,
+)
+
 
 NEG_INF = -1e30  # softmax mask value; avoids -inf NaN propagation in exp
 
 # Every ``attention_fn`` runs under this scope, so a profile separates the
 # attention core from the qkv and output projections whichever one is in.
 CORE_SCOPE = "attn_core"
+
+# The most float32 scores, in bytes, that one slice of a dense call holds
+# (``slice_count``). Measured on a v5e (PR 30; T = 196, head size 64, bf16
+# operands; the core's device time, forward and backward, from a trace). Two
+# blocks alone at 12 heads and batch 128 (236 MB whole): whole 6.40 ms,
+# slices of 64 images (118 MB) 8.20, of 32 (59 MB) 5.76, of 16 (29.5 MB)
+# 3.78, of 8 (14.8 MB) 3.75. Inside the ViT training step, a step: at 12
+# heads whole 38.6 ms, slices of 32 34.8, of 16 22.6; at 16 heads and batch
+# 32 (78.7 MB whole) whole 11.3, slices of 16 (39.3 MB) 13.8, of 8 (19.7 MB)
+# 10.1, of 2 (4.9 MB) 11.8. So the line lies between 29.5 MB, where the
+# compiler keeps a slice's scores in the fast memory through both matmuls,
+# and 39.3 MB, where it does not.
+SLICE_SCORE_BYTES = 32 * 2 ** 20
 
 
 def _masked_scores(q, k, causal, scale, window=None):
@@ -56,7 +84,42 @@ def _masked_scores(q, k, causal, scale, window=None):
     return s
 
 
-def _dense_fwd(q, k, v, causal, scale, window=None):
+def slice_count(b: int, h: int, tq: int, tk: int) -> int:
+    """Into how many slices of the batch the dense core cuts a call: the
+    smallest divisor ``n`` of ``b`` for which the float32 scores of ``b / n``
+    examples are at most ``SLICE_SCORE_BYTES``, and 1 (the call stays whole)
+    where they already are or no divisor brings them under it."""
+    per_example = h * tq * tk * 4
+    for n in range(1, b + 1):
+        if b % n == 0 and (b // n) * per_example <= SLICE_SCORE_BYTES:
+            return n
+    return 1
+
+
+def _over_slices(fn, n, *operands):
+    """``fn`` over ``n`` interleaved slices of the leading axis of every
+    operand and result: the batch is viewed as ``(B / n, n)`` and slice
+    ``j`` is ``[:, j]``, read and written back by index inside one loop, so
+    a batch axis that GSPMD has sharded stays sharded the same way in every
+    slice and nothing is transposed to put a slice axis first."""
+    views = tuple(x.reshape(x.shape[0] // n, n, *x.shape[1:])
+                  for x in operands)
+
+    def on_slice(j):
+        return fn(*(jax.lax.dynamic_index_in_dim(x, j, 1, keepdims=False)
+                    for x in views))
+
+    def body(j, done):
+        return tuple(jax.lax.dynamic_update_index_in_dim(r, o, j, 1)
+                     for r, o in zip(done, on_slice(j)))
+
+    done = jax.lax.fori_loop(0, n, body, tuple(
+        jnp.zeros((x.shape[0], n, *x.shape[1:]), x.dtype)
+        for x in jax.eval_shape(on_slice, 0)))
+    return tuple(x.reshape(x.shape[0] * n, *x.shape[2:]) for x in done)
+
+
+def _fwd_slice(q, k, v, causal, scale, window):
     s = _masked_scores(q, k, causal, scale, window)
     m = jnp.max(s, axis=-1, keepdims=True)
     if causal:
@@ -71,6 +134,39 @@ def _dense_fwd(q, k, v, causal, scale, window=None):
         preferred_element_type=jnp.float32,
     ).astype(q.dtype)
     lse = (m + jnp.log(l))[..., 0]  # (B, H, Tq) float32
+    return o, lse
+
+
+# The sliced passes are jitted of their own, so that a model's layers, which
+# call them with the same shapes, share one trace and one lowered function:
+# traced in line, 24 layers' loops added 5 s to the set-up of ``vit-l16``.
+@partial(jax.jit, static_argnums=(3, 4, 5, 6))
+def _sliced_fwd(q, k, v, causal, scale, window, n):
+    return _over_slices(
+        partial(_fwd_slice, causal=causal, scale=scale, window=window),
+        n, q, k, v)
+
+
+@partial(jax.jit, static_argnums=(6, 7, 8, 9))
+def _sliced_bwd(q, k, v, o, lse, do, causal, scale, window, n):
+    return _over_slices(
+        partial(_bwd_slice, causal=causal, scale=scale, window=window),
+        n, q, k, v, o, lse, do)
+
+
+def _traced_slices(q, k):
+    """``slice_count`` of a call, entered in the process's counter."""
+    n = slice_count(q.shape[0], q.shape[2], q.shape[1], k.shape[1])
+    dense_attention_slices.record(n)
+    return n
+
+
+def _dense_fwd(q, k, v, causal, scale, window=None):
+    n = _traced_slices(q, k)
+    if n == 1:
+        o, lse = _fwd_slice(q, k, v, causal, scale, window)
+    else:
+        o, lse = _sliced_fwd(q, k, v, causal, scale, window, n)
     return o, (q, k, v, o, lse)
 
 
@@ -79,12 +175,7 @@ def _dense_attention(q, k, v, causal, scale, window=None):
     return _dense_fwd(q, k, v, causal, scale, window)[0]
 
 
-def _dense_bwd(causal, scale, window, residuals, do):
-    """Closed form of softmax attention's VJP: with ``p = softmax(s)``,
-    ``ds = scale * p * (dp - rowsum(do * o))``. The probabilities are not
-    kept; they are recomputed in float32 from the same operands and the
-    row log-sum-exp, so they are the forward's values."""
-    q, k, v, o, lse = residuals
+def _bwd_slice(q, k, v, o, lse, do, causal, scale, window):
     s = _masked_scores(q, k, causal, scale, window)
     p = jnp.exp(s - lse[..., None])  # a masked entry is exp(NEG_INF): 0
     delta = jnp.einsum(
@@ -100,6 +191,19 @@ def _dense_bwd(causal, scale, window, residuals, do):
     dk = jnp.einsum(
         "bhqk,bqhd->bkhd", ds, q, preferred_element_type=jnp.float32)
     return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
+
+
+def _dense_bwd(causal, scale, window, residuals, do):
+    """Closed form of softmax attention's VJP: with ``p = softmax(s)``,
+    ``ds = scale * p * (dp - rowsum(do * o))``. The probabilities are not
+    kept; they are recomputed in float32 from the same operands and the
+    row log-sum-exp, so they are the forward's values. Sliced as the
+    forward is, in a loop of its own."""
+    q, k, v, o, lse = residuals
+    n = _traced_slices(q, k)
+    if n == 1:
+        return _bwd_slice(q, k, v, o, lse, do, causal, scale, window)
+    return _sliced_bwd(q, k, v, o, lse, do, causal, scale, window, n)
 
 
 _dense_attention.defvjp(_dense_fwd, _dense_bwd)
@@ -126,6 +230,12 @@ def full_attention(
     fewer heads than ``q`` (grouped key-value heads; query head ``h`` reads
     head ``h // (H_q / H_kv)``): they are repeated here, which the flash
     kernels avoid (``ops/pallas/flash.py``).
+
+    A call whose float32 scores ``(B, H, Tq, Tk)`` pass
+    ``SLICE_SCORE_BYTES`` runs, in each pass, as a loop over interleaved
+    slices of the batch (every ``n``-th example, ``n = slice_count(B, H,
+    Tq, Tk)``): one slice's float32 scores are then the largest thing the
+    op holds at a time, and the results are the whole call's.
 
     Matmul operands keep ``q.dtype``, accumulation and the softmax are
     float32 (module docstring). Reverse-mode differentiation takes the

@@ -224,8 +224,9 @@ def device_report() -> dict:
     ``/healthz`` reply, so nothing is read without its device:
     ``platform``/``device_kind``/``device_count`` in jax's own words,
     ``input_backend`` (the host input path in use: ``native`` C++ or
-    ``numpy``), ``pallas_lowerings`` (:class:`LoweringLog`) and
-    ``flash_schedules`` (:class:`ScheduleLog`)."""
+    ``numpy``), ``pallas_lowerings`` (:class:`LoweringLog`),
+    ``flash_schedules`` (:class:`ScheduleLog`) and
+    ``dense_attention_slices`` (:class:`SliceLog`)."""
     from pytorch_distributed_mnist_tpu.data import native
 
     devices = jax.devices()
@@ -234,7 +235,8 @@ def device_report() -> dict:
             "device_count": len(devices),
             "input_backend": "native" if native.available() else "numpy",
             "pallas_lowerings": pallas_lowerings.snapshot(),
-            "flash_schedules": flash_schedules.snapshot()}
+            "flash_schedules": flash_schedules.snapshot(),
+            "dense_attention_slices": dense_attention_slices.snapshot()}
 
 
 class LoweringLog:
@@ -295,6 +297,36 @@ class ScheduleLog:
 
 
 flash_schedules = ScheduleLog()
+
+
+class SliceLog:
+    """The dense attention calls traced in this process
+    (``ops/attention.py`` records ``slice_count`` once a forward and once a
+    backward): how many there were, how many of them work on slices of the
+    batch, and the slices a sliced call — ``None`` where none sliced."""
+
+    def __init__(self) -> None:
+        self._lock = threading.Lock()
+        self._sites = self._sliced_sites = self._slices = 0
+
+    def record(self, slices: int) -> None:
+        with self._lock:
+            self._sites += 1
+            if slices > 1:
+                self._sliced_sites += 1
+                self._slices += slices
+
+    def snapshot(self) -> Dict:
+        with self._lock:
+            return {
+                "sites": self._sites,
+                "sliced_sites": self._sliced_sites,
+                "slices_per_sliced_site": (
+                    round(self._slices / self._sliced_sites, 2)
+                    if self._sliced_sites else None)}
+
+
+dense_attention_slices = SliceLog()
 
 
 class CompileLog:

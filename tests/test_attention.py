@@ -162,11 +162,12 @@ def test_sharded_flash_matches_dense_on_tp_mesh():
 
 # -- the dense op's closed-form backward (ops/attention.py::_dense_bwd) -------
 
-def _grad_inputs(tq, tk, dtype, seed=11):
+def _grad_inputs(tq, tk, dtype, seed=11, b=2, h=3, h_kv=None):
     ks = jax.random.split(jax.random.key(seed), 4)
-    q = jax.random.normal(ks[0], (2, tq, 3, 8), jnp.float32)
-    k, v = (jax.random.normal(kk, (2, tk, 3, 8), jnp.float32) for kk in ks[1:3])
-    w = jax.random.normal(ks[3], (2, tq, 3, 8), jnp.float32)  # the cotangent
+    q = jax.random.normal(ks[0], (b, tq, h, 8), jnp.float32)
+    k, v = (jax.random.normal(kk, (b, tk, h_kv or h, 8), jnp.float32)
+            for kk in ks[1:3])
+    w = jax.random.normal(ks[3], (b, tq, h, 8), jnp.float32)  # the cotangent
     return tuple(x.astype(dtype) for x in (q, k, v)), w
 
 
@@ -280,3 +281,174 @@ def test_full_attention_refuses_forward_mode(qkv):
     q, k, v = qkv
     with pytest.raises(TypeError, match="custom_vjp"):
         jax.jvp(full_attention, (q, k, v), (q, k, v))
+
+
+# -- the dense op over slices of the batch (ops/attention.py::_over_slices) ---
+
+def _score_bytes(examples, h, tq, tk):
+    return examples * h * tq * tk * 4
+
+
+@pytest.mark.parametrize("shape,limit,want", [
+    # the cells' calls under the constant the module ships
+    ((128, 12, 196, 196), None, 8),   # vit-b16: 236 MB, 16 images a slice
+    ((32, 16, 196, 196), None, 4),    # vit-l16: 78.7 MB, 8 images a slice
+    ((128, 16, 196, 196), None, 16),  # train_l16_dp4's global batch: 2 a chip
+    ((8, 12, 196, 196), None, 1),     # 14.8 MB stays whole
+    ((1, 48, 8192, 8192), None, 1),   # the 8,192-token oracle: B = 1
+    # the rule alone
+    ((6, 2, 8, 8), _score_bytes(4, 2, 8, 8), 2),    # 3 + 3, not 4 + 2
+    ((6, 2, 8, 8), _score_bytes(1, 2, 8, 8), 6),
+    ((5, 2, 8, 8), _score_bytes(2, 2, 8, 8), 5),    # a prime: slices of one
+    ((4, 2, 8, 8), _score_bytes(4, 2, 8, 8), 1),    # fits whole
+    ((4, 2, 8, 8), _score_bytes(1, 2, 8, 8) - 1, 1),  # no divisor fits
+    ((1, 2, 8, 8), 1, 1),
+])
+def test_slice_count_reads_only_the_shape(monkeypatch, shape, limit, want):
+    from pytorch_distributed_mnist_tpu.ops import attention
+
+    if limit is not None:
+        monkeypatch.setattr(attention, "SLICE_SCORE_BYTES", limit)
+    assert attention.slice_count(*shape) == want
+
+
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 1e-6),
+                                       (jnp.bfloat16, 1e-2)])
+@pytest.mark.parametrize("b,per,slices,tq,tk,h_kv,kwargs", [
+    (4, 2, 2, 16, 16, 4, {}),
+    (4, 1, 4, 8, 24, 4, {"causal": True}),
+    (4, 2, 2, 24, 8, 4, {"causal": True}),    # rows with nothing to see
+    (6, 4, 2, 16, 16, 4, {"causal": True, "window": 5}),
+    (6, 1, 6, 16, 16, 2, {}),                 # grouped key-value heads
+    (5, 2, 5, 8, 24, 1, {"causal": True, "window": 3}),
+    (4, 0.5, 1, 16, 16, 4, {}),               # no divisor fits: whole
+    (1, 0.5, 1, 16, 16, 4, {"causal": True}),  # B = 1 stays whole
+])
+def test_sliced_dense_attention_is_the_whole_one(monkeypatch, b, per, slices,
+                                                 tq, tk, h_kv, kwargs, dtype,
+                                                 tol):
+    """The output and dq, dk, dv with the constant set so low that these
+    tiny calls slice, against the same calls kept whole: a slice only
+    decides which examples share a fusion."""
+    from pytorch_distributed_mnist_tpu.ops import attention
+
+    (q, k, v), w = _grad_inputs(tq, tk, dtype, b=b, h=4, h_kv=h_kv)
+    loss = _weighted(full_attention, w, **kwargs)
+
+    def run():
+        before = attention.dense_attention_slices.snapshot()
+        out = full_attention(q, k, v, **kwargs)
+        grads = jax.grad(loss, (0, 1, 2))(q, k, v)
+        after = attention.dense_attention_slices.snapshot()
+        return (out,) + grads, after["sliced_sites"] - before["sliced_sites"]
+
+    monkeypatch.setattr(attention, "SLICE_SCORE_BYTES",
+                        int(_score_bytes(per, 4, tq, tk)))
+    assert attention.slice_count(b, 4, tq, tk) == slices
+    got, sliced_sites = run()
+    assert sliced_sites == (3 if slices > 1 else 0)  # fwd, then fwd and bwd
+    monkeypatch.setattr(attention, "SLICE_SCORE_BYTES", 2 ** 40)
+    want, sliced_sites = run()
+    assert sliced_sites == 0
+    for g, r in zip(got, want):
+        assert g.dtype == r.dtype and g.shape == r.shape
+        g, r = (np.asarray(x.astype(jnp.float32)) for x in (g, r))
+        assert np.isfinite(g).all()
+        np.testing.assert_allclose(g, r, rtol=tol, atol=tol * np.abs(r).max())
+
+
+def _contiguous_slices(fn, n, *operands):
+    """``_over_slices`` with slice ``j`` the ``j``-th contiguous run of the
+    batch: what point 2 of ISSUE 30 rules out."""
+    views = tuple(x.reshape(n, x.shape[0] // n, *x.shape[1:])
+                  for x in operands)
+
+    def on_slice(j):
+        return fn(*(jax.lax.dynamic_index_in_dim(x, j, 0, keepdims=False)
+                    for x in views))
+
+    def body(j, done):
+        return tuple(jax.lax.dynamic_update_index_in_dim(r, o, j, 0)
+                     for r, o in zip(done, on_slice(j)))
+
+    done = jax.lax.fori_loop(0, n, body, tuple(
+        jnp.zeros((n, *x.shape), x.dtype)
+        for x in jax.eval_shape(on_slice, 0)))
+    return tuple(x.reshape(n * x.shape[1], *x.shape[2:]) for x in done)
+
+
+COLLECTIVES = ("all-gather", "all-to-all", "collective-permute")
+
+
+def test_slices_of_a_sharded_batch_move_nothing_between_devices(monkeypatch):
+    """``jax.grad`` of the sliced core with q, k, v spread over ``data`` on
+    four devices: interleaved slices leave every example on its device;
+    contiguous slices (written here) make GSPMD gather them."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from pytorch_distributed_mnist_tpu.ops import attention
+
+    mesh = Mesh(np.array(jax.devices()[:4]), ("data",))
+    (q, k, v), w = _grad_inputs(16, 16, jnp.float32, b=16, h=4)
+    sharded = NamedSharding(mesh, P("data"))
+    args = tuple(jax.device_put(x, sharded) for x in (q, k, v))
+    monkeypatch.setattr(attention, "SLICE_SCORE_BYTES",
+                        _score_bytes(4, 4, 16, 16))
+    assert attention.slice_count(16, 4, 16, 16) == 4
+
+    def program():
+        fn = jax.jit(jax.grad(_weighted(full_attention, w), (0, 1, 2)),
+                     out_shardings=(sharded,) * 3)
+        compiled = fn.lower(*args).compile()
+        return compiled.as_text(), compiled(*args)
+
+    text, got = program()
+    assert " while(" in text
+    assert not [c for c in COLLECTIVES if c in text]
+    monkeypatch.setattr(attention, "_over_slices", _contiguous_slices)
+    jax.clear_caches()  # the sliced passes are jitted: trace them anew
+    try:
+        contiguous, same = program()
+    finally:
+        jax.clear_caches()  # and let no later test find these traces
+    assert [c for c in COLLECTIVES if c in contiguous]
+    for g, r in zip(got, same):
+        np.testing.assert_allclose(g, r, rtol=1e-6, atol=1e-6)
+
+
+def test_a_call_under_the_constant_is_the_unsliced_program(monkeypatch):
+    """With the slicing taken out (``slice_count`` always 1) a call under
+    the constant lowers to the same text, and one over it does not; the
+    process's counter tells a sliced call from a whole one."""
+    from pytorch_distributed_mnist_tpu.ops import attention
+    from pytorch_distributed_mnist_tpu.utils.profiling import device_report
+
+    (q, k, v), w = _grad_inputs(16, 16, jnp.bfloat16, b=4, h=4)
+
+    def text():
+        return jax.jit(jax.grad(_weighted(full_attention, w, causal=True),
+                                (0, 1, 2))).lower(q, k, v).as_text()
+
+    def counted(fn):
+        before = device_report()["dense_attention_slices"]
+        out = fn()
+        after = device_report()["dense_attention_slices"]
+        assert after == attention.dense_attention_slices.snapshot()
+        return out, after["sites"] - before["sites"], (
+            after["sliced_sites"] - before["sliced_sites"])
+
+    assert attention.slice_count(4, 4, 16, 16) == 1
+    whole, sites, sliced = counted(text)
+    assert (sites, sliced) == (2, 0)  # a forward and a backward, both whole
+    with monkeypatch.context() as m:
+        m.setattr(attention, "slice_count", lambda *a: 1)
+        assert text() == whole
+    monkeypatch.setattr(attention, "SLICE_SCORE_BYTES",
+                        _score_bytes(2, 4, 16, 16))
+    sliced_text, sites, sliced = counted(text)
+    assert (sites, sliced) == (2, 2)
+    assert device_report()["dense_attention_slices"][
+        "slices_per_sliced_site"] is not None
+    assert sliced_text != whole and "while" in sliced_text
+    monkeypatch.setattr(attention, "slice_count", lambda *a: 1)
+    assert text() == whole

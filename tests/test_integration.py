@@ -238,6 +238,8 @@ def test_metrics_file(tmp_path):
     assert set(summary["pallas_lowerings"]) == {"mosaic", "interpret"}
     assert set(summary["flash_schedules"]) == {
         "sites", "folded_sites", "folded_evaluated_over_needed"}
+    assert set(summary["dense_attention_slices"]) == {
+        "sites", "sliced_sites", "slices_per_sliced_site"}
     assert "train_epoch" in summary["compile_stats"]["programs"]
     assert "history" not in summary  # the epoch rows above already say it
     for key in ("platform", "device_kind", "device_count"):

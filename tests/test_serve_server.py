@@ -108,6 +108,7 @@ def test_predict_healthz_stats(server):
     assert health["input_backend"] in ("native", "numpy")
     assert set(health["pallas_lowerings"]) == {"mosaic", "interpret"}
     assert "folded_sites" in health["flash_schedules"]
+    assert "sliced_sites" in health["dense_attention_slices"]
 
     reply = srv.post("/predict", {"images": images.tolist()})
     assert len(reply["predictions"]) == 5
