@@ -15,6 +15,10 @@ Drives the main path once, through the entry points a user would call:
               synthetic_tokens`` at its tiny preset on sequences of 1,024
               tokens (8 steps of 8): window and full attention through
               the flash kernels, top-4 of 16 experts by grouped matmuls;
+            * the state-space hybrid, ``--model sambay --dataset
+              synthetic_tokens`` at its tiny preset on the same sequences:
+              two selective scans, differential attention (window, full
+              and cross) through the flash kernels, a Gated Memory Unit;
   server    python -m pytorch_distributed_mnist_tpu serve      (server ->
             engine -> batcher -> pool) on the checkpoint the trainer just
             wrote, answering ``tools/loadgen.py --smoke`` and a batch of
@@ -70,6 +74,7 @@ LAGUNA = ["--model", "laguna", "--dataset", "synthetic_tokens",
           "--seq-len", "1024", "--batch-size", str(LAGUNA_BATCH),
           "--synthetic-train-size", str(LAGUNA_STEPS * LAGUNA_BATCH),
           "--synthetic-test-size", "16", "--lr", "1e-3"]
+SAMBAY = ["--model", "sambay"] + LAGUNA[2:]
 # The trainer's own warnings that a compiled program was refused or
 # compiled twice (train/trainer.py): legitimate on a user's machine,
 # a failure here.
@@ -463,6 +468,7 @@ def main() -> int:
         cnn = smoke.train("cnn", [], kernels=False)
         smoke.train("vit", VIT, kernels=True)
         smoke.train("laguna", LAGUNA, kernels=True, steps=LAGUNA_STEPS)
+        smoke.train("sambay", SAMBAY, kernels=True, steps=LAGUNA_STEPS)
         ref = smoke.reference(cnn)
         smoke.serve(cnn, ref, "f32")
         smoke.serve(cnn, ref, "int8")

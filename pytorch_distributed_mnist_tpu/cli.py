@@ -143,7 +143,7 @@ def build_parser() -> argparse.ArgumentParser:
                             "synthetic_tokens"],
                    help="synthetic_tokens: a seeded corpus of packed "
                         "token sequences (data/tokens.py) for a token "
-                        "model (--model laguna); --synthetic-*-size "
+                        "model (--model laguna, sambay); --synthetic-*-size "
                         "count sequences of --seq-len tokens")
     p.add_argument("--seq-len", type=int, default=64,
                    help="tokens a sequence, --dataset synthetic_tokens")
@@ -1543,7 +1543,7 @@ def _run_body(args) -> dict:
     if tokens != (_token_vocab(args) is not None):
         raise SystemExit(
             f"--model {args.model} and --dataset {args.dataset} do not go "
-            f"together: a token model (laguna) reads --dataset "
+            f"together: a token model (laguna, sambay) reads --dataset "
             f"synthetic_tokens, and nothing else does")
     moe_dispatch = getattr(args, "moe_dispatch", "dense")
     if getattr(args, "remat", False):

@@ -5,8 +5,10 @@ The reference hard-codes a single model (``Net``, a ``Linear(784, 10)``,
 fixed call site (``:185``). Here the model is pluggable via a registry:
 ``linear`` is the exact reference-parity model, ``cnn`` is the small convnet
 required for the >=99% MNIST accuracy target (BASELINE.json north star),
-``vit`` and ``moe_mlp`` carry attention and experts, and ``laguna`` is the
-decoder-only token model family (``models/decoder.py``).
+``vit`` and ``moe_mlp`` carry attention and experts, ``laguna`` is the
+decoder-only token model family (``models/decoder.py``) and ``sambay`` the
+hybrid of state-space, differential-attention, gated-memory and shared-KV
+cross layers (``models/sambay.py``).
 """
 
 from pytorch_distributed_mnist_tpu.models.linear import LinearNet
@@ -14,6 +16,7 @@ from pytorch_distributed_mnist_tpu.models.cnn import ConvNet
 from pytorch_distributed_mnist_tpu.models.attention import VisionTransformer
 from pytorch_distributed_mnist_tpu.models.moe import MoEClassifier, SparseExperts, SwitchMoE
 from pytorch_distributed_mnist_tpu.models.decoder import Decoder
+from pytorch_distributed_mnist_tpu.models.sambay import SambaY
 from pytorch_distributed_mnist_tpu.models.registry import get_model, register_model, list_models, model_accepts
 
 __all__ = [
@@ -24,6 +27,7 @@ __all__ = [
     "SparseExperts",
     "SwitchMoE",
     "Decoder",
+    "SambaY",
     "get_model",
     "register_model",
     "list_models",

@@ -129,8 +129,11 @@ class RMSNorm(nn.Module):
         return (xf * scale).astype(self.compute_dtype)
 
 
-def attend(q, k, v, *, window: Optional[int], attention: str):
-    """Causal attention of one layer under ``attn_core/<kind>``."""
+def attend(q, k, v, *, window: Optional[int], attention: str,
+           scale: Optional[float] = None, cross: bool = False):
+    """Causal attention of one layer under ``attn_core/<kind>``: ``window``,
+    ``full`` or, where the keys and values are an earlier layer's
+    (``cross``), ``cross``. ``scale`` defaults to ``D ** -0.5``."""
     if attention == "auto":
         attention = "flash" if jax.default_backend() == "tpu" else "dense"
     if attention == "flash":
@@ -141,9 +144,9 @@ def attend(q, k, v, *, window: Optional[int], attention: str):
         fn = full_attention
     else:
         raise ValueError(f"unknown attention {attention!r}")
-    kind = "full" if window is None else "window"
+    kind = "cross" if cross else "full" if window is None else "window"
     with jax.named_scope(f"{CORE_SCOPE}/{kind}"):
-        return fn(q, k, v, causal=True, window=window)
+        return fn(q, k, v, causal=True, window=window, scale=scale)
 
 
 class GatedAttention(nn.Module):

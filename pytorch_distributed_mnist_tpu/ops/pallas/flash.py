@@ -51,7 +51,14 @@ What the kernels take (``flash_attention``'s docstring has the contract):
   ``(B, T, H*D)`` as it leaves the projection, a 128-lane column block a
   head, with no transpose; otherwise (the ViT's 16 to 64) the arrays are
   put head-major first, ``(B, H, T, D)``, because a block's last dimension
-  has to be a multiple of 128 lanes or the whole array's;
+  has to be a multiple of 128 lanes or the whole array's. Who calls at
+  which size: ``models/decoder.py`` (``laguna``) at 128; the ViT at 16 to
+  64, head-major; ``models/sambay.py`` (differential attention: heads of
+  64 whose values are 128 wide) lays its queries and keys out at the
+  values' width with zeros behind and passes the scale of 64, so its
+  three kinds of core (window, full, and cross over an earlier layer's
+  keys and values) are calls at 128 on the lane-blocked path, one score
+  map a query head, and the key and value widths stay one argument;
 - operands in the type they arrive in (bf16 in the training cells, f32 in
   the tests), every matmul accumulated in float32, scores and softmax in
   float32, the probabilities cast to the operands' type only where they

@@ -225,8 +225,9 @@ def device_report() -> dict:
     ``platform``/``device_kind``/``device_count`` in jax's own words,
     ``input_backend`` (the host input path in use: ``native`` C++ or
     ``numpy``), ``pallas_lowerings`` (:class:`LoweringLog`),
-    ``flash_schedules`` (:class:`ScheduleLog`) and
-    ``dense_attention_slices`` (:class:`SliceLog`)."""
+    ``flash_schedules`` (:class:`ScheduleLog`),
+    ``dense_attention_slices`` (:class:`SliceLog`) and ``state_scans``
+    (:class:`ScanLog`)."""
     from pytorch_distributed_mnist_tpu.data import native
 
     devices = jax.devices()
@@ -236,7 +237,8 @@ def device_report() -> dict:
             "input_backend": "native" if native.available() else "numpy",
             "pallas_lowerings": pallas_lowerings.snapshot(),
             "flash_schedules": flash_schedules.snapshot(),
-            "dense_attention_slices": dense_attention_slices.snapshot()}
+            "dense_attention_slices": dense_attention_slices.snapshot(),
+            "state_scans": scan_log.snapshot()}
 
 
 class LoweringLog:
@@ -327,6 +329,51 @@ class SliceLog:
 
 
 dense_attention_slices = SliceLog()
+
+
+class ScanLog:
+    """The selective scans traced in this process (``ops/ssm.py`` records
+    one a call) and who reads what a layer publishes for later layers
+    (``models/sambay.py`` records one a reading layer): how many scans
+    there were, the chunks a scan walks, the bytes of state a scan keeps
+    at its chunks' starts for the backward (all it keeps of the ``(T, C,
+    N)`` states), and the layers traced that read the published scan
+    output (``memory``) and the published keys and values (``kv``)."""
+
+    def __init__(self) -> None:
+        self._lock = threading.Lock()
+        self.reset()
+
+    def reset(self) -> None:
+        with self._lock:
+            self._sites = self._chunks = self._state_bytes = 0
+            self._readers = {"memory": 0, "kv": 0}
+
+    def record_scan(self, *, chunks: int, state_bytes: int) -> None:
+        with self._lock:
+            self._sites += 1
+            self._chunks += chunks
+            self._state_bytes += state_bytes
+
+    def record_reader(self, what: str) -> None:
+        with self._lock:
+            self._readers[what] += 1
+
+    def snapshot(self) -> Dict:
+        with self._lock:
+            n = self._sites
+
+            def per_site(total):
+                return total / n if n else None
+
+            return {"sites": n,
+                    "chunks_per_site": per_site(self._chunks),
+                    "state_bytes_kept_per_site": per_site(self._state_bytes),
+                    "memory_readers": self._readers["memory"],
+                    "kv_readers": self._readers["kv"]}
+
+
+scan_log = ScanLog()
 
 
 class CompileLog:

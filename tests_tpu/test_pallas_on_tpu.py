@@ -3,7 +3,7 @@
 The hermetic suite (tests/test_pallas_kernels.py) pins the same numerics in
 interpret mode; this suite is the hardware half — it catches Mosaic-only
 failures (block tiling rules, SMEM refs, lane alignment for the ViT head
-dims D=16/32) that interpret mode cannot see. Each of the four kernels is
+dims D=16/32) that interpret mode cannot see. Each of the five kernels is
 compiled at one shape the main path uses (what ``chip_smoke.py`` drives).
 
 Oracle comparisons run under ``jax_default_matmul_precision=highest``
@@ -194,3 +194,48 @@ def test_flash_window_grouped_heads_on_tpu(b, t, heads, kv_heads, window):
         gr = gr.astype(jnp.float32)
         err = float(jnp.max(jnp.abs(g.astype(jnp.float32) - gr)))
         assert err < 0.03 * float(jnp.max(jnp.abs(gr))) + 1e-2
+
+
+# The selective scan's two kernels (ops/pallas/ssm.py) at the tiny preset's
+# shape (one piece of 128 channels, 4 states) and at the training cell's
+# widths on a shorter sequence (ten pieces of 512 channels, 16 states, four
+# chunks of 256 positions, so the state and its gradient cross chunk and
+# piece boundaries), against the recurrence position by position.
+# (B, T, C, N).
+SCAN_CASES = [(2, 1024, 128, 4), (1, 1024, 5120, 16)]
+
+
+@pytest.mark.parametrize("b,t,c,n", SCAN_CASES)
+def test_selective_scan_on_tpu_matches_the_loop(b, t, c, n):
+    from pytorch_distributed_mnist_tpu.ops.ssm import selective_scan
+
+    ks = jax.random.split(jax.random.key(2), 7)
+    a = jax.random.normal(ks[0], (b, t, c))
+    dt = jnp.exp(jax.random.uniform(ks[1], (b, t, c)) * 4.6 - 6.9)
+    A = -jnp.broadcast_to(jnp.arange(1.0, n + 1), (c, n))
+    B = jax.random.normal(ks[2], (b, t, n))
+    C = jax.random.normal(ks[3], (b, t, n))
+    D = jax.random.normal(ks[4], (c,))
+    weight = jax.random.normal(ks[5], (b, t, c))
+
+    def loop(a, dt, A, B, C, D):
+        def step(s, x):
+            a_t, dt_t, b_t, c_t = x
+            s = jnp.exp(dt_t[:, None] * A) * s \
+                + (dt_t * a_t)[:, None] * b_t[None]
+            return s, jnp.sum(s * c_t[None], axis=-1) + D * a_t
+
+        return jax.vmap(lambda a, dt, B, C: jax.lax.scan(
+            step, jnp.zeros(A.shape), (a, dt, B, C))[1])(a, dt, B, C)
+
+    def rel(x, y):
+        return float(jnp.max(jnp.abs(x - y)) / jnp.max(jnp.abs(y)))
+
+    args = (a, dt, A, B, C, D)
+    assert rel(jax.jit(selective_scan)(*args), jax.jit(loop)(*args)) < 1e-4
+    grads, want = (
+        jax.jit(jax.grad(lambda *xs: jnp.sum(f(*xs) * weight),
+                         argnums=range(6)))(*args)
+        for f in (selective_scan, loop))
+    for name, g, w in zip("a dt A B C D".split(), grads, want):
+        assert rel(g, w) < 1e-3, name
