@@ -3,17 +3,26 @@
 Blockwise online-softmax attention: scores are computed tile-by-tile in
 VMEM and never materialized as a (T, T) matrix in HBM — in either pass.
 The forward kernel additionally emits the per-row logsumexp; the backward
-is the standard two-pass flash recipe over that residual:
+is one kernel over that residual, which evaluates every tile once and
+takes all three gradients from it:
 
   delta_i = rowsum(dO_i * O_i)                       (tiny elementwise, XLA)
   P_ij    = exp(scale * q_i.k_j - lse_i)             (recomputed per tile)
   dV_j    = sum_i P_ij^T dO_i
   dS_ij   = P_ij * (dO_i.V_j - delta_i)
-  dQ_i    = scale * sum_j dS_ij K_j                  (kernel 1: grid over i)
-  dK_j    = scale * sum_i dS_ij^T Q_i                (kernel 2: grid over j)
+  dK_j    = scale * sum_i dS_ij^T Q_i
+  dQ_i    = scale * sum_j dS_ij K_j
 
-so gradients also run at flash memory cost — no ``jax.vjp`` of a dense
-reference anywhere. Oracle for all three kernels: ``full_attention`` under
+5 matmuls a tile. The grid goes over key blocks ``j``: dK_j and dV_j are
+a program's own, and dQ_i, which every key block of the band adds to, is
+summed in a float32 accumulator that holds the head's whole ``(T, D)`` in
+VMEM across the head's programs (no partial dQ and no float32 dQ in HBM).
+Two kernels, one by query block for dQ and one by key block for dK and
+dV, would each compute the scores and ``dO.V`` for themselves: 7 matmuls
+and the softmax's elementwise work twice.
+
+Gradients also run at flash memory cost — no ``jax.vjp`` of a dense
+reference anywhere. Oracle for both kernels: ``full_attention`` under
 ``jax.vjp``, asserted in interpret mode by tests/test_pallas_kernels.py and
 tests/test_flash_window.py, and on the chip by tests_tpu/.
 
@@ -37,16 +46,19 @@ What the kernels take (``flash_attention``'s docstring has the contract):
   whole, the upper right the edge block's, and the two on the diagonal are
   selected from both by a mask of two local iotas, so that of either
   block's product only the three quadrants that hold needed pairs are
-  multiplied. The backward's kernels fold the same way (``dkv``: key block
-  ``j`` with query blocks ``j`` and ``j + window / block``, the row
-  statistics selected with them). The query blocks with no edge block (the
-  first ``window / block``) and the key blocks with no later query block
-  keep the masked tiles; any other window, and every padded length,
-  compiles the masked schedule alone;
+  multiplied. The backward folds the same way (key block ``j`` with query
+  blocks ``j`` and ``j + window / block``, the row statistics selected
+  with them; ``_fold_tn`` takes the tile's ``ds`` apart again for the two
+  blocks' dQ). The query blocks with no edge block (the first ``window /
+  block``) and the key blocks with no later query block keep the masked
+  tiles; any other window, and every padded length, compiles the masked
+  schedule alone;
 - grouped key-value heads: ``k`` and ``v`` may hold ``H / G`` heads; query
   head ``h`` reads head ``h // G`` through the block index, so the repeated
   keys and values are never written anywhere. ``dk`` and ``dv`` come out of
-  the kernel per query head and are summed over the group by XLA;
+  the kernel per query head, through the block index ordered by a head's
+  place in its group first, and are summed over the group by XLA as ``G``
+  runs side by side;
 - any head size. Where it is a multiple of 128 the kernels read
   ``(B, T, H*D)`` as it leaves the projection, a 128-lane column block a
   head, with no transpose; otherwise (the ViT's 16 to 64) the arrays are
@@ -64,10 +76,17 @@ What the kernels take (``flash_attention``'s docstring has the contract):
   float32, the probabilities cast to the operands' type only where they
   enter a matmul.
 
-Each program holds one head's whole ``(T, D)`` keys and values (the
-backward's second kernel: queries and output gradients) in VMEM: 2 MB each
-at T = 8192, D = 128 in bf16, fetched once per head (per key-value head in
-the forward: the block index does not change inside a group).
+A forward program holds one head's whole ``(T, D)`` keys and values in
+VMEM: 2 MB each at T = 8192, D = 128 in bf16, fetched once per key-value
+head (the block index does not change inside a group). A backward program
+holds the head's whole queries and output gradients, the dQ block they
+leave through (two buffers each) and the float32 accumulator:
+``T D (6 itemsize + 4)`` bytes, 16 MiB at T = 8192 and 32 MiB at 16,384
+in bf16 at D = 128, under ``VMEM_LIMIT_BYTES`` with room for the tiles.
+The shape rule (``_backward_vmem_limit``): a longer call raises its limit
+by what it keeps resident, and one that would pass ``VMEM_MAX_BYTES``
+(T = 49,152 in bf16 at D = 128) is refused by name; there is no second
+path.
 
 Layout of the public function: ``(B, T, H, D)``.
 """
@@ -93,8 +112,15 @@ LANES = 128
 # and values (8 MB at T = 8192, D = 128, bf16; 16 MB in f32) and a few
 # (block, block) float32 tiles. The v5e has 128 MiB.
 VMEM_LIMIT_BYTES = 64 * 1024 * 1024
+# The backward's shape rule (``_backward_vmem_limit``): beside what it
+# keeps resident a program's tiles and stack took 1.5 MiB (bf16) to 9.2 MiB
+# (float32 operands under ``highest``) in compiles for a described v5e; no
+# call is given more than 112 of the chip's 128 MiB.
+VMEM_TILE_BYTES = 16 * 1024 * 1024
+VMEM_MAX_BYTES = 112 * 1024 * 1024
 _NT = (((1,), (1,)), ((), ()))  # a @ b.T
 _NN = (((1,), (0,)), ((), ()))  # a @ b
+_TN = (((0,), (0,)), ((), ()))  # a.T @ b
 
 
 def _dot(a, b, dims):
@@ -112,7 +138,7 @@ def _visible(iq, jk, block, t_real, causal, window, keys_first=False):
     """Which (query, key) pairs of tile (iq, jk) count: both in range, the
     key not after the query (``causal``) and fewer than ``window`` before
     it. ``(block, block)`` bool, queries along the rows, or along the
-    columns where ``keys_first`` (the transposed tiles of ``_dkv_kernel``).
+    columns where ``keys_first`` (the transposed tiles of ``_bwd_kernel``).
 
     The causal form is start-aligned (qi >= ki), identical to the dense
     oracle's end-aligned mask because Tq == Tk, which ``flash_attention``
@@ -253,11 +279,6 @@ def _col_to_row(col):
                    keepdims=True)
 
 
-def _row_to_col(row):
-    return jnp.sum(jnp.where(_eye(row.shape[1]), row, 0.0), axis=1,
-                   keepdims=True)
-
-
 def _rows(ref, j, block):
     return ref[pl.ds(pl.multiple_of(j * block, block), block), :]
 
@@ -356,8 +377,7 @@ def tile_counts(t: int, block: int | None, causal: bool, window) -> dict:
     keeps, ``evaluated_pairs`` the tiles' ``block * block`` each, and the
     tiles by body: ``plain`` (no mask), ``masked`` (``_visible``),
     ``folded`` (diagonal and edge in one). By query block, as the forward
-    and ``flash_bwd_dq`` go; ``flash_bwd_dkv`` visits the same tiles by
-    key block."""
+    goes; the backward visits the same tiles by key block."""
     block, t_pad = _block_sizes(t, block)
     n = t_pad // block
     fold = _fold_width(t, block, n, window)
@@ -410,16 +430,16 @@ class _Layout:
         return ((b, self.t_pad, h * self.d) if self.lane_blocked
                 else (b, h, self.t_pad, self.d))
 
-    def spec(self, rows: int, *, blocked: bool, group: int = 1):
+    def spec(self, rows: int, *, blocked: bool, head=lambda h: h):
         """Block ``i`` of ``rows`` rows (or, not ``blocked``, the whole
-        sequence) of head ``h // group``, for a grid (b, h, i)."""
+        sequence) of head ``head(h)``, for a grid (b, h, i)."""
         if self.lane_blocked:
             return pl.BlockSpec(
                 (None, rows, self.d),
-                lambda b, h, i: (b, i if blocked else 0, h // group))
+                lambda b, h, i: (b, i if blocked else 0, head(h)))
         return pl.BlockSpec(
             (None, None, rows, self.d),
-            lambda b, h, i: (b, h // group, i if blocked else 0, 0))
+            lambda b, h, i: (b, head(h), i if blocked else 0, 0))
 
 
 def _row_stat_spec(n_blocks: int, block: int, *, blocked: bool):
@@ -433,10 +453,10 @@ def _row_stat_spec(n_blocks: int, block: int, *, blocked: bool):
                         lambda b, h, i: (b, h, 0, 0, 0))
 
 
-def _params():
+def _params(vmem_limit_bytes: int = VMEM_LIMIT_BYTES):
     return pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel", "arbitrary"),
-        vmem_limit_bytes=VMEM_LIMIT_BYTES)
+        vmem_limit_bytes=vmem_limit_bytes)
 
 
 def _flash_forward(q, k, v, causal: bool, window, scale: float,
@@ -447,17 +467,14 @@ def _flash_forward(q, k, v, causal: bool, window, scale: float,
     n = t_pad // block
     lay = _Layout(d, t, t_pad)
     flash_schedules.record(tile_counts(t, block, causal, window))
+    whole_kv = lay.spec(t_pad, blocked=False, head=lambda hq: hq // group)
     kernel = functools.partial(
         _fwd_kernel, block=block, causal=causal, window=window,
         scale=scale, t_real=t, fold=_fold_width(t, block, n, window))
     out, lse = pl.pallas_call(
         kernel,
         grid=(b, h, n),
-        in_specs=[
-            lay.spec(block, blocked=True),
-            lay.spec(t_pad, blocked=False, group=group),
-            lay.spec(t_pad, blocked=False, group=group),
-        ],
+        in_specs=[lay.spec(block, blocked=True), whole_kv, whole_kv],
         out_specs=(lay.spec(block, blocked=True),
                    _row_stat_spec(n, block, blocked=True)),
         out_shape=(
@@ -476,66 +493,53 @@ def _flash_forward(q, k, v, causal: bool, window, scale: float,
 # --------------------------------------------------------------------------
 
 
-def _dq_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref, dq_ref, *,
-               block: int, causal: bool, window, scale: float, t_real: int,
-               fold: int):
-    """Grid (B, H, q-block): stream the band's K/V, accumulate this
-    q-block's dQ."""
-    iq = pl.program_id(2)
-    q, do = q_ref[...], do_ref[...]           # (BQ, D)
-    lse = _row_to_col(lse_ref[...])           # (BQ, 1)
-    delta = _row_to_col(delta_ref[...])       # (BQ, 1)
+def _fold_tn(p, y, low):
+    """``p_lo^T y`` and ``p_up^T y`` for a folded tile ``p``
+    (``_fold_nt``'s layout): the inverse of ``_fold_nn``, what the tile's
+    entries at and below the diagonal give their columns' block and what
+    the others give theirs, by the same quadrants, 3 quarter tiles each."""
+    h = p.shape[0] // 2
+    top_left, bottom_right = p[:h, :h], p[h:, h:]
 
-    def body(j, dq, masked):
-        k_blk, v_blk = _rows(k_ref, j, block), _rows(v_ref, j, block)
-        p = jnp.exp(_dot(q, k_blk, _NT) * scale - lse)  # (BQ, BK)
-        if masked:
-            p = jnp.where(
-                _visible(iq, j, block, t_real, causal, window), p, 0.0)
-        ds = p * (_dot(do, v_blk, _NT) - delta)
-        return dq + _dot(ds.astype(k_blk.dtype), k_blk, _NN)
+    def tn(a, b):
+        return _dot(a.astype(y.dtype), b, _TN)
 
-    def finish(dq):
-        dq_ref[...] = (scale * dq).astype(dq_ref.dtype)
-
-    def banded():
-        finish(_banded_loop(
-            _key_blocks(iq, block, k_ref.shape[0] // block, t_real, causal,
-                        window),
-            body, jnp.zeros(q.shape, jnp.float32)))
-
-    def folded():
-        low = _on_diagonal(block // 2)
-        k_d, v_d = _rows(k_ref, iq, block), _rows(v_ref, iq, block)
-        k_e, v_e = (_rows(k_ref, iq - fold, block),
-                    _rows(v_ref, iq - fold, block))
-        p = jnp.exp(_fold_nt(q, k_d, k_e, low) * scale - lse)
-        ds = p * (_fold_nt(do, v_d, v_e, low) - delta)
-        dq = _fold_nn(ds, k_d, k_e, low)
-        if fold > 1:
-            dq = jax.lax.fori_loop(
-                iq - fold + 1, iq, functools.partial(body, masked=False), dq)
-        finish(dq)
-
-    if fold:
-        pl.when(iq < fold)(banded)
-        pl.when(iq >= fold)(folded)
-    else:
-        banded()
+    from_lo = jnp.concatenate(
+        [tn(jnp.concatenate([jnp.where(low, top_left, 0.0), p[h:, :h]],
+                            axis=0), y),
+         tn(jnp.where(low, bottom_right, 0.0), y[h:])], axis=0)
+    from_up = jnp.concatenate(
+        [tn(jnp.where(low, 0.0, top_left), y[:h]),
+         tn(jnp.concatenate([p[:h, h:], jnp.where(low, 0.0, bottom_right)],
+                            axis=0), y)], axis=0)
+    return from_lo, from_up
 
 
-def _dkv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref,
-                dk_ref, dv_ref, *, block: int, causal: bool, window,
-                scale: float, t_real: int, fold: int):
-    """Grid (B, H, k-block): stream the band's Q/dO rows, accumulate one
-    query head's share of dK and dV. The tiles are transposed, keys along
-    the rows, so that the per-query statistics broadcast as the lane-major
-    rows they are stored as and all four matmuls are plain or
-    transposed-right. Folded, key block ``jk`` meets query block ``jk``
-    through the diagonal and ``jk + fold`` through the window's edge."""
+def _bwd_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref,
+                dq_ref, dk_ref, dv_ref, dq_acc, *, block: int, causal: bool,
+                window, scale: float, t_real: int, fold: int):
+    """Grid (B, H, k-block): stream the band's Q/dO rows, evaluate each
+    tile once and take all three gradients from it: one query head's share
+    of this key block's dK and dV as the loop's carry, and each query
+    block's share of dQ added into ``dq_acc``, the head's whole ``(T, D)`` in
+    float32, zeroed at key block 0 and written out at the last (``dq_ref``
+    is the head's whole sequence, so it leaves VMEM once a head). The
+    tiles are transposed, keys along the rows, so that the per-query
+    statistics broadcast as the lane-major rows they are stored as and
+    four matmuls are plain or transposed-right; the fifth, ``ds^T k``,
+    contracts the rows of both. Folded, key block ``jk`` meets query
+    block ``jk`` through the diagonal and ``jk + fold`` through the
+    window's edge."""
     jk = pl.program_id(2)
     n = q_ref.shape[0] // block
     k_blk, v_blk = k_ref[...], v_ref[...]     # (BK, D)
+
+    @pl.when(jk == 0)
+    def _():
+        dq_acc[...] = jnp.zeros(dq_acc.shape, jnp.float32)
+
+    def add_dq(i, dq):
+        dq_acc[pl.ds(pl.multiple_of(i * block, block), block), :] += dq
 
     def body(i, carry, masked):
         dk, dv = carry
@@ -547,8 +551,9 @@ def _dkv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref,
                 _visible(i, jk, block, t_real, causal, window,
                          keys_first=True), p, 0.0)
         dv = dv + _dot(p.astype(do.dtype), do, _NN)
-        ds = p * (_dot(v_blk, do, _NT) - delta)
-        return dk + _dot(ds.astype(q.dtype), q, _NN), dv
+        ds = (p * (_dot(v_blk, do, _NT) - delta)).astype(q.dtype)
+        add_dq(i, _dot(ds, k_blk, _TN))
+        return dk + _dot(ds, q, _NN), dv
 
     def finish(dk, dv):
         dk_ref[...] = (scale * dk).astype(dk_ref.dtype)
@@ -572,6 +577,9 @@ def _dkv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref,
         p = jnp.exp(_fold_nt(k_blk, q_e, q_d, low) * scale - lse)
         dv = _fold_nn(p, do_e, do_d, low)
         ds = p * (_fold_nt(v_blk, do_e, do_d, low) - delta)
+        dq_e, dq_d = _fold_tn(ds, k_blk, low)
+        add_dq(jk + fold, dq_e)
+        add_dq(jk, dq_d)
         carry = _fold_nn(ds, q_e, q_d, low), dv
         if fold > 1:
             carry = jax.lax.fori_loop(
@@ -585,14 +593,40 @@ def _dkv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref,
     else:
         banded()
 
+    @pl.when(jk == n - 1)
+    def _():
+        dq_ref[...] = (scale * dq_acc[...]).astype(dq_ref.dtype)
 
-def _sum_over_group(x, group: int, dtype):
-    """Per-query-head dK or dV ``(B, T, H, D)`` -> ``(B, T, H/G, D)``."""
+
+def _sum_over_group(x, group: int, lay: _Layout, dtype):
+    """Per-query-head dK or dV as the kernel wrote it, the heads ordered by
+    their place in the group first (``_flash_backward``) -> per key-value
+    head, summed in float32: ``group`` runs side by side, of lanes or,
+    head-major, of heads. (Ordered as the queries are, the sum would view
+    the lanes as ``(H/G, G, D)``, and the array changes its tiling on the
+    way there: a copy of all of it wherever ``G`` is not 8.)"""
     if group == 1:
         return x
-    b, t, h, d = x.shape
-    return jnp.sum(x.reshape(b, t, h // group, group, d), axis=3,
-                   dtype=jnp.float32).astype(dtype)
+    parts = jnp.split(x, group, axis=-1 if lay.lane_blocked else 1)
+    return sum(part.astype(jnp.float32) for part in parts).astype(dtype)
+
+
+def _backward_vmem_limit(t_pad: int, d: int, itemsize: int) -> int:
+    """Scoped VMEM for a backward program, from what it keeps resident
+    (module docstring): the head's whole ``q`` and ``do`` and the dQ block,
+    two buffers each, and the float32 accumulator; ``VMEM_LIMIT_BYTES``
+    wherever that leaves the tiles ``VMEM_TILE_BYTES``, as both training
+    cells' calls do."""
+    resident = t_pad * max(d, LANES) * (6 * itemsize + 4)
+    limit = max(VMEM_LIMIT_BYTES, resident + VMEM_TILE_BYTES)
+    if limit > VMEM_MAX_BYTES:
+        raise ValueError(
+            f"flash_attention's backward keeps a head's whole queries, "
+            f"output gradients and dQ in VMEM: {resident >> 20} MiB at "
+            f"T={t_pad}, D={d} and {itemsize} bytes an element, and with "
+            f"{VMEM_TILE_BYTES >> 20} MiB for its tiles that passes "
+            f"{VMEM_MAX_BYTES >> 20} MiB; shard the sequence")
+    return limit
 
 
 def _flash_backward(q, k, v, o, lse, g, causal: bool, window, scale: float,
@@ -602,7 +636,7 @@ def _flash_backward(q, k, v, o, lse, g, causal: bool, window, scale: float,
     block, t_pad = _block_sizes(t, block_override)
     n = t_pad // block
     lay = _Layout(d, t, t_pad)
-    qp, kp, vp, dop = lay.pack(q), lay.pack(k), lay.pack(v), lay.pack(g)
+    vmem_limit = _backward_vmem_limit(t_pad, d, q.dtype.itemsize)
     # delta = rowsum(dO * O): tiny elementwise op, fine in XLA; kept
     # lane-major like lse.
     delta = jnp.einsum("bthd,bthd->bht", g, o,
@@ -611,46 +645,41 @@ def _flash_backward(q, k, v, o, lse, g, causal: bool, window, scale: float,
         delta = jnp.pad(delta, ((0, 0), (0, 0), (0, t_pad - t)))
     delta = delta.reshape(b, h, n, 1, block)
 
-    flash_schedules.record(tile_counts(t, block, causal, window))
-    common = dict(block=block, causal=causal, window=window, scale=scale,
-                  t_real=t, fold=_fold_width(t, block, n, window))
-    blk = lay.spec(block, blocked=True)
-    blk_kv = lay.spec(block, blocked=True, group=group)
+    flash_schedules.record(tile_counts(t, block, causal, window),
+                           backward_kernels=1)
+    kv_heads = h // group
+    blk_kv = lay.spec(block, blocked=True, head=lambda hq: hq // group)
+    # dK and dV leave per query head, head ``j * group + g`` in place
+    # ``g * kv_heads + j``: ``_sum_over_group`` adds the ``g``.
+    blk_out = lay.spec(
+        block, blocked=True,
+        head=lambda hq: hq % group * kv_heads + hq // group)
     whole = lay.spec(t_pad, blocked=False)
-    whole_kv = lay.spec(t_pad, blocked=False, group=group)
-    stat = _row_stat_spec(n, block, blocked=True)
     stat_whole = _row_stat_spec(n, block, blocked=False)
-    grid = (b, h, n)
-
-    dq = pl.pallas_call(
-        functools.partial(_dq_kernel, **common),
-        grid=grid,
-        in_specs=[blk, blk, stat, stat, whole_kv, whole_kv],
-        out_specs=blk,
-        out_shape=jax.ShapeDtypeStruct(lay.shape(b, h), q.dtype),
-        compiler_params=_params(),
-        interpret=interpret,
-        name="flash_bwd_dq",
-    )(qp, dop, lse, delta, kp, vp)
-
-    dk, dv = pl.pallas_call(
-        functools.partial(_dkv_kernel, **common),
-        grid=grid,
+    dq, dk, dv = pl.pallas_call(
+        functools.partial(
+            _bwd_kernel, block=block, causal=causal, window=window,
+            scale=scale, t_real=t, fold=_fold_width(t, block, n, window)),
+        grid=(b, h, n),
         in_specs=[blk_kv, blk_kv, whole, whole, stat_whole, stat_whole],
-        out_specs=(blk, blk),
+        out_specs=(whole, blk_out, blk_out),
         out_shape=(
+            jax.ShapeDtypeStruct(lay.shape(b, h), q.dtype),
             jax.ShapeDtypeStruct(lay.shape(b, h), k.dtype),
             jax.ShapeDtypeStruct(lay.shape(b, h), v.dtype),
         ),
-        compiler_params=_params(),
+        scratch_shapes=[pltpu.VMEM((t_pad, d), jnp.float32)],
+        compiler_params=_params(vmem_limit),
         interpret=interpret,
-        name="flash_bwd_dkv",
-    )(kp, vp, qp, dop, lse, delta)
+        # ``benchmark/scopes_lm.py`` finds the backward's calls and time
+        # under a name that begins ``flash_bwd_dq``.
+        name="flash_bwd_dq_dkv",
+    )(lay.pack(k), lay.pack(v), lay.pack(q), lay.pack(g), lse, delta)
 
     return (
         lay.unpack(dq),
-        _sum_over_group(lay.unpack(dk), group, k.dtype),
-        _sum_over_group(lay.unpack(dv), group, v.dtype),
+        lay.unpack(_sum_over_group(dk, group, lay, k.dtype)),
+        lay.unpack(_sum_over_group(dv, group, lay, v.dtype)),
     )
 
 

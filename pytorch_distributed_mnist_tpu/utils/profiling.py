@@ -273,20 +273,29 @@ class ScheduleLog:
     forward and once a backward, beside its ``pallas_lowerings`` entry):
     how many there were, how many of them fold the band's two half-masked
     tiles into one, and over those the pairs the tiles evaluate a pair
-    the mask keeps — 1 is a kernel that evaluates no pair in vain."""
+    the mask keeps — 1 is a kernel that evaluates no pair in vain. Of the
+    backward calls, how many there were and how many run as one kernel,
+    which evaluates each tile once for all three gradients."""
 
     def __init__(self) -> None:
         self._lock = threading.Lock()
         self._sites = self._folded_sites = 0
         self._needed = self._evaluated = 0
+        self._backward_sites = self._fused_backward_sites = 0
 
-    def record(self, counts: Dict[str, int]) -> None:
+    def record(self, counts: Dict[str, int],
+               backward_kernels: int = 0) -> None:
+        """``backward_kernels``: the Pallas calls a backward site makes;
+        0 for a forward."""
         with self._lock:
             self._sites += 1
             if counts["folded"]:
                 self._folded_sites += 1
                 self._needed += counts["needed_pairs"]
                 self._evaluated += counts["evaluated_pairs"]
+            if backward_kernels:
+                self._backward_sites += 1
+                self._fused_backward_sites += backward_kernels == 1
 
     def snapshot(self) -> Dict:
         with self._lock:
@@ -295,7 +304,9 @@ class ScheduleLog:
                 "folded_sites": self._folded_sites,
                 "folded_evaluated_over_needed": (
                     round(self._evaluated / self._needed, 4)
-                    if self._needed else None)}
+                    if self._needed else None),
+                "backward_sites": self._backward_sites,
+                "fused_backward_sites": self._fused_backward_sites}
 
 
 flash_schedules = ScheduleLog()

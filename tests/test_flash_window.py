@@ -7,6 +7,8 @@ folded schedule of a window that is a multiple of the block (the band's
 diagonal and edge tiles evaluated as one): its results, what it covers,
 its counts, and which calls it leaves alone."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -285,7 +287,99 @@ def test_only_a_window_of_whole_blocks_changes_the_program(
     monkeypatch.setattr(flash, "_fold_width", lambda *a: 0)
     without = program()
     assert (with_fold != without) == folds
-    assert ("cond[" in with_fold) == folds  # the kernels' ``pl.when``
+    # the folded kernels' ``pl.when``, beside the two of every backward
+    # (zero the dQ accumulator, write it out)
+    assert without.count("cond[") == 2
+    assert (with_fold.count("cond[") > 2) == folds
+
+
+# The one backward kernel against the dense oracle under ``jax.vjp``: every
+# kind of tile it has a body for, by the sizes that choose the body, the
+# layout and the grouping (two key-value heads, so that dK and dV leave
+# the kernel in another order of heads than the queries'). T = 48 at block
+# 16 is three key blocks, so every query block's rows of the dQ accumulator
+# but the first's are read back and added to by more than one program;
+# T = 40 pads the last block.
+KINDS = {
+    "full causal": dict(causal=True, window=None),
+    "window of whole blocks": dict(causal=True, window=16),  # folds unpadded
+    "window across blocks": dict(causal=True, window=12),
+    "non-causal": dict(causal=False, window=None),
+}
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("t", [48, 40], ids=["unpadded", "padded"])
+@pytest.mark.parametrize("d", [128, 64], ids=["lane-blocked", "head-major"])
+@pytest.mark.parametrize("group", [1, 2, 8])
+@pytest.mark.parametrize("kind", list(KINDS))
+def test_one_backward_kernel_matches_dense_vjp(kind, group, d, t, dtype):
+    block = 16
+    mask = KINDS[kind]
+    assert bool(flash.tile_counts(t, block, mask["causal"],
+                                  mask["window"])["folded"]) \
+        == (kind == "window of whole blocks" and t == 48)
+    q, k, v = (x.astype(dtype) for x in _qkv(t, 2 * group, 2, d, seed=5, b=1))
+    g = jax.random.normal(jax.random.key(6), q.shape, jnp.float32)
+
+    def grads(f, **kw):  # jitted whole: op by op the oracle takes 2 s
+        def run(*a):
+            out, vjp = jax.vjp(functools.partial(f, **mask, **kw), *a)
+            return out, vjp(g.astype(dtype))
+        return jax.jit(run)(q, k, v)
+
+    out, got = grads(flash_attention, block=block)
+    _, want = grads(full_attention)
+    assert out.dtype == dtype
+    for name, g_, w in zip(("dq", "dk", "dv"), got, want):
+        assert g_.shape == w.shape and g_.dtype == dtype, name
+        w = np.asarray(w.astype(jnp.float32))
+        if dtype == jnp.float32:
+            limits = dict(atol=5e-5, rtol=5e-5)
+        else:
+            limits = dict(atol=0.03 * np.abs(w).max() + 1e-2)
+        np.testing.assert_allclose(g_.astype(jnp.float32), w,
+                                   err_msg=name, **limits)
+
+
+def test_dq_is_summed_over_every_key_block_of_the_band():
+    """Five key blocks and a window of two: a folded program adds to the
+    accumulator rows of two query blocks and its plain tile to a third's;
+    the last two key blocks run the masked body. Each query block's dQ is
+    what the oracle gives, so no program's share was lost or added twice."""
+    q, k, v = _qkv(80, 2, 1, 16, seed=7, b=1)
+    assert flash.tile_counts(80, 16, True, 32) == {
+        "needed_pairs": 32 * 33 // 2 + 48 * 32, "evaluated_pairs": 9 * 256,
+        "plain": 4, "masked": 2, "folded": 3}
+
+    def dq(f, **kw):
+        return jax.grad(lambda q: jnp.sum(jnp.sin(
+            f(q, k, v, causal=True, window=32, **kw))))(q)
+
+    np.testing.assert_allclose(dq(flash_attention, block=16),
+                               dq(full_attention), atol=5e-5, rtol=5e-5)
+
+
+def test_backward_vmem_follows_the_shape():
+    """The shape rule: what a backward program keeps resident sets its
+    scoped VMEM. Both training cells' calls leave the tiles their room
+    under the constant; a longer call is given more; one the chip cannot
+    hold is refused by name, at trace time, forward untouched."""
+    mib = 1 << 20
+    assert flash._backward_vmem_limit(8192, 128, 2) == flash.VMEM_LIMIT_BYTES
+    assert flash._backward_vmem_limit(16384, 128, 2) == flash.VMEM_LIMIT_BYTES
+    assert flash._backward_vmem_limit(16384, 128, 4) == (56 + 16) * mib
+    assert flash._backward_vmem_limit(32768, 128, 2) == (64 + 16) * mib
+    # a head of 64 fills 128 lanes
+    assert flash._backward_vmem_limit(32768, 64, 2) == (64 + 16) * mib
+    assert flash._backward_vmem_limit(49152, 128, 2) == flash.VMEM_MAX_BYTES
+    x = jax.ShapeDtypeStruct((1, 65536, 1, 128), jnp.bfloat16)
+    assert jax.eval_shape(
+        lambda *a: flash_attention(*a, causal=True), x, x, x).shape == x.shape
+    with pytest.raises(ValueError, match="T=65536, D=128.*shard"):
+        jax.eval_shape(jax.grad(lambda *a: jnp.sum(flash_attention(
+            *a, causal=True).astype(jnp.float32))), x, x, x)
 
 
 def test_a_traced_call_records_its_schedule():
@@ -300,6 +394,7 @@ def test_a_traced_call_records_its_schedule():
     after = flash_schedules.snapshot()
     assert after["sites"] == before["sites"] + 1
     assert after["folded_sites"] == before["folded_sites"]
+    assert after["backward_sites"] == before["backward_sites"]
     # forward and backward of a folded call: two sites more
     jax.make_jaxpr(jax.grad(lambda *a: jnp.sum(flash_attention(
         *a, causal=True, window=16, block=16))))(q, k, v)
@@ -307,6 +402,9 @@ def test_a_traced_call_records_its_schedule():
     assert folded["sites"] == after["sites"] + 2
     assert folded["folded_sites"] == after["folded_sites"] + 2
     assert 1.0 <= folded["folded_evaluated_over_needed"] < 2.0
+    # the backward is one site and one kernel, which the program shows
+    assert folded["backward_sites"] == after["backward_sites"] + 1
+    assert folded["fused_backward_sites"] == folded["backward_sites"]
     assert device_report()["flash_schedules"] == folded
 
 

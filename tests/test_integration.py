@@ -237,7 +237,8 @@ def test_metrics_file(tmp_path):
     assert summary["input_backend"] in ("native", "numpy")
     assert set(summary["pallas_lowerings"]) == {"mosaic", "interpret"}
     assert set(summary["flash_schedules"]) == {
-        "sites", "folded_sites", "folded_evaluated_over_needed"}
+        "sites", "folded_sites", "folded_evaluated_over_needed",
+        "backward_sites", "fused_backward_sites"}
     assert set(summary["dense_attention_slices"]) == {
         "sites", "sliced_sites", "slices_per_sliced_site"}
     assert "train_epoch" in summary["compile_stats"]["programs"]

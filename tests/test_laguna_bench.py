@@ -214,9 +214,42 @@ def test_lm_scope_classes_and_kernel_names():
     assert scopes_lm.kind_of(f"{jvp}/attn/attn_core/full/x") == "full"
     assert scopes_lm.kernel_of("%flash_bwd_dkv.3 = custom-call", "") \
         == "flash_bwd_dkv"
+    # the one backward kernel (ops/pallas/flash.py) reads as the first
+    assert scopes_lm.kernel_of("%flash_bwd_dq_dkv.3 = custom-call", "") \
+        == "flash_bwd_dq"
     assert scopes_lm.kernel_of("custom-call.7", f"{jvp}/flash_fwd") \
         == "flash_fwd"
     assert scopes_lm.kernel_of("fusion.1", jvp) is None
+
+
+def test_backward_roofline_reads_one_kernel_a_call():
+    """``flash_bwd_roofline`` takes the calls of ``flash_bwd_dq`` and the
+    seconds of both backward names; a program whose backward is one kernel
+    leaves ``flash_bwd_dkv`` at 0 calls and 0 s and still reads a number:
+    the need of the calls over the one kernel's time."""
+    from types import SimpleNamespace
+
+    from benchmark import flash_cost
+
+    def cell(s, calls):
+        return {"s": s, "calls": calls}
+
+    found = {"kernels": {
+        "flash_fwd": {"full": cell(0.065, 4), "window": cell(0.042, 12)},
+        "flash_bwd_dq": {"full": cell(0.060, 2), "window": cell(0.048, 6)},
+        "flash_bwd_dkv": {"full": cell(0.0, 0), "window": cell(0.0, 0)}}}
+    config = spec_and_config()[1]
+    run = SimpleNamespace(
+        config=config,
+        counters={"scopes_lm": found, "batch": 2, "chips": 1,
+                  "tokens_per_image": 8192, "device_kind": "TPU v5 lite"})
+    share = flash_cost.roofline_share(
+        run, ("flash_bwd_dq", "flash_bwd_dkv"), flash_cost.backward)
+    calls = flash_cost.layer_calls(config["kwargs"], batch=2, seq_len=8192)
+    least = sum(n * flash_cost.backward(**calls[kind])["flops"] / 197e12
+                for kind, n in (("full", 2), ("window", 6)))
+    assert share == pytest.approx(100.0 * least / 0.108)
+    assert 0 < share < 100
 
 
 def test_tiny_cell_runs_correct_and_counts_its_routing(tiny_root):

@@ -155,3 +155,36 @@ def test_the_kernels_compile_at_the_cells_shape_and_hold_no_states(
     assert "selective_scan_fwd" in text and "selective_scan_bwd" in text
     assert compiled.memory_analysis().temp_size_in_bytes < 2e9
     assert f"[1,{t},{n},{c}]" not in text and f"[1,{t},{c},{n}]" not in text
+
+
+def test_no_op_of_xlas_carries_the_flash_backwards_scope(one_chip,
+                                                         monkeypatch):
+    """In this file because it is the one that describes the chip. The
+    benchmark counts a backward call for every device op whose scope names
+    the backward kernel (``benchmark/scopes_lm.py kernel_of``), and a copy
+    that XLA puts behind a kernel inherits the kernel's scope: with dK and
+    dV viewed as ``(H/G, G, D)`` for the group's sum, a group of 6 cost two
+    such copies a call and ``flash_bwd_roofline`` read three calls for
+    one. Compiled among free layouts (projections before and behind, as
+    in ``models/decoder.py``), the kernel is alone under its name."""
+    import re
+
+    from pytorch_distributed_mnist_tpu.ops.pallas import flash
+
+    monkeypatch.setattr(flash, "should_interpret", lambda: False)
+    b, t, h, kv, d, width = 1, 1024, 12, 2, 128, 256
+
+    def shape(*dims):
+        return jax.ShapeDtypeStruct(dims, jnp.bfloat16, sharding=one_chip)
+
+    def loss(x, wq, wk, wv, wo):
+        q, k, v = ((x @ w).reshape(b, t, -1, d) for w in (wq, wk, wv))
+        o = flash.flash_attention(q, k, v, causal=True)
+        return jnp.sum((o.reshape(b, t, h * d) @ wo).astype(jnp.float32))
+
+    text = jax.jit(jax.grad(loss, argnums=range(5))).lower(
+        shape(b, t, width), shape(width, h * d), shape(width, kv * d),
+        shape(width, kv * d), shape(h * d, width)).compile().as_text()
+    under = re.findall(
+        r"= \S+ ([\w-]+)\([^\n]*op_name=\"[^\"]*flash_bwd", text)
+    assert under and set(under) <= {"custom-call", "get-tuple-element"}

@@ -16,6 +16,7 @@ import jax.numpy as jnp
 import optax
 import pytest
 
+from benchmark.reference.laguna import _attention_core as reference_core
 from pytorch_distributed_mnist_tpu.ops.attention import full_attention
 from pytorch_distributed_mnist_tpu.ops.pallas.adam import pallas_adam
 from pytorch_distributed_mnist_tpu.ops.pallas.flash import flash_attention
@@ -161,9 +162,10 @@ def test_no_kernel_was_interpreted():
 # masked oracle. The last case is the training cell's own window layer, 64
 # query heads on 8 key-value heads, at T = 2,048 (8,192 is too large for
 # the dense oracle in one piece): four blocks, so the folded schedule's
-# first block (no edge), folded blocks and, in ``flash_bwd_dkv``, last
-# block (no later query block) all occur; the one before it folds at block
-# 128, where a quadrant is half a vector register wide.
+# first block (no edge), folded blocks and, in the backward kernel, which
+# goes by key block, last block (no later query block) all occur; the one
+# before it folds at block 128, where a quadrant is half a vector register
+# wide.
 # (B, T, H_q, H_kv, window).
 WINDOW_CASES = [(2, 1024, 8, 1, 512), (2, 1024, 6, 1, None),
                 (2, 1024, 16, 2, 200), (2, 256, 4, 2, 128),
@@ -194,6 +196,39 @@ def test_flash_window_grouped_heads_on_tpu(b, t, heads, kv_heads, window):
         gr = gr.astype(jnp.float32)
         err = float(jnp.max(jnp.abs(g.astype(jnp.float32) - gr)))
         assert err < 0.03 * float(jnp.max(jnp.abs(gr))) + 1e-2
+
+
+# The one backward kernel at the training cells' lengths, head size and
+# grouping, two key-value heads of each: Laguna's full layer (6 query heads
+# a key-value head, 16 key blocks) and window layer (8 a head, folded),
+# Phi-4-mini-flash's full and cross layers (2 a head, 32 key blocks, the
+# dQ accumulator 8 MB). The oracle is the benchmark's plain reference core,
+# float32 in query blocks that the backward recomputes one at a time: the
+# whole ``(T, T)`` scores here are 3 GB a tensor. (T, H_q, H_kv, window).
+CELL_CASES = [(8192, 12, 2, None), (8192, 16, 2, 512), (16384, 4, 2, None)]
+
+
+@pytest.mark.parametrize("t,heads,kv_heads,window", CELL_CASES)
+def test_flash_gradients_at_the_cells_lengths_on_tpu(t, heads, kv_heads,
+                                                     window):
+    d = 128
+    ks = jax.random.split(jax.random.key(3), 3)
+    q = jax.random.normal(ks[0], (1, t, heads, d), jnp.bfloat16)
+    k = jax.random.normal(ks[1], (1, t, kv_heads, d), jnp.bfloat16)
+    v = jax.random.normal(ks[2], (1, t, kv_heads, d), jnp.bfloat16)
+
+    def grads(f):
+        return jax.jit(jax.grad(lambda *a: jnp.sum(jnp.sin(
+            f(*a).astype(jnp.float32))), argnums=(0, 1, 2)))(q, k, v)
+
+    got = grads(lambda *a: flash_attention(*a, causal=True, window=window))
+    want = grads(lambda *a: reference_core(
+        *(x.astype(jnp.float32) for x in a), window))
+    for name, g, gr in zip(("dq", "dk", "dv"), got, want):
+        assert g.dtype == jnp.bfloat16, name
+        gr = gr.astype(jnp.float32)
+        err = float(jnp.max(jnp.abs(g.astype(jnp.float32) - gr)))
+        assert err < 0.03 * float(jnp.max(jnp.abs(gr))) + 1e-2, (name, err)
 
 
 # The selective scan's two kernels (ops/pallas/ssm.py) at the tiny preset's
