@@ -19,6 +19,12 @@ Drives the main path once, through the entry points a user would call:
               synthetic_tokens`` at its tiny preset on the same sequences:
               two selective scans, differential attention (window, full
               and cross) through the flash kernels, a Gated Memory Unit;
+            * the latent-attention sparse decoder, ``--model instella
+              --dataset synthetic_tokens`` at its tiny preset on the same
+              sequences: latent attention through the flash kernels,
+              top-4 of 16 experts under a selection bias the step moves
+              (the resumed evaluation reads the bias from the
+              checkpoint), the multi-token-prediction module;
   server    python -m pytorch_distributed_mnist_tpu serve      (server ->
             engine -> batcher -> pool) on the checkpoint the trainer just
             wrote, answering ``tools/loadgen.py --smoke`` and a batch of
@@ -75,6 +81,7 @@ LAGUNA = ["--model", "laguna", "--dataset", "synthetic_tokens",
           "--synthetic-train-size", str(LAGUNA_STEPS * LAGUNA_BATCH),
           "--synthetic-test-size", "16", "--lr", "1e-3"]
 SAMBAY = ["--model", "sambay"] + LAGUNA[2:]
+INSTELLA = ["--model", "instella"] + LAGUNA[2:]
 # The trainer's own warnings that a compiled program was refused or
 # compiled twice (train/trainer.py): legitimate on a user's machine,
 # a failure here.
@@ -469,6 +476,7 @@ def main() -> int:
         smoke.train("vit", VIT, kernels=True)
         smoke.train("laguna", LAGUNA, kernels=True, steps=LAGUNA_STEPS)
         smoke.train("sambay", SAMBAY, kernels=True, steps=LAGUNA_STEPS)
+        smoke.train("instella", INSTELLA, kernels=True, steps=LAGUNA_STEPS)
         ref = smoke.reference(cnn)
         smoke.serve(cnn, ref, "f32")
         smoke.serve(cnn, ref, "int8")

@@ -83,6 +83,7 @@ from pytorch_distributed_mnist_tpu.utils.profiling import (
     failure_events,
     phase,
     profile_trace,
+    routing_log,
     staging_log,
 )
 
@@ -143,8 +144,9 @@ def build_parser() -> argparse.ArgumentParser:
                             "synthetic_tokens"],
                    help="synthetic_tokens: a seeded corpus of packed "
                         "token sequences (data/tokens.py) for a token "
-                        "model (--model laguna, sambay); --synthetic-*-size "
-                        "count sequences of --seq-len tokens")
+                        "model (--model laguna, sambay, instella); "
+                        "--synthetic-*-size count sequences of --seq-len "
+                        "tokens")
     p.add_argument("--seq-len", type=int, default=64,
                    help="tokens a sequence, --dataset synthetic_tokens")
     p.add_argument("--download", action="store_true",
@@ -206,15 +208,28 @@ def build_parser() -> argparse.ArgumentParser:
                         "split data x expert, expert count must divide "
                         "evenly. Composes with --optimizer-sharding zero1 "
                         "and --moe-dispatch")
-    p.add_argument("--moe-aux-weight", type=float, default=0.0,
+    p.add_argument("--moe-aux-weight", type=float, default=None,
                    metavar="W",
                    help="weight of the MoE router's load-balance loss in "
                         "the training objective (models/moe.py sows it "
                         "under intermediates; top-1 routing can collapse "
                         "onto one expert without it — 0.01 is a typical "
-                        "switch-transformer value). 0 (default) skips the "
+                        "switch-transformer value). 0 skips the "
                         "capture entirely; metrics always report the "
-                        "cross-entropy alone")
+                        "cross-entropy alone. Default: 0, and for --model "
+                        "instella its source's 1e-4 on the sequence-wise "
+                        "balance term")
+    p.add_argument("--mtp-weight", type=float, default=None, metavar="W",
+                   help="weight of the multi-token-prediction module's "
+                        "cross-entropy (the token after the next) in the "
+                        "objective of --model instella; default its "
+                        "source's 0.3. The reported loss stays the next "
+                        "token's")
+    p.add_argument("--bias-rate", type=float, default=None, metavar="G",
+                   help="what the train step moves an expert's selection "
+                        "bias by a step, against its load (--model "
+                        "instella); default its source's 1e-3, 0 freezes "
+                        "the bias")
     p.add_argument("--moe-dispatch", type=str, default="dense",
                    choices=["dense", "capacity"],
                    help="moe_mlp routing: dense = algebraic one-hot "
@@ -1130,12 +1145,22 @@ def _run_body(args) -> dict:
             "--epoch-gather device requires --trainer-mode scan (the "
             "gather lives inside the scanned epoch program)"
         )
-    aux_weight = getattr(args, "moe_aux_weight", 0.0)
+    from pytorch_distributed_mnist_tpu.models.registry import model_objective
+
+    # The model's own where the flag is not given (--model instella).
+    stated = model_objective(args.model)
+    aux_weight, mtp_weight, bias_rate = (
+        stated.get(key, 0.0) if getattr(args, flag, None) is None
+        else getattr(args, flag)
+        for flag, key in (("moe_aux_weight", "aux_weight"),
+                          ("mtp_weight", "mtp_weight"),
+                          ("bias_rate", "bias_rate")))
     if aux_weight:
-        if args.model != "moe_mlp":
+        if args.model != "moe_mlp" and "aux_weight" not in stated:
             raise SystemExit(
-                f"--moe-aux-weight applies to --model moe_mlp (the router "
-                f"sows the load-balance loss); got --model {args.model}"
+                f"--moe-aux-weight applies to --model moe_mlp and instella "
+                f"(the router sows a load-balance loss); got --model "
+                f"{args.model}"
             )
         if args.trainer_mode == "explicit":
             raise SystemExit(
@@ -1543,8 +1568,8 @@ def _run_body(args) -> dict:
     if tokens != (_token_vocab(args) is not None):
         raise SystemExit(
             f"--model {args.model} and --dataset {args.dataset} do not go "
-            f"together: a token model (laguna, sambay) reads --dataset "
-            f"synthetic_tokens, and nothing else does")
+            f"together: a token model (laguna, sambay, instella) reads "
+            f"--dataset synthetic_tokens, and nothing else does")
     moe_dispatch = getattr(args, "moe_dispatch", "dense")
     if getattr(args, "remat", False):
         if not model_accepts(args.model, "remat"):
@@ -1775,7 +1800,8 @@ def _run_body(args) -> dict:
     trainer = Trainer(state, train_loader, test_loader, mesh=mesh,
                       mode=args.trainer_mode, state_sharding=state_sharding,
                       grad_accum=grad_accum, epoch_gather=epoch_gather,
-                      aux_weight=aux_weight,
+                      aux_weight=aux_weight, mtp_weight=mtp_weight,
+                      bias_rate=bias_rate,
                       feed_window=getattr(args, "feed_window", 2),
                       staging_log=staging_log,
                       zero_overlap=zero_overlap,
@@ -1788,6 +1814,7 @@ def _run_body(args) -> dict:
     # below); reset here so a re-entrant run() reports its own run only.
     compile_log.reset()
     staging_log.reset()
+    routing_log.reset()
     if not args.evaluate and not getattr(args, "no_precompile", False):
         # AOT-compile every program this run will execute on background
         # threads, overlapping the first epoch's host staging below —

@@ -6,9 +6,11 @@ fixed call site (``:185``). Here the model is pluggable via a registry:
 ``linear`` is the exact reference-parity model, ``cnn`` is the small convnet
 required for the >=99% MNIST accuracy target (BASELINE.json north star),
 ``vit`` and ``moe_mlp`` carry attention and experts, ``laguna`` is the
-decoder-only token model family (``models/decoder.py``) and ``sambay`` the
+decoder-only token model family (``models/decoder.py``), ``sambay`` the
 hybrid of state-space, differential-attention, gated-memory and shared-KV
-cross layers (``models/sambay.py``).
+cross layers (``models/sambay.py``) and ``instella`` the latent-attention
+sparse decoder with a selection bias, a multi-token-prediction module and
+the FarSkip residual (``models/instella.py``).
 """
 
 from pytorch_distributed_mnist_tpu.models.linear import LinearNet
@@ -17,6 +19,7 @@ from pytorch_distributed_mnist_tpu.models.attention import VisionTransformer
 from pytorch_distributed_mnist_tpu.models.moe import MoEClassifier, SparseExperts, SwitchMoE
 from pytorch_distributed_mnist_tpu.models.decoder import Decoder
 from pytorch_distributed_mnist_tpu.models.sambay import SambaY
+from pytorch_distributed_mnist_tpu.models.instella import Instella
 from pytorch_distributed_mnist_tpu.models.registry import get_model, register_model, list_models, model_accepts
 
 __all__ = [
@@ -28,6 +31,7 @@ __all__ = [
     "SwitchMoE",
     "Decoder",
     "SambaY",
+    "Instella",
     "get_model",
     "register_model",
     "list_models",

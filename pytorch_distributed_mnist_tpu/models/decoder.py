@@ -116,6 +116,22 @@ def apply_rope(x: jnp.ndarray, inv_freq: np.ndarray, factor: float):
     return out.astype(x.dtype)
 
 
+def apply_rope_interleaved(x: jnp.ndarray, inv_freq: np.ndarray,
+                           factor: float):
+    """Rotate all ``2 * len(inv_freq)`` dimensions of every head of ``x``
+    (B, T, H, R) by position, pairing dimension ``2i`` with ``2i + 1`` (the
+    interleaved convention, ``rope_interleave: true`` of a ``deepseek_v3``
+    configuration); float32 inside."""
+    angles = jnp.arange(x.shape[1], dtype=jnp.float32)[:, None] \
+        * jnp.asarray(inv_freq, jnp.float32)[None, :]  # (T, R/2)
+    cos = (jnp.cos(angles) * factor)[None, :, None, :]
+    sin = (jnp.sin(angles) * factor)[None, :, None, :]
+    xf = x.astype(jnp.float32).reshape(x.shape[:-1] + (-1, 2))
+    a, b = xf[..., 0], xf[..., 1]
+    out = jnp.stack([a * cos - b * sin, b * cos + a * sin], axis=-1)
+    return out.reshape(x.shape).astype(x.dtype)
+
+
 class RMSNorm(nn.Module):
     eps: float = 1e-6
     compute_dtype: jnp.dtype = jnp.bfloat16
@@ -183,6 +199,89 @@ class GatedAttention(nn.Module):
             * nn.sigmoid(dense(h, "gate")(u))[..., None]
         return dense(c, "proj", kernel_init=residual_init(self.depth))(
             o.reshape(b, t, h * d))
+
+
+def yarn_softmax_scale(qk_dim: int, rope: dict) -> float:
+    """``qk_dim ** -0.5 * mscale ** 2`` with ``mscale = 0.1 * mscale_all_dim
+    * ln(factor) + 1``: the softmax scale of latent attention under YaRN
+    (the ``deepseek_v3`` modelling code folds the factor that YaRN puts on
+    cos and sin of both sides into the scale, where a whole head is not
+    rotated)."""
+    mscale = 0.1 * rope.get("mscale_all_dim", 0.0) \
+        * math.log(rope.get("factor", 1.0)) + 1.0
+    return qk_dim ** -0.5 * mscale * mscale
+
+
+class LatentAttention(nn.Module):
+    """Multi-head latent attention (arXiv:2405.04434, section 2.1) with no
+    query latent: keys and values come from one ``kv_rank``-wide latent a
+    token, normed and projected up per head; one ``rope_dim``-wide rotary
+    key a token is given to every head beside the head's ``nope_dim``
+    un-rotated dimensions; an optional RMSNorm over each head's query and
+    key (one scale for all heads) before the rotary; an element-wise
+    sigmoid output gate. Keys and values of the core are ``nope_dim +
+    rope_dim`` and ``v_dim`` wide, one key-value head a query head: where
+    the two widths are equal the flash kernels take it as it is.
+
+    Scopes ``mla/{q, kv_a, kv_b, rope, gate, proj}`` hold what is not the
+    core, which stays under ``attn_core/full``."""
+
+    num_heads: int
+    nope_dim: int
+    rope_dim: int
+    v_dim: int
+    kv_rank: int
+    rope: Any  # rope parameters (a dict), over the ``rope_dim`` dimensions
+    depth: int  # of the model: models/moe.py residual_init
+    qk_norm: bool = True
+    gated: bool = True
+    rms_eps: float = 1e-6
+    attention: str = "auto"
+    compute_dtype: jnp.dtype = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, u: jnp.ndarray) -> jnp.ndarray:
+        b, t, c = u.shape
+        h, nope, rot, dv = (self.num_heads, self.nope_dim, self.rope_dim,
+                            self.v_dim)
+        rope = dict(self.rope)
+
+        def dense(n, name, **kw):
+            return nn.Dense(n, use_bias=False, dtype=self.compute_dtype,
+                            name=name, **kw)
+
+        norm = partial(RMSNorm, self.rms_eps, self.compute_dtype)
+        with jax.named_scope("mla/q"):
+            q = dense(h * (nope + rot), "q")(u).reshape(b, t, h, nope + rot)
+        with jax.named_scope("mla/kv_a"):
+            c_kv, k_rope = jnp.split(
+                dense(self.kv_rank + rot, "kv_a")(u), [self.kv_rank], axis=-1)
+            c_kv = norm(name="kv_norm")(c_kv)
+        with jax.named_scope("mla/kv_b"):
+            k_nope, v = jnp.split(
+                dense(h * (nope + dv), "kv_b")(c_kv).reshape(
+                    b, t, h, nope + dv), [nope], axis=-1)
+            k = jnp.concatenate([k_nope, jnp.broadcast_to(
+                k_rope[:, :, None, :], (b, t, h, rot))], axis=-1)
+        if self.qk_norm:
+            with jax.named_scope("mla/q"):
+                q = norm(name="q_norm")(q)
+            with jax.named_scope("mla/kv_b"):
+                k = norm(name="k_norm")(k)
+        with jax.named_scope("mla/rope"):
+            inv_freq, factor = rope_frequencies(rot, rope)
+            q, k = (jnp.concatenate(
+                [x[..., :nope],
+                 apply_rope_interleaved(x[..., nope:], inv_freq, factor)],
+                axis=-1) for x in (q, k))
+        o = attend(q, k, v, window=None, attention=self.attention,
+                   scale=yarn_softmax_scale(nope + rot, rope))
+        o = o.astype(self.compute_dtype).reshape(b, t, h * dv)
+        if self.gated:
+            with jax.named_scope("mla/gate"):
+                o = o * nn.sigmoid(dense(h * dv, "gate")(u))
+        with jax.named_scope("mla/proj"):
+            return dense(c, "proj", kernel_init=residual_init(self.depth))(o)
 
 
 class DecoderBlock(nn.Module):

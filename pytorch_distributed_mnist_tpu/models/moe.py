@@ -3,14 +3,22 @@
 Two expert layers. Expert weights carry a leading expert dim that
 ``moe_ep_rules`` (parallel/expert.py) shards on the ``expert`` mesh axis.
 
-``SparseExperts`` is the layer of the ``laguna`` decoder
-(models/decoder.py): a sigmoid router over ``num_experts``, ``top_k`` a
-token, SwiGLU experts and one shared expert. It is told which experts it
-holds (``experts_held = (first, count)``), routes over all of them and
-computes its own experts' part for the pairs that land here, none dropped,
-by grouped matmuls (``parallel/moe_dispatch.held_experts_forward``). With
-every expert held it is the whole layer; with a share it is one chip's part
-of an expert-parallel deployment, without the exchange.
+``SparseExperts`` is the expert layer of the decoders (``laguna``,
+models/decoder.py; ``instella``, models/instella.py): a sigmoid router over
+``num_experts``, ``top_k`` a token, SwiGLU experts and one shared SwiGLU
+whose width is the sum of the source's shared experts (one of 512 for
+``laguna``, two of 1,408 as one of 2,816 for ``instella``). It is told
+which experts it holds (``experts_held = (first, count)``), routes over
+all of them and computes its own experts' part for the pairs that land
+here, none dropped, by grouped matmuls
+(``parallel/moe_dispatch.held_experts_forward``). With every expert held it
+is the whole layer; with a share it is one chip's part of an
+expert-parallel deployment, without the exchange. With ``selection_bias``
+the ``top_k`` are chosen on ``score + bias``: the bias is a variable of the
+collection ``router_bias`` that no gradient moves, the layer sows the load
+of all ``num_experts`` beside it and the train step moves the bias against
+the load (``train/steps.py``); with ``balance`` it sows the sequence-wise
+balance term as ``aux_loss``.
 
 ``SwitchMoE`` (``moe_mlp``) is the small classifier's layer: top-1 routing,
 ReLU experts with biases, a softmax gate. Its ``dispatch='dense'`` runs
@@ -52,12 +60,18 @@ import jax.numpy as jnp
 from jax.sharding import Mesh
 
 from pytorch_distributed_mnist_tpu.models.registry import register_model
-from pytorch_distributed_mnist_tpu.ops.metrics import ROUTING_COLLECTION
+from pytorch_distributed_mnist_tpu.ops.metrics import (
+    BIAS_COLLECTION,
+    LOAD_COLLECTION,
+    ROUTING_COLLECTION,
+)
 from pytorch_distributed_mnist_tpu.parallel.moe_dispatch import (
+    expert_load,
     held_experts_forward,
     load_balance_loss,
     moe_capacity_forward,
     route_topk,
+    sequence_balance_loss,
     top1_mask_gate,
 )
 
@@ -95,7 +109,10 @@ class SparseExperts(nn.Module):
     ``F = scale * sum_{e in chosen, held here} w_e E_e(x) + E_shared(x)``
     with ``w_e = s_e / sum_chosen s``, ``s = sigmoid(x W_r)`` over all
     ``num_experts`` (module docstring). Router scores are float32 at the
-    highest matmul precision: the choice is discrete.
+    highest matmul precision: the choice is discrete. ``selection_bias``:
+    the chosen are the ``top_k`` of ``s + b`` (``w_e`` still reads ``s``).
+    ``balance``: the sequence-wise balance term is sown as ``aux_loss``;
+    the input is then (B, T, C), one sequence a row.
     """
 
     num_experts: int
@@ -105,6 +122,8 @@ class SparseExperts(nn.Module):
     depth: int  # of the model: residual_init
     experts_held: Optional[Tuple[int, int]] = None  # (first, count); all
     routed_scale: float = 1.0
+    selection_bias: bool = False
+    balance: bool = False
     compute_dtype: jnp.dtype = jnp.bfloat16
 
     @nn.compact
@@ -116,9 +135,25 @@ class SparseExperts(nn.Module):
             self.num_experts, use_bias=False, dtype=jnp.float32,
             precision=jax.lax.Precision.HIGHEST, name="router",
         )(tokens.astype(jnp.float32))
+        bias = self.variable(
+            BIAS_COLLECTION, "select", jnp.zeros, (self.num_experts,),
+            jnp.float32).value if self.selection_bias else None
         with jax.named_scope("router"):
+            scores = nn.sigmoid(logits)
             idx, weight = route_topk(
-                nn.sigmoid(logits), self.top_k, self.routed_scale)
+                scores, self.top_k, self.routed_scale, bias)
+            if self.selection_bias or self.balance:
+                # (B, E), one sequence a row: what balances the load reads
+                # the pairs given to each of all ``num_experts``.
+                load = expert_load(
+                    idx.reshape((-1, x.shape[-2], self.top_k)),
+                    self.num_experts)
+            if self.balance:
+                self.sow("intermediates", "aux_loss", sequence_balance_loss(
+                    scores.reshape(load.shape[0], x.shape[-2], -1), load,
+                    self.top_k))
+        if self.selection_bias and not self.is_initializing():
+            self.sow(LOAD_COLLECTION, "select", jnp.sum(load, axis=0))
         # For a caller that compares this layer with another computation of
         # it on the same choices (``mutable=['intermediates']``).
         self.sow("intermediates", "choices", idx)

@@ -70,6 +70,14 @@ def model_field_default(name: str, field: str):
     raise ValueError(f"model {name!r} has no field {field!r} with a default")
 
 
+def model_objective(name: str) -> dict:
+    """What weighs the further terms of a registered model's training
+    objective where its source states them (the class's ``objective``:
+    ``aux_weight``, ``mtp_weight``, ``bias_rate``, the arguments of
+    ``train/steps.py _train_step``); ``{}`` for a model that has none."""
+    return dict(getattr(_lookup(name), "objective", {}))
+
+
 def model_accepts(name: str, field: str) -> bool:
     """True if the registered model's constructor takes ``field``.
 

@@ -33,6 +33,20 @@ ROUTING_COUNTERS = ("landed", "routed", "dropped", "max_over_mean",
 # The flax collection the expert layers sow them into (``models/moe.py``);
 # the train step asks for it where ``TrainState.counters`` says so.
 ROUTING_COLLECTION = "counters"
+# Where an expert layer chooses on ``score + bias``: the collection of the
+# bias (a variable no gradient moves, ``TrainState.buffers``) and the one
+# the layer sows its load into, the (token, choice) pairs given to each of
+# all its experts, under the bias's own name, so that the two trees match
+# leaf for leaf (``train/steps.py move_selection_bias``).
+BIAS_COLLECTION = "router_bias"
+LOAD_COLLECTION = "expert_load"
+# What a step adds to ``MetricState.routing``, after ROUTING_COUNTERS,
+# where its state carries a selection bias: the bias's range after the
+# step's update (max - min over a layer's experts, the largest over the
+# layers), the loss of the multi-token-prediction head (0 without one), the
+# objective whose gradients the step applied (both cross-entropies and the
+# sown term under the job's weights) and the number of steps summed.
+STEP_COUNTERS = ("bias_range", "mtp_loss", "objective", "steps")
 
 
 class MetricState(NamedTuple):
@@ -47,12 +61,12 @@ class MetricState(NamedTuple):
     routing: Optional[jnp.ndarray] = None
 
 
-def metrics_init(routing: bool = False) -> MetricState:
+def metrics_init(routing: int = 0) -> MetricState:
+    """``routing``: how many routing counters the step returns (0: none)."""
     zero = jnp.zeros((), jnp.float32)
     return MetricState(
         zero, zero, zero,
-        jnp.zeros((len(ROUTING_COUNTERS),), jnp.float32) if routing
-        else None)
+        jnp.zeros((routing,), jnp.float32) if routing else None)
 
 
 def metrics_update(

@@ -48,6 +48,8 @@ __all__ = [
     "moe_capacity_forward",
     "load_balance_loss",
     "route_topk",
+    "expert_load",
+    "sequence_balance_loss",
     "held_experts_forward",
 ]
 
@@ -209,10 +211,14 @@ def moe_capacity_forward(
 CHOICE_NAME = "expert_choice"
 
 
-def route_topk(scores: jnp.ndarray, top_k: int, scale: float):
+def route_topk(scores: jnp.ndarray, top_k: int, scale: float,
+               bias: Optional[jnp.ndarray] = None):
     """(N, E) float32 router scores -> ``(idx, weight)``, both (N, k): the
     ``k`` largest a token and their weights ``scale * s_e / sum_chosen s``,
-    normalised over all ``k`` chosen wherever their experts live.
+    normalised over all ``k`` chosen wherever their experts live. With a
+    selection ``bias`` (E,) the ``k`` chosen are the largest of ``scores +
+    bias`` and the weights still read the scores alone (arXiv:2412.19437,
+    eq. 16: the bias steers the load and never enters a weight).
 
     The weights read the scores at ``idx`` and ``idx`` carries
     ``CHOICE_NAME``: under per-block recomputation the backward pass
@@ -222,11 +228,31 @@ def route_topk(scores: jnp.ndarray, top_k: int, scale: float):
     in the backward pass than the one its forward result came from (0.3%
     of the pairs at 1,024 tokens; the routed leaves' gradients were 2-12%
     off for it). With the choice kept, both passes see one routing."""
-    _, idx = lax.top_k(scores, top_k)
+    _, idx = lax.top_k(scores if bias is None else scores + bias, top_k)
     idx = checkpoint_name(idx.astype(jnp.int32), CHOICE_NAME)
     vals = jnp.take_along_axis(scores, idx, axis=-1)
     weight = vals / jnp.sum(vals, axis=-1, keepdims=True) * scale
     return idx, weight
+
+
+def expert_load(idx: jnp.ndarray, num_experts: int) -> jnp.ndarray:
+    """(..., T, k) chosen experts -> (..., E) float32: the (token, choice)
+    pairs each of all ``num_experts`` was given, whoever holds it."""
+    hit = idx[..., None] == jnp.arange(num_experts, dtype=idx.dtype)
+    return jnp.sum(hit, axis=(-3, -2), dtype=jnp.float32)
+
+
+def sequence_balance_loss(scores: jnp.ndarray, load: jnp.ndarray,
+                          top_k: int) -> jnp.ndarray:
+    """The sequence-wise balance term of arXiv:2412.19437, eq. 17-20, without
+    its weight: ``mean over sequences of sum_e f_e P_e`` with ``f_e = E / (k
+    T) * load_e`` (``load`` (B, E): the pairs of one sequence, no gradient)
+    and ``P_e`` the mean over the sequence's ``T`` tokens of ``s_e / sum_e'
+    s_e'`` (``scores`` (B, T, E)). 1.0 under a uniform load."""
+    t, e = scores.shape[-2:]
+    f = lax.stop_gradient(load) * (e / (top_k * t))
+    p = jnp.mean(scores / jnp.sum(scores, axis=-1, keepdims=True), axis=-2)
+    return jnp.mean(jnp.sum(f * p, axis=-1))
 
 
 def _sort_pairs(idx: jnp.ndarray, first: int, count: int):

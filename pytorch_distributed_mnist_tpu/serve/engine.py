@@ -742,4 +742,4 @@ def load_params_for_serving(path: str, template_state) -> Tuple[object, int]:
     from pytorch_distributed_mnist_tpu.train.checkpoint import load_checkpoint
 
     state, next_epoch, _best = load_checkpoint(path, template_state)
-    return state.params, next_epoch - 1
+    return state.variables, next_epoch - 1

@@ -5,7 +5,8 @@ Schema parity with the reference's richest auxiliary subsystem
 
 - checkpoint dict ``{epoch: epoch+1, state_dict, best_acc, optimizer}``
   becomes ``{epoch, best_acc}`` metadata + the flattened
-  ``{params, opt_state, step}`` leaf arrays;
+  ``{params, opt_state, step}`` leaf arrays (and ``buffers``, where the
+  state carries variables that no gradient moves);
 - one file per epoch (``checkpoint_{epoch}.npz``) plus a ``model_best``
   copy on improvement (``:267-271``; every epoch's file retained, no GC,
   same as the reference);
@@ -82,8 +83,15 @@ def _leaves_with_names(tree: Any):
 
 
 def _state_tree(state) -> Dict[str, Any]:
-    return {"params": state.params, "opt_state": state.opt_state,
+    """What a checkpoint holds of a state. ``buffers`` (the state no
+    gradient moves, ``train/state.py``) is a key only where the state has
+    them, so that every other state writes the leaves, names and bytes it
+    always wrote."""
+    tree = {"params": state.params, "opt_state": state.opt_state,
             "step": state.step}
+    if getattr(state, "buffers", None) is not None:
+        tree["buffers"] = state.buffers
+    return tree
 
 
 def _world_stamp() -> Dict[str, int]:
@@ -602,10 +610,7 @@ def _restore_onto_template(path, leaf_names, arrays, state):
             restored.append(jax.device_put(arr, sharding))
         else:
             restored.append(arr)
-    tree = jax.tree_util.tree_unflatten(treedef, restored)
-    return state.replace(
-        params=tree["params"], opt_state=tree["opt_state"], step=tree["step"]
-    )
+    return state.replace(**jax.tree_util.tree_unflatten(treedef, restored))
 
 
 def load_checkpoint(path: str, state) -> Tuple[Any, int, float]:
@@ -1047,6 +1052,7 @@ class _HostState:
         self.params = tree["params"]
         self.opt_state = tree["opt_state"]
         self.step = tree["step"]
+        self.buffers = tree.get("buffers")
 
 
 def try_resume(path: str, state) -> Tuple[Any, int, float]:

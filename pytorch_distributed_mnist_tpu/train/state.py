@@ -16,7 +16,7 @@ no re-jit when the LR changes.
 
 from __future__ import annotations
 
-from typing import Any, Callable
+from typing import Any, Callable, Mapping
 
 import flax.struct
 import jax
@@ -37,6 +37,19 @@ class TrainState:
     # (``ops/metrics.py`` ROUTING_COLLECTION): the train step then asks for that
     # collection and carries its sum out in ``MetricState.routing``.
     counters: bool = flax.struct.field(pytree_node=False, default=False)
+    # Variables of the model that no gradient moves and the step updates
+    # itself (the expert layers' selection bias, ``{"router_bias": ...}``:
+    # ``train/steps.py move_selection_bias``), by flax collection, or None.
+    # Kept apart from ``params`` so that the optimizer carries no moments
+    # for them; donated, returned and checkpointed like the rest.
+    buffers: Any = None
+
+    @property
+    def variables(self):
+        """What ``apply_fn`` takes: ``params`` and, where the model has
+        them, the ``buffers`` beside."""
+        return self.params if self.buffers is None \
+            else {**self.params, **self.buffers}
 
     def apply_gradients(self, grads):
         # The scope names these ops in a profile (README, "Profiling a run").
@@ -127,6 +140,12 @@ def train_state_from_params(
     benchmark's reference check of a model that fills the chip) makes the
     two in turn."""
     tx = make_optimizer(lr, optimizer, momentum, weight_decay, mesh=mesh)
+    # ``model.init`` returns every collection: what is not ``params`` is
+    # state that no gradient moves.
+    buffers = {k: v for k, v in params.items() if k != "params"} \
+        if isinstance(params, Mapping) and "params" in params else {}
+    if buffers:
+        params = {"params": params["params"]}
     return TrainState(
         step=jnp.zeros((), jnp.int32),
         params=params,
@@ -134,4 +153,5 @@ def train_state_from_params(
         apply_fn=model.apply,
         tx=tx,
         counters=bool(getattr(model, "counters", False)),
+        buffers=buffers or None,
     )

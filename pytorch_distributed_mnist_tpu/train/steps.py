@@ -26,7 +26,11 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from pytorch_distributed_mnist_tpu.ops.loss import cross_entropy, example_weights
 from pytorch_distributed_mnist_tpu.ops.metrics import (
+    BIAS_COLLECTION,
+    LOAD_COLLECTION,
     ROUTING_COLLECTION as COUNTERS,
+    ROUTING_COUNTERS,
+    STEP_COUNTERS,
     add_routing,
     metrics_init,
     metrics_merge,
@@ -35,38 +39,53 @@ from pytorch_distributed_mnist_tpu.ops.metrics import (
 
 
 def _forward_with_aux(state, params, images, aux_weight: float):
-    """Training forward returning ``(logits, aux, counters)`` where
+    """Training forward returning ``(logits, mtp_logits, aux, counters,
+    load)``. ``logits`` is the model's array of logits, or the first of
+    the two that a model with a multi-token-prediction module returns in
+    training, ``mtp_logits`` then the second (else ``None``).
     ``aux`` is the sum of the ``aux_loss`` entries the model sowed under
-    ``intermediates`` (the MoE router's load-balance term, models/moe.py)
+    ``intermediates`` (the expert layers' balance terms, models/moe.py)
     — 0.0 when ``aux_weight`` is 0, in which case the capture is skipped
     entirely and the program is byte-identical to the plain path — and
     ``counters`` the sum of what its expert layers sowed under
     ``counters`` where the state says it has such layers
-    (``TrainState.counters``), else ``None``.
+    (``TrainState.counters``), else ``None``. ``load`` is what the expert
+    layers sowed beside their selection bias where the state carries one
+    (``TrainState.buffers``), else ``None``.
 
     Only leaves whose key is literally ``aux_loss`` enter the objective;
-    any other sown intermediate raises, so a future diagnostic sow can
+    the expert layers' sown ``choices`` are passed over and any other sown
+    intermediate raises, so a future diagnostic sow can
     never silently join the loss. The aux statistic is computed by the
     model over the full static batch — it cannot see the validity mask —
     so it assumes fully-valid train batches, which the train loader
     guarantees (``drop_last=train``, data/loader.py: the ragged tail is
     dropped, never padded; only EVAL batches pad, and eval never runs
     this path)."""
+    balanced = state.buffers is not None and BIAS_COLLECTION in state.buffers
     collections = (["intermediates"] if aux_weight else []) \
-        + ([COUNTERS] if _counts_routing(state) else [])
+        + ([COUNTERS] if _counts_routing(state) else []) \
+        + ([LOAD_COLLECTION] if balanced else [])
+    variables = state.replace(params=params).variables
     if not collections:
-        return state.apply_fn(params, images, train=True), 0.0, None
-    logits, mods = state.apply_fn(
-        params, images, train=True, mutable=collections
-    )
+        out, mods = state.apply_fn(variables, images, train=True), {}
+    else:
+        out, mods = state.apply_fn(
+            variables, images, train=True, mutable=collections)
+    logits, mtp_logits = out if isinstance(out, tuple) else (out, None)
+    if not collections:
+        return logits, mtp_logits, 0.0, None, None
     counters = None
     if COUNTERS in collections:
         counters = sum(jax.tree_util.tree_leaves(mods.get(COUNTERS, {})))
         counters = jax.lax.stop_gradient(counters)
+    load = jax.lax.stop_gradient(mods[LOAD_COLLECTION]) if balanced else None
     aux = jnp.float32(0.0) if aux_weight else 0.0
     for path, leaf in jax.tree_util.tree_leaves_with_path(
             mods.get("intermediates", {})):
         names = [getattr(k, "key", getattr(k, "name", None)) for k in path]
+        if "choices" in names:
+            continue
         if "aux_loss" not in names:
             raise ValueError(
                 f"aux_weight is set but the model sowed a non-aux_loss "
@@ -74,34 +93,87 @@ def _forward_with_aux(state, params, images, aux_weight: float):
                 f"'aux_loss' entries may join the training objective"
             )
         aux = aux + jnp.sum(leaf)
-    return logits, aux, counters
+    return logits, mtp_logits, aux, counters, load
 
 
-def _train_step(state, batch, aux_weight: float = 0.0):
+def mtp_labels(labels: jnp.ndarray) -> jnp.ndarray:
+    """The token after the next: ``labels`` (B, T) shifted by one more,
+    with ``data.tokens.IGNORE`` at the last position (the one before it is
+    ignored already: its label, the last position's, is)."""
+    return jnp.pad(labels[:, 1:], ((0, 0), (0, 1)), constant_values=-1)
+
+
+def move_selection_bias(bias, load, rate: float):
+    """``b_e += rate * sign(mean_e' n_e' - n_e)`` for every expert layer
+    (arXiv:2412.19437, section 4.2): ``bias`` the tree of (E,) biases,
+    ``load`` the like tree the layers sowed, each leaf a tuple of the (E,)
+    pair counts ``n`` of the layer's calls in the step."""
+    def one(b, sown):
+        n = sum(sown)
+        return b + rate * jnp.sign(jnp.mean(n) - n)
+
+    return jax.tree_util.tree_map(one, bias, load)
+
+
+def _balance_state(state, load, mtp_loss, objective, bias_rate: float):
+    """``(state, step counters)`` after the step's gradients were applied:
+    the selection bias moved against ``load``, and STEP_COUNTERS."""
+    with jax.named_scope("moe/bias"):
+        bias = move_selection_bias(
+            state.buffers[BIAS_COLLECTION], load, bias_rate)
+        spread = jnp.max(jnp.stack([
+            jnp.max(b) - jnp.min(b)
+            for b in jax.tree_util.tree_leaves(bias)]))
+    state = state.replace(buffers={**state.buffers, BIAS_COLLECTION: bias})
+    return state, jnp.stack([
+        spread, jnp.float32(0.0) if mtp_loss is None else mtp_loss,
+        objective, jnp.float32(1.0)])
+
+
+def _train_step(state, batch, aux_weight: float = 0.0,
+                mtp_weight: float = 0.0, bias_rate: float = 0.0):
     """One optimizer step on one (global) batch. Pure; jitted by the factory.
 
-    The objective is ``cross_entropy + aux_weight * sown_aux``; metrics
-    report the cross-entropy alone so loss curves stay comparable with
-    the reference (which has no auxiliary terms, ``:88``)."""
+    The objective is ``cross_entropy + aux_weight * sown_aux`` and, where
+    the model returns the logits of a multi-token-prediction module,
+    ``+ mtp_weight * cross_entropy(mtp_logits, the token after the
+    next)``; metrics report the next token's cross-entropy alone so loss
+    curves stay comparable with the reference (which has no auxiliary
+    terms, ``:88``). A state with a selection bias has it moved by
+    ``bias_rate`` after the gradients are applied."""
     mask = batch.get("mask")
 
     def loss_fn(params):
-        logits, aux, counters = _forward_with_aux(
+        logits, mtp_logits, aux, counters, load = _forward_with_aux(
             state, params, batch["image"], aux_weight)
         with jax.named_scope("loss"):
             ce = cross_entropy(logits, batch["label"], mask)
-        return ce + aux_weight * aux, (ce, logits, counters)
+        objective = ce + aux_weight * aux
+        mtp_ce = None
+        if mtp_logits is not None:
+            with jax.named_scope("mtp/loss"):
+                mtp_ce = cross_entropy(
+                    mtp_logits, mtp_labels(batch["label"]), mask)
+            objective = objective + mtp_weight * mtp_ce
+        return objective, (ce, logits, counters, load, mtp_ce)
 
-    (_, (loss, logits, counters)), grads = jax.value_and_grad(
-        loss_fn, has_aux=True)(state.params)
+    (objective, (loss, logits, counters, load, mtp_ce)), grads = \
+        jax.value_and_grad(loss_fn, has_aux=True)(state.params)
     new_state = state.apply_gradients(grads)
+    if load is not None:
+        new_state, stepped = _balance_state(
+            new_state, load, mtp_ce, objective, bias_rate)
+        if counters is not None:
+            counters = jnp.concatenate([counters, stepped])
     with jax.named_scope("loss"):
         metrics = metrics_update(
             metrics_init(), loss, logits, batch["label"], mask)
     return new_state, add_routing(metrics, counters)
 
 
-def make_accum_train_step_fn(accum: int, aux_weight: float = 0.0):
+def make_accum_train_step_fn(accum: int, aux_weight: float = 0.0,
+                             mtp_weight: float = 0.0,
+                             bias_rate: float = 0.0):
     """Pure ``step(state, batch)`` with ``accum``-way gradient accumulation.
 
     The batch splits into ``accum`` equal micro-batches along dim 0; a
@@ -118,11 +190,22 @@ def make_accum_train_step_fn(accum: int, aux_weight: float = 0.0):
     count — the example-weighted mean of micro-batch aux values, an
     approximation of the full-batch aux (the router's load fractions are
     per-micro-batch statistics), standard for MoE grad accumulation.
+
+    ``mtp_weight``, ``bias_rate``: see ``_train_step``, the only step that
+    has a second head or a selection bias; the accumulating one refuses a
+    state that carries ``buffers``.
     """
     if accum < 2:
-        return functools.partial(_train_step, aux_weight=aux_weight)
+        return functools.partial(
+            _train_step, aux_weight=aux_weight, mtp_weight=mtp_weight,
+            bias_rate=bias_rate)
 
     def step(state, batch):
+        if state.buffers is not None:
+            raise ValueError(
+                "--grad-accum > 1 does not carry the state that no "
+                f"gradient moves ({sorted(state.buffers)}): the selection "
+                "bias is moved once a step, from one batch's load")
         b = batch["image"].shape[0]
         if b % accum:
             raise ValueError(
@@ -141,7 +224,7 @@ def make_accum_train_step_fn(accum: int, aux_weight: float = 0.0):
                  else jnp.asarray(float(mb["label"].shape[0])))
 
             def loss_fn(params):
-                logits, aux, counters = _forward_with_aux(
+                logits, _, aux, counters, _ = _forward_with_aux(
                     state, params, mb["image"], aux_weight)
                 # per-example SUM: micro-means weighted by real count so
                 # the accumulated gradient equals the full-batch gradient
@@ -206,7 +289,8 @@ def _eval_step(state, batch):
     sharded eval reports exact whole-dataset metrics (the reference instead
     evaluates the full set redundantly on every rank, ``:143-144``)."""
     mask = batch.get("mask")
-    logits = make_forward_program(state.apply_fn)(state.params, batch["image"])
+    logits = make_forward_program(state.apply_fn)(
+        state.variables, batch["image"])
     loss = cross_entropy(logits, batch["label"], mask)
     return metrics_update(metrics_init(), loss, logits, batch["label"], mask)
 
@@ -226,7 +310,8 @@ def _shardings(mesh: Optional[Mesh], axis: str):
 
 def make_train_step(
     mesh: Optional[Mesh] = None, axis: str = "data", state_sharding=None,
-    grad_accum: int = 1, aux_weight: float = 0.0,
+    grad_accum: int = 1, aux_weight: float = 0.0, mtp_weight: float = 0.0,
+    bias_rate: float = 0.0,
 ):
     """Jitted ``step(state, batch) -> (state, MetricState)``.
 
@@ -239,7 +324,8 @@ def make_train_step(
     micro-batches before the single optimizer step
     (``make_accum_train_step_fn``).
     """
-    step_fn = make_accum_train_step_fn(grad_accum, aux_weight)
+    step_fn = make_accum_train_step_fn(
+        grad_accum, aux_weight, mtp_weight, bias_rate)
     repl, data = _shardings(mesh, axis)
     if mesh is None:
         return jax.jit(step_fn, donate_argnums=(0,))
@@ -290,10 +376,16 @@ def accumulate_metrics(acc, m):
     return metrics_merge(acc, m)
 
 
-def _counts_routing(state) -> bool:
-    """Whether a train step on ``state`` returns routing counters, which
-    a scan's metric carry then has to hold from its first step."""
-    return bool(getattr(state, "counters", False))
+def _counts_routing(state) -> int:
+    """How many routing counters a train step on ``state`` returns (0:
+    none), which a scan's metric carry then has to hold from its first
+    step: the expert layers' own and, where the state carries a selection
+    bias, the step's (``ops/metrics.py STEP_COUNTERS``)."""
+    if not getattr(state, "counters", False):
+        return 0
+    buffers = getattr(state, "buffers", None)
+    return len(ROUTING_COUNTERS) + (
+        len(STEP_COUNTERS) if buffers and BIAS_COLLECTION in buffers else 0)
 
 
 _accumulate = accumulate_metrics
@@ -359,7 +451,8 @@ def _make_epoch(mesh, axis, state_sharding, step_fn, train, indexed):
 
 def make_train_epoch(
     mesh: Optional[Mesh] = None, axis: str = "data", state_sharding=None,
-    grad_accum: int = 1, aux_weight: float = 0.0,
+    grad_accum: int = 1, aux_weight: float = 0.0, mtp_weight: float = 0.0,
+    bias_rate: float = 0.0,
 ):
     """Jitted ``epoch(state, batches) -> (state, MetricState)`` via lax.scan.
 
@@ -370,14 +463,17 @@ def make_train_epoch(
     ``state_sharding`` overrides the replicated state layout (TP tables from
     ``parallel/tensor.py``, ZeRO-1 from ``parallel/zero.py``).
     """
-    return _make_epoch(mesh, axis, state_sharding,
-                       make_accum_train_step_fn(grad_accum, aux_weight),
-                       train=True, indexed=False)
+    return _make_epoch(
+        mesh, axis, state_sharding,
+        make_accum_train_step_fn(
+            grad_accum, aux_weight, mtp_weight, bias_rate),
+        train=True, indexed=False)
 
 
 def make_train_epoch_indexed(
     mesh: Optional[Mesh] = None, axis: str = "data", state_sharding=None,
-    grad_accum: int = 1, aux_weight: float = 0.0,
+    grad_accum: int = 1, aux_weight: float = 0.0, mtp_weight: float = 0.0,
+    bias_rate: float = 0.0,
 ):
     """Jitted ``epoch(state, data, ticks) -> (state, MetricState)`` where
     the per-step batch is gathered ON DEVICE.
@@ -398,9 +494,11 @@ def make_train_epoch_indexed(
     is the documented memory/host-bandwidth saver, not the default
     (``--epoch-gather host``).
     """
-    return _make_epoch(mesh, axis, state_sharding,
-                       make_accum_train_step_fn(grad_accum, aux_weight),
-                       train=True, indexed=True)
+    return _make_epoch(
+        mesh, axis, state_sharding,
+        make_accum_train_step_fn(
+            grad_accum, aux_weight, mtp_weight, bias_rate),
+        train=True, indexed=True)
 
 
 def make_eval_epoch(

@@ -93,6 +93,8 @@ class Trainer:
         grad_accum: int = 1,
         epoch_gather: str = "host",
         aux_weight: float = 0.0,
+        mtp_weight: float = 0.0,
+        bias_rate: float = 0.0,
         feed_window: int = 2,
         staging_log=None,
         zero_overlap: bool = False,
@@ -113,6 +115,16 @@ class Trainer:
             )
         if state_sharding is not None and mesh is None:
             raise ValueError("state_sharding requires a mesh")
+        if getattr(state, "buffers", None) is not None and (
+                mode == "explicit" or zero_overlap):
+            raise ValueError(
+                "a state with buffers (a selection bias that the step "
+                "moves) trains on the propagation path only: mode "
+                "'scan' or 'stepwise', without zero_overlap")
+        # What weighs the objective's further terms and moves the bias
+        # (train/steps.py _train_step).
+        objective = dict(aux_weight=aux_weight, mtp_weight=mtp_weight,
+                         bias_rate=bias_rate)
         if zero_overlap:
             # The explicit overlapped-ZeRO data plane
             # (parallel/zero_overlap.py): pure data parallelism with the
@@ -200,14 +212,13 @@ class Trainer:
         else:
             self._train_step = make_train_step(
                 mesh, state_sharding=state_sharding, grad_accum=grad_accum,
-                aux_weight=aux_weight,
-            )
+                **objective)
             self._eval_step = make_eval_step(mesh, state_sharding=state_sharding)
         self.epoch_gather = epoch_gather
         if mode == "scan" and epoch_gather == "device":
             self._train_epoch = make_train_epoch_indexed(
                 mesh, state_sharding=state_sharding, grad_accum=grad_accum,
-                aux_weight=aux_weight)
+                **objective)
         elif mode == "scan" and zero_overlap:
             from pytorch_distributed_mnist_tpu.parallel.zero_overlap import (
                 make_overlap_train_epoch,
@@ -220,8 +231,7 @@ class Trainer:
         else:
             self._train_epoch = (
                 make_train_epoch(mesh, state_sharding=state_sharding,
-                                 grad_accum=grad_accum,
-                                 aux_weight=aux_weight)
+                                 grad_accum=grad_accum, **objective)
                 if mode == "scan" else None
             )
         # Eval always uses the one-time device staging (_eval_staged):

@@ -179,6 +179,16 @@ class RoutingLog:
     counts ride out of the pass's program in ``MetricState.routing``
     (``ops/metrics.py ROUTING_COUNTERS``) and the trainer records them
     here when it reads the pass's metrics: no host sync of their own.
+
+    Where the layers choose under a selection bias that the train step
+    moves, the step's own counters follow the layers' (``ops/metrics.py
+    STEP_COUNTERS``) and the summary gains ``bias_range`` (max - min over
+    a layer's experts after a step's update, the largest over the layers;
+    the mean over the recorded steps, and ``bias_range_last_pass`` over
+    the newest pass's), ``mtp_loss`` (the multi-token-prediction head's
+    cross-entropy, the mean over the steps; ``mtp_loss_last_pass``) and
+    ``objective`` (what the step's gradients are of: both cross-entropies
+    and the sown term under the job's weights; ``objective_last_pass``).
     """
 
     def __init__(self) -> None:
@@ -188,31 +198,42 @@ class RoutingLog:
     def reset(self) -> None:
         with self._lock:
             self._sums = None
+            self._last = None
             self._passes = 0
 
     def record(self, counters) -> None:
-        """One pass's summed counters, ``(len(ROUTING_COUNTERS),)``."""
+        """One pass's summed counters: ``(len(ROUTING_COUNTERS),)``, or
+        with the step's own after them."""
         with self._lock:
             values = [float(x) for x in counters]
             self._sums = values if self._sums is None else [
                 a + b for a, b in zip(self._sums, values)]
+            self._last = values
             self._passes += 1
 
     def summary(self) -> Dict:
         """``{}`` when nothing was recorded (a model without such layers),
-        else the sums and the two ratios the benchmark reads."""
+        else the sums and the ratios the benchmark reads."""
         from pytorch_distributed_mnist_tpu.ops.metrics import (
             ROUTING_COUNTERS,
+            STEP_COUNTERS,
         )
 
         with self._lock:
             if self._sums is None:
                 return {}
-            out = dict(zip(ROUTING_COUNTERS, self._sums))
+            names = ROUTING_COUNTERS + STEP_COUNTERS
+            out = dict(zip(names, self._sums))
             out["passes"] = self._passes
             out["local_pair_share"] = out["landed"] / max(out["routed"], 1.0)
             out["load_max_over_mean"] = (
                 out["max_over_mean"] / max(out["summands"], 1.0))
+            if "steps" in out:
+                last = dict(zip(names, self._last))
+                for key in ("bias_range", "mtp_loss", "objective"):
+                    out[key] = out[key] / max(out["steps"], 1.0)
+                    out[f"{key}_last_pass"] = (
+                        last[key] / max(last["steps"], 1.0))
             return out
 
 
@@ -226,8 +247,9 @@ def device_report() -> dict:
     ``input_backend`` (the host input path in use: ``native`` C++ or
     ``numpy``), ``pallas_lowerings`` (:class:`LoweringLog`),
     ``flash_schedules`` (:class:`ScheduleLog`),
-    ``dense_attention_slices`` (:class:`SliceLog`) and ``state_scans``
-    (:class:`ScanLog`)."""
+    ``dense_attention_slices`` (:class:`SliceLog`), ``state_scans``
+    (:class:`ScanLog`) and ``expert_routing`` (:class:`RoutingLog`: ``{}``
+    for a model without top-k expert layers)."""
     from pytorch_distributed_mnist_tpu.data import native
 
     devices = jax.devices()
@@ -238,7 +260,8 @@ def device_report() -> dict:
             "pallas_lowerings": pallas_lowerings.snapshot(),
             "flash_schedules": flash_schedules.snapshot(),
             "dense_attention_slices": dense_attention_slices.snapshot(),
-            "state_scans": scan_log.snapshot()}
+            "state_scans": scan_log.snapshot(),
+            "expert_routing": routing_log.summary()}
 
 
 class LoweringLog:

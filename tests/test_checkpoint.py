@@ -595,3 +595,115 @@ def test_quarantine_checkpoint_numbered_on_collision(tmp_path):
     assert names == ["checkpoint_3.npz.corrupt", "checkpoint_3.npz.corrupt2"]
     # quarantined names are invisible to resolution and pruning
     assert latest_checkpoint(str(tmp_path)) is None
+
+
+# -- state that no gradient moves (TrainState.buffers) ----------------------
+
+def _instella_state(seed=0):
+    model = get_model("instella", compute_dtype=jnp.float32,
+                      attention="dense")
+    return create_train_state(model, jax.random.key(seed),
+                              input_shape=(1, 32))
+
+
+def _token_batch(seed=0):
+    from pytorch_distributed_mnist_tpu.data.tokens import (
+        synthetic_token_corpus,
+    )
+
+    tokens, labels = synthetic_token_corpus(
+        2, 32, 256, seed=seed, median_len=8, min_len=4)
+    return {"image": jnp.asarray(tokens), "label": jnp.asarray(labels)}
+
+
+def _leaves_equal(a, b):
+    la, lb = jax.tree.leaves(a), jax.tree.leaves(b)
+    assert len(la) == len(lb)
+    for x, y in zip(la, lb):
+        np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+
+
+@pytest.mark.parametrize("layout", ["npz", "async", "sharded"])
+def test_buffers_round_trip_bit_for_bit(tmp_path, layout, mesh8):
+    """A checkpoint of a state with a selection bias carries the bias: the
+    three layouts restore it, the parameters and the moments bit for bit
+    onto a template that was initialised otherwise."""
+    from pytorch_distributed_mnist_tpu.train.checkpoint import (
+        AsyncCheckpointer,
+    )
+
+    state = _instella_state()
+    step = make_train_step(aux_weight=1e-4, mtp_weight=0.3, bias_rate=1e-3)
+    for _ in range(2):
+        state, _ = step(state, _token_batch())
+    assert float(max(jnp.max(jnp.abs(b))
+                     for b in jax.tree.leaves(state.buffers))) > 0
+    kwargs = dict(epoch=1, best_acc=0.25, is_best=False,
+                  directory=str(tmp_path), process_index=0)
+    if layout == "async":
+        with AsyncCheckpointer() as saver:
+            saver.save(state, **kwargs)
+        path = str(tmp_path / "checkpoint_1.npz")
+    elif layout == "sharded":
+        state = jax.device_put(state, replicated_sharding(mesh8))
+        path = save_checkpoint(state, **kwargs, layout="sharded")
+        assert os.path.isdir(path)
+    else:
+        path = save_checkpoint(state, **kwargs)
+    restored, start_epoch, best_acc = load_checkpoint(
+        path, _instella_state(seed=1))
+    assert (start_epoch, best_acc, int(restored.step)) == (2, 0.25, 2)
+    _leaves_equal(state.buffers, restored.buffers)
+    _leaves_equal(state.params, restored.params)
+    _leaves_equal(state.opt_state, restored.opt_state)
+
+
+def test_a_resumed_step_equals_the_uninterrupted_one(tmp_path):
+    """Save after two steps, restore onto a fresh template, take the third:
+    parameters, moments and bias are the uninterrupted run's, bit for bit
+    (the bias chose the third step's experts)."""
+    step = make_train_step(aux_weight=1e-4, mtp_weight=0.3, bias_rate=1e-3)
+    state = _instella_state()
+    for i in range(2):
+        state, _ = step(state, _token_batch(i))
+    path = save_checkpoint(state, epoch=0, best_acc=0.0, is_best=False,
+                           directory=str(tmp_path), process_index=0)
+    straight, straight_metrics = step(state, _token_batch(2))
+    restored, _, _ = load_checkpoint(path, _instella_state(seed=7))
+    resumed, resumed_metrics = step(restored, _token_batch(2))
+    _leaves_equal(straight.buffers, resumed.buffers)
+    _leaves_equal(straight.params, resumed.params)
+    _leaves_equal(straight.opt_state, resumed.opt_state)
+    np.testing.assert_array_equal(straight_metrics.routing,
+                                  resumed_metrics.routing)
+
+
+def test_a_state_without_buffers_writes_what_it_always_wrote(tmp_path):
+    """``buffers`` is a key of the checkpoint's tree only where the state
+    has them: a state without writes the leaves, names and order it wrote
+    before the field existed, and a checkpoint of one kind does not load
+    onto the other."""
+    import json
+
+    from pytorch_distributed_mnist_tpu.train.checkpoint import (
+        _leaves_with_names,
+        _state_tree,
+    )
+
+    state = fresh_state()
+    assert state.buffers is None
+    assert set(_state_tree(state)) == {"params", "opt_state", "step"}
+    path = save_checkpoint(state, epoch=0, best_acc=0.0, is_best=False,
+                           directory=str(tmp_path), process_index=0)
+    with np.load(path) as z:
+        meta = json.loads(bytes(z["__meta__"]).decode())
+        assert len(z.files) == len(meta["leaf_names"]) + 1
+    assert meta["leaf_names"] == [name for name, _ in _leaves_with_names(
+        {"params": state.params, "opt_state": state.opt_state,
+         "step": state.step})]
+    assert not [n for n in meta["leaf_names"] if "buffers" in n]
+    with_bias = _instella_state()
+    names = [n for n, _ in _leaves_with_names(_state_tree(with_bias))]
+    assert len([n for n in names if n.startswith("['buffers']")]) == 3
+    with pytest.raises(ValueError, match="leaves"):
+        load_checkpoint(path, with_bias)
