@@ -51,7 +51,12 @@ from pytorch_distributed_mnist_tpu.ops.attention import (
     CORE_SCOPE,
     full_attention,
 )
+from pytorch_distributed_mnist_tpu.ops.pallas.rope import (
+    rotate_heads,
+    whole_heads,
+)
 from pytorch_distributed_mnist_tpu.parallel.moe_dispatch import CHOICE_NAME
+from pytorch_distributed_mnist_tpu.utils.profiling import rotary_sites
 
 FULL, WINDOW = "full_attention", "sliding_attention"
 DENSE, SPARSE = "dense", "sparse"
@@ -100,11 +105,41 @@ def rope_frequencies(head_dim: int, params: dict) -> Tuple[np.ndarray, float]:
     return blended, float(params.get("attention_factor", 1.0))
 
 
+def rope_tables(t: int, head_dim: int, inv_freq: np.ndarray, factor: float):
+    """``(C, S)``, float32 ``(t, head_dim)``, with which the half-split
+    rotary of a whole head is ``x * C + swap(x) * S`` (``swap`` exchanges
+    lanes ``i`` and ``i + rot/2``): ``C`` is ``factor * cos`` on the ``rot``
+    leading lanes and 1 behind them, ``S`` is ``-factor * sin`` on lanes
+    ``[0, rot/2)``, ``+factor * sin`` on ``[rot/2, rot)`` and 0 behind."""
+    rot = 2 * inv_freq.shape[0]
+    angles = jnp.arange(t, dtype=jnp.float32)[:, None] \
+        * jnp.asarray(inv_freq, jnp.float32)[None, :]  # (t, rot/2)
+    cos, sin = jnp.cos(angles) * factor, jnp.sin(angles) * factor
+    rest = (t, head_dim - rot)
+    return (jnp.concatenate([cos, cos, jnp.ones(rest, jnp.float32)], axis=-1),
+            jnp.concatenate([-sin, sin, jnp.zeros(rest, jnp.float32)],
+                            axis=-1))
+
+
 def apply_rope(x: jnp.ndarray, inv_freq: np.ndarray, factor: float):
     """Rotate the leading ``2 * len(inv_freq)`` dimensions of every head of
     ``x`` (B, T, H, D) by position (0 .. T-1), pairing dimension ``i`` with
-    ``i + rot/2`` (the half-split convention); float32 inside."""
+    ``i + rot/2`` (the half-split convention); float32 inside.
+
+    One path a shape, chosen by the shape. Where a head is whole registers
+    of 128 lanes (``D % 128 == 0``, the benchmark's widths) the head is
+    never sliced: ``x * C + swap(x) * S`` over :func:`rope_tables`, one
+    kernel that reads and writes ``x`` once (``ops/pallas/rope.py``), whose
+    backward is the same kernel at the negated angle and not autodiff's
+    transpose of slices and a concatenate, which cost the compiled step
+    layout copies of every half. Any other head size (the tiny preset's
+    16) is sliced, as before that kernel. The same products in the same
+    order either way. ``utils.profiling.rotary_sites`` counts the calls."""
     rot = 2 * inv_freq.shape[0]
+    if whole_heads(x.shape[-1]):
+        c, s = rope_tables(x.shape[1], x.shape[-1], inv_freq, factor)
+        return rotate_heads(x, c, s, rot)
+    rotary_sites.record(rot, whole_head=False)
     angles = jnp.arange(x.shape[1], dtype=jnp.float32)[:, None] \
         * jnp.asarray(inv_freq, jnp.float32)[None, :]  # (T, rot/2)
     cos = (jnp.cos(angles) * factor)[None, :, None, :]

@@ -247,9 +247,10 @@ def device_report() -> dict:
     ``input_backend`` (the host input path in use: ``native`` C++ or
     ``numpy``), ``pallas_lowerings`` (:class:`LoweringLog`),
     ``flash_schedules`` (:class:`ScheduleLog`),
-    ``dense_attention_slices`` (:class:`SliceLog`), ``state_scans``
-    (:class:`ScanLog`) and ``expert_routing`` (:class:`RoutingLog`: ``{}``
-    for a model without top-k expert layers)."""
+    ``dense_attention_slices`` (:class:`SliceLog`), ``rotary_sites``
+    (:class:`RotaryLog`), ``state_scans`` (:class:`ScanLog`) and
+    ``expert_routing`` (:class:`RoutingLog`: ``{}`` for a model without
+    top-k expert layers)."""
     from pytorch_distributed_mnist_tpu.data import native
 
     devices = jax.devices()
@@ -260,6 +261,7 @@ def device_report() -> dict:
             "pallas_lowerings": pallas_lowerings.snapshot(),
             "flash_schedules": flash_schedules.snapshot(),
             "dense_attention_slices": dense_attention_slices.snapshot(),
+            "rotary_sites": rotary_sites.snapshot(),
             "state_scans": scan_log.snapshot(),
             "expert_routing": routing_log.summary()}
 
@@ -363,6 +365,35 @@ class SliceLog:
 
 
 dense_attention_slices = SliceLog()
+
+
+class RotaryLog:
+    """The half-split rotary calls traced in this process
+    (``models/decoder.py apply_rope``): how many there were, how many of
+    them work on whole heads of 128 lanes (``ops/pallas/rope.py``, which
+    records a forward and a backward each; a call on slices of a head
+    records its forward, its backward being autodiff's), and the numbers of
+    rotated lanes a head (``rot``) seen."""
+
+    def __init__(self) -> None:
+        self._lock = threading.Lock()
+        self._sites = self._whole_head_sites = 0
+        self._rotated_lanes = set()
+
+    def record(self, rot: int, *, whole_head: bool) -> None:
+        with self._lock:
+            self._sites += 1
+            self._whole_head_sites += whole_head
+            self._rotated_lanes.add(rot)
+
+    def snapshot(self) -> Dict:
+        with self._lock:
+            return {"sites": self._sites,
+                    "whole_head_sites": self._whole_head_sites,
+                    "rotated_lanes": sorted(self._rotated_lanes)}
+
+
+rotary_sites = RotaryLog()
 
 
 class ScanLog:

@@ -241,6 +241,8 @@ def test_metrics_file(tmp_path):
         "backward_sites", "fused_backward_sites"}
     assert set(summary["dense_attention_slices"]) == {
         "sites", "sliced_sites", "slices_per_sliced_site"}
+    assert set(summary["rotary_sites"]) == {
+        "sites", "whole_head_sites", "rotated_lanes"}
     assert "train_epoch" in summary["compile_stats"]["programs"]
     assert "history" not in summary  # the epoch rows above already say it
     for key in ("platform", "device_kind", "device_count"):

@@ -109,6 +109,7 @@ def test_predict_healthz_stats(server):
     assert set(health["pallas_lowerings"]) == {"mosaic", "interpret"}
     assert "folded_sites" in health["flash_schedules"]
     assert "sliced_sites" in health["dense_attention_slices"]
+    assert "whole_head_sites" in health["rotary_sites"]
 
     reply = srv.post("/predict", {"images": images.tolist()})
     assert len(reply["predictions"]) == 5

@@ -188,3 +188,95 @@ def test_no_op_of_xlas_carries_the_flash_backwards_scope(one_chip,
     under = re.findall(
         r"= \S+ ([\w-]+)\([^\n]*op_name=\"[^\"]*flash_bwd", text)
     assert under and set(under) <= {"custom-call", "get-tuple-element"}
+
+
+@pytest.mark.parametrize("heads, kind", [(64, "sliding_attention"),
+                                         (48, "full_attention")])
+def test_the_rotary_is_one_kernel_on_whole_heads_in_the_compiled_layer(
+        one_chip, monkeypatch, heads, kind):
+    """In this file because it is the one that describes the chip.
+    ``GatedAttention`` at the training cell's ``(2, 8192)``, 64 heads of
+    128 with all 128 lanes rotated and 48 with 64, forward and gradient
+    under ``remat``. Sliced into halves of a head the rotary cost the step
+    an array of its own a half (``copy f32[2,8192,64,64]``,
+    ``bf16[2,8192,48,32]``); on whole heads nothing under the ``rope``
+    scope and no copy anywhere is such a half, the kernel is alone under
+    its name, four calls (q and k, forward and backward: XLA shares the
+    recomputed forward with the first here), and q goes from its
+    projection through the kernel into ``flash_fwd`` with no copy. The one
+    copy the scope keeps is k's: k is a slice of the ``kv`` projection's
+    result, and a custom call takes no slice."""
+    import json
+    import math
+    import os
+    import re
+
+    import flax.linen as nn
+
+    from pytorch_distributed_mnist_tpu.models import decoder
+    from pytorch_distributed_mnist_tpu.ops.pallas import flash, rope
+
+    monkeypatch.setattr(flash, "should_interpret", lambda: False)
+    monkeypatch.setattr(rope, "should_interpret", lambda: False)
+    with open(os.path.join(os.path.dirname(__file__), "..", "benchmark",
+                           "configs", "laguna-xs2-ep8.json")) as f:
+        params = json.load(f)["rope_parameters"][kind]
+    b, t, c, kv, d = 2, 8192, 2048, 8, 128
+    layer = nn.remat(decoder.GatedAttention)(
+        num_heads=heads, num_kv_heads=kv, head_dim=d,
+        window=512 if kind == "sliding_attention" else None,
+        rope=decoder._frozen(params), depth=5, attention="flash")
+
+    def on_chip(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    u = jax.ShapeDtypeStruct((b, t, c), jnp.bfloat16)
+    weights = jax.eval_shape(layer.init, jax.random.key(0), u)
+
+    def loss(p, u):
+        return jnp.sum(layer.apply(p, u).astype(jnp.float32))
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        on_chip(weights), on_chip(u)).compile().as_text()
+    ops = []  # (result's dimensions, op, scope) of every instruction
+    for line in text.splitlines():
+        head = re.match(r"\s*(?:ROOT )?%\S+ = \w+\[([\d,]*)\]\S* ([\w-]+)\(",
+                        line)
+        if head:
+            scope = re.search(r'op_name="([^"]*)"', line)
+            ops.append((*head.groups(), scope.group(1) if scope else ""))
+
+    def last(dims):
+        return int(dims.split(",")[-1]) if dims else 1
+
+    def size(dims):
+        return math.prod(int(n) for n in dims.split(",")) if dims else 1
+
+    under_rope = [(op, dims) for dims, op, scope in ops
+                  if "/rope/" in scope]
+    assert under_rope
+    half_of_k = b * t * kv * 32  # the smallest half there was; a table
+    # is (8192, 64) and the gate (2, 8192, heads): an eighth of it
+
+    def halves(found):
+        return [dims for dims in found
+                if last(dims) in (32, 64) and size(dims) >= half_of_k]
+
+    assert not halves(dims for _, dims in under_rope)
+    assert not halves(dims for dims, op, _ in ops if op == "copy")
+    copied = sum(size(dims) for op, dims in under_rope if op == "copy")
+    assert copied <= b * t * kv * d  # k's slice, no more
+    named = [op for _, op, scope in ops if "rope_whole_head" in scope]
+    assert named == ["custom-call"] * 4
+    # q's forward call reads a projection's matmul and feeds the core
+    calls = re.findall(
+        r"%(rope_whole_head\S*) = bf16\[2,8192,(\d+)\]\S* custom-call\("
+        r"%(\S+?),", text)
+    fed = {operand for name, width, operand in calls
+           if int(width) == heads * d}
+    assert any(re.search(rf"%{re.escape(o)} = \S+ fusion\(", text)
+               for o in fed)
+    flash_fwd = re.search(r"custom-call\(([^)]*)\)[^\n]*flash_fwd", text)
+    assert {name for name, width, _ in calls if int(width) == heads * d} \
+        & set(re.findall(r"%([\w.-]+)", flash_fwd.group(1)))

@@ -274,3 +274,58 @@ def test_selective_scan_on_tpu_matches_the_loop(b, t, c, n):
         for f in (selective_scan, loop))
     for name, g, w in zip("a dt A B C D".split(), grads, want):
         assert rel(g, w) < 1e-3, name
+
+
+# The rotary's kernel (ops/pallas/rope.py) at the training cell's two kinds
+# of layer, queries and keys: 64 heads with all 128 lanes rotated (one
+# rotation of the lanes) and 48 with 64 (two rotations and a select), 8
+# key-value heads of each, bf16 at (2, 8192). The oracle is the body
+# ``apply_rope`` had before, slices of a head and a concatenate
+# (``tests/test_rope.py sliced``), and its ``jax.grad``: the same float32
+# products in the same order, so at most one bfloat16 rounding apart.
+# (heads, kind of layer).
+ROPE_CASES = [(64, "sliding_attention"), (8, "sliding_attention"),
+              (48, "full_attention"), (8, "full_attention")]
+
+
+@pytest.mark.parametrize("heads,kind", ROPE_CASES)
+def test_rotary_on_whole_heads_on_tpu(heads, kind):
+    import json
+
+    from pytorch_distributed_mnist_tpu.models import decoder
+    from pytorch_distributed_mnist_tpu.utils.profiling import (
+        pallas_lowerings,
+        rotary_sites,
+    )
+    from tests.test_rope import sliced
+
+    with open("benchmark/configs/laguna-xs2-ep8.json") as f:
+        params = json.load(f)["rope_parameters"][kind]
+    inv_freq, factor = decoder.rope_frequencies(128, params)
+    rot = 2 * len(inv_freq)
+
+    ks = jax.random.split(jax.random.key(4), 2)
+    x = jax.random.normal(ks[0], (2, 8192, heads, 128), jnp.bfloat16)
+    weight = jax.random.normal(ks[1], x.shape, jnp.bfloat16)
+    before = pallas_lowerings.snapshot(), rotary_sites.snapshot()
+
+    def both(f):
+        return jax.jit(jax.value_and_grad(lambda x: jnp.sum(
+            (f(x) * weight).astype(jnp.float32)), has_aux=False))(x)[1], \
+            jax.jit(f)(x)
+
+    (g, y), (g_ref, y_ref) = (both(f) for f in (
+        lambda x: decoder.apply_rope(x, inv_freq, factor),
+        lambda x: sliced(x, inv_freq, factor)))
+    after = pallas_lowerings.snapshot(), rotary_sites.snapshot()
+    assert after[0]["interpret"] == before[0]["interpret"]
+    assert after[0]["mosaic"] > before[0]["mosaic"]
+    assert after[1]["whole_head_sites"] - before[1]["whole_head_sites"] \
+        == after[1]["sites"] - before[1]["sites"] == 3  # y; y and g
+    for name, got, want in (("values", y, y_ref), ("gradient", g, g_ref)):
+        assert got.dtype == jnp.bfloat16, name
+        got, want = (a.astype(jnp.float32) for a in (got, want))
+        assert bool(jnp.all(jnp.abs(got - want)
+                            <= 2.0 ** -7 * jnp.abs(want))), name
+    assert bool(jnp.all(y[..., rot:] == x[..., rot:]))
+    assert bool(jnp.all(g[..., rot:] == weight[..., rot:]))
