@@ -1963,12 +1963,25 @@ def _run_body(args) -> dict:
              f"consumer blocked {staging['consumer_wait_ms']:.0f} ms, "
              f"overlap {staging['overlap_fraction']:.0%}")
     compile_stats = compile_log.stats()
+    startup = {s["name"]: s["end_unix"] - s["start_unix"]
+               for s in compile_stats["spans"]
+               if s["name"].startswith("startup")}
+    if startup:
+        # What a warm restart that says "cache hit" still waits for
+        # before any program: interpreter, imports, chip attach, then
+        # the model, the state and the loaders.
+        attach = startup.get("startup:imports_attach")
+        log0(f"startup: {startup['startup']:.1f} s to the first program"
+             + ("" if attach is None
+                else f" (imports and attach {attach:.1f})"))
     for prog, rec in compile_stats["programs"].items():
         hit = rec["persistent_cache_hit"]
         cache = ("cache off" if hit is None
                  else "cache hit" if hit else "cache miss")
         log0(f"compile[{prog}]: {rec['wall_ms']:.0f} ms "
-             f"({rec['backend_compiles']} XLA compile(s), {cache})")
+             f"(trace {rec['trace_ms']:.0f}, lower {rec['lower_ms']:.0f}, "
+             f"load {rec['cache_load_ms']:.0f}; "
+             f"{rec['backend_compiles']} XLA compile(s), {cache})")
     events = failure_events.snapshot()
     for ev in events:
         # Retries/quarantines the run survived still belong in the log —

@@ -35,6 +35,8 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from pytorch_distributed_mnist_tpu.utils.profiling import compile_log
+
 # The two tiers of a hierarchical mesh, leading (data-major) — together
 # they ARE the data axis; model axes follow.
 HIER_DATA_AXES: Tuple[str, str] = ("dcn", "ici")
@@ -61,6 +63,9 @@ def make_mesh(
             raise ValueError("shape is required for multi-axis meshes")
     if int(np.prod(shape)) != devs.size:
         raise ValueError(f"mesh shape {shape} != device count {devs.size}")
+    # Every entry point passes here (or make_hier_mesh) once the devices
+    # answer: where set-up's ``startup`` span is split.
+    compile_log.backend_ready()
     return Mesh(devs.reshape(shape), axes)
 
 
@@ -187,6 +192,7 @@ def make_hier_mesh(
     shape = (dcn_slices, per_slice // model) + tuple(extra_shape)
     grid = np.empty(len(ordered), dtype=object)
     grid[:] = ordered
+    compile_log.backend_ready()
     return Mesh(grid.reshape(shape), HIER_DATA_AXES + tuple(extra_axes))
 
 

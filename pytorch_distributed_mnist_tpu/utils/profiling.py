@@ -11,6 +11,7 @@ from __future__ import annotations
 
 import collections
 import contextlib
+import functools
 import json
 import os
 import threading
@@ -18,6 +19,8 @@ import time
 from typing import Callable, Dict, Optional
 
 import jax
+
+_IMPORTED_AT = time.time()
 
 
 class StepTimer:
@@ -76,10 +79,6 @@ class StepTimer:
     @property
     def last_images_per_sec_per_chip(self) -> float:
         return self.last_images_per_sec / self.num_chips
-
-    @property
-    def steps_per_sec(self) -> float:
-        return self.steps / max(self.elapsed, 1e-9)
 
 
 class StagingLog:
@@ -441,37 +440,112 @@ class ScanLog:
 scan_log = ScanLog()
 
 
+@functools.lru_cache(maxsize=None)
+def process_started_at() -> float:
+    """Unix time at which this process started, from ``/proc`` (its start
+    and the machine's uptime are both counted from boot, to 10 ms), as
+    ``benchmark/run.py process_started_at`` reckons the origin of
+    ``setup_s``; this module's import where ``/proc`` cannot say. Reckoned
+    once: every caller gets the same instant."""
+    try:
+        with open("/proc/self/stat") as f:
+            ticks = int(f.read().rsplit(")", 1)[1].split()[19])
+        with open("/proc/uptime") as f:
+            uptime = float(f.read().split()[0])
+        age = uptime - ticks / os.sysconf("SC_CLK_TCK")
+        if 0 <= age < 24 * 3600:
+            return time.time() - age
+    except (OSError, ValueError, IndexError):
+        pass
+    return _IMPORTED_AT
+
+
+# The jax.monitoring durations CompileLog keeps. Tracing and lowering fire
+# for nested calls too (a jitted function called inside another's trace
+# reports first, inside the outer one's interval): kept as intervals.
+_TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+_LOWER_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+_BACKEND_EVENT = "/jax/core/compile/backend_compile_duration"
+_CACHE_LOAD_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
+# A nesting kind's number is its place in _COMPILE_MS_KEYS, in a thread's
+# pair of interval lists and in a function's row.
+_NESTING_EVENTS = {_TRACE_EVENT: 0, _LOWER_EVENT: 1}
+_COMPILE_MS_KEYS = ("trace_ms", "lower_ms", "cache_load_ms",
+                    "backend_compile_ms")
+
+
+def _zero_counters() -> Dict:
+    """What the totals and every program's record count."""
+    return {"cache_hits": 0, "cache_misses": 0, "backend_compiles": 0,
+            **dict.fromkeys(_COMPILE_MS_KEYS, 0.0)}
+
+
 class CompileLog:
-    """Per-program compile observability: wall ms, XLA backend compiles,
-    and persistent-cache hit/miss, attributed to named programs.
+    """Per-program compile observability: wall ms, seconds of tracing,
+    lowering, backend compile and cache load, persistent-cache hit/miss,
+    attributed to named programs, and the timeline of the measures
+    themselves.
 
     jax reports compile activity through ``jax.monitoring`` events —
     ``/jax/compilation_cache/cache_hits`` / ``cache_misses`` fire per XLA
     compile request when the persistent cache is enabled, and the
     backend-compile duration event fires for every compile (a
-    persistent-cache *hit* still reports a few ms there: that is the
-    executable deserialization, not a compile). Listeners run on the
-    thread doing the compiling, so attribution is thread-local: whatever
-    program name the current thread has open via ``measure(name)`` owns
-    the events — concurrent background precompiles (train/trainer.py)
-    can't misfile each other's counts.
+    persistent-cache *hit* still reports there: that is the executable's
+    retrieval and deserialization, which ``cache_load_ms`` gives apart, not
+    a compile). Listeners run on the thread doing the compiling, so
+    attribution is thread-local: whatever program name the current thread
+    has open via ``measure(name)`` owns the events — concurrent background
+    precompiles (train/trainer.py) can't misfile each other's counts.
+
+    Tracing (Python to a jaxpr) and lowering (jaxpr to MLIR) report a
+    duration at their end, so each is the interval ``[now - secs, now]``
+    on the listening thread, and **traces nest**: a jitted function called
+    inside another's trace (every ``jnp`` primitive is one) fires first,
+    inside the outer one's interval. ``trace_ms`` and ``lower_ms`` are
+    therefore the length of the *union* of the intervals, folded as they
+    come (an arriving parent swallows the children inside it), never the
+    sum of the events, which reads several times the wall. A program's
+    ``functions`` are the eight names with the most *self* seconds (an
+    interval less the children inside it; lowering's ``jit(f)`` is filed
+    under ``f``): which function's tracing grew. The kinds are folded
+    apart, so a small program that an eager call traces, lowers and
+    compiles *while* an outer trace runs counts in each: their sum can pass
+    the wall by that much.
+
+    ``stats()["spans"]`` is every opening of ``measure`` since the last
+    ``reset()`` with its start, end and the measure it was opened inside
+    on its thread, in order of opening (the first :attr:`MAX_SPANS`),
+    led by ``startup``: from the process's start to the first measure
+    opened after the reset, with the part before :meth:`backend_ready`
+    as ``startup:imports_attach`` and the rest as ``startup:build``.
+    Inside a ``jax.profiler`` capture each measure is also the host span
+    ``compile:<program>`` (:func:`phase`).
 
     ``cache_misses`` is the honest "programs actually compiled" counter:
     the acceptance bar for a warm start is zero misses, not zero
     backend-duration events.
     """
 
+    MAX_SPANS = 256
+    # Finished intervals a thread keeps for a parent that may still arrive;
+    # beyond it the older half becomes one block (exact unless a parent
+    # starts inside the block, which it then cannot split).
+    MAX_OPEN_INTERVALS = 1024
+    MAX_FUNCTIONS = 512  # names a program keeps; the rest are "(other)"
+    TOP_FUNCTIONS = 8
+
     def __init__(self) -> None:
         self._lock = threading.Lock()
         self._tls = threading.local()
         self._listening = False
+        self._backend_ready_unix: Optional[float] = None
         self.reset()
 
     def reset(self) -> None:
         with self._lock:
             self._programs: Dict[str, Dict] = {}
-            self._totals = {"cache_hits": 0, "cache_misses": 0,
-                            "backend_compiles": 0, "backend_compile_ms": 0.0}
+            self._totals = _zero_counters()
+            self._spans: list = []
 
     # -- jax.monitoring plumbing ------------------------------------------
 
@@ -504,8 +578,9 @@ class CompileLog:
             monitoring.unregister_event_duration_listener(self._on_duration)
             self._listening = False
 
-    def _current(self) -> Optional[Dict]:
-        return getattr(self._tls, "record", None)
+    def _open_measure(self) -> tuple:
+        """``(record, name)`` of the measure open on this thread."""
+        return getattr(self._tls, "measure", (None, None))
 
     def _on_event(self, name: str, **kwargs) -> None:
         if name == "/jax/compilation_cache/cache_hits":
@@ -514,69 +589,163 @@ class CompileLog:
             key = "cache_misses"
         else:
             return
-        rec = self._current()
+        rec = self._open_measure()[0]
         with self._lock:
             self._totals[key] += 1
             if rec is not None:
                 rec[key] += 1
 
+    def _self_seconds(self, kind: int, secs: float) -> float:
+        """Fold the interval ``[now - secs, now]`` into this thread's
+        finished intervals of ``kind`` and return what it adds to their
+        union: its length less the children inside it. Events come in
+        order of their ends, so the children are the newest entries."""
+        try:
+            done = self._tls.done[kind]
+        except AttributeError:
+            self._tls.done = ([], [])
+            done = self._tls.done[kind]
+        end = time.time()
+        start = end - secs
+        inside = 0.0
+        while done and done[-1][0] >= start:
+            inside += done.pop()[2]
+        if done and done[-1][1] > start:
+            # A child that began within the clock's jitter of its parent,
+            # or a block: counted already, so the parent starts after it.
+            start = done[-1][1]
+        length = end - start
+        done.append((start, end, length))
+        if len(done) > self.MAX_OPEN_INTERVALS:
+            half = len(done) // 2
+            done[:half] = [(done[0][0], done[half - 1][1],
+                            sum(d[2] for d in done[:half]))]
+        return max(length - inside, 0.0)
+
     def _on_duration(self, name: str, secs: float, **kwargs) -> None:
-        if name != "/jax/core/compile/backend_compile_duration":
+        kind = _NESTING_EVENTS.get(name)
+        if kind is not None:
+            key = _COMPILE_MS_KEYS[kind]
+            ms = self._self_seconds(kind, secs) * 1e3
+            compiles = 0
+        elif name == _BACKEND_EVENT:
+            key, ms, compiles = "backend_compile_ms", secs * 1e3, 1
+        elif name == _CACHE_LOAD_EVENT:
+            key, ms, compiles = "cache_load_ms", secs * 1e3, 0
+        else:
             return
-        rec = self._current()
+        rec = self._open_measure()[0]
         with self._lock:
-            self._totals["backend_compiles"] += 1
-            self._totals["backend_compile_ms"] += secs * 1e3
-            if rec is not None:
-                rec["backend_compiles"] += 1
-                rec["backend_compile_ms"] += secs * 1e3
+            self._totals[key] += ms
+            self._totals["backend_compiles"] += compiles
+            if rec is None:
+                return
+            rec[key] += ms
+            rec["backend_compiles"] += compiles
+            if kind is not None:
+                functions = rec["functions"]
+                fun = kwargs.get("fun_name", "?")
+                if kind and fun.startswith("jit(") and fun.endswith(")"):
+                    fun = fun[4:-1]  # lowering names f's module "jit(f)"
+                row = functions.get(fun)
+                if row is None:
+                    if len(functions) >= self.MAX_FUNCTIONS:
+                        fun = "(other)"
+                    row = functions.setdefault(fun, [0.0, 0.0, 0])
+                row[kind] += ms
+                row[2] += kind == 0
 
     # -- public API --------------------------------------------------------
+
+    def backend_ready(self) -> None:
+        """Stamp, once a process, the instant the backend was up: called
+        where a mesh is made (``parallel/mesh.py``), which ``cli.run`` and
+        the benchmark's runners both pass after the chip is attached. It
+        splits ``startup``; ``reset()`` keeps it, as it keeps the
+        process's start."""
+        if self._backend_ready_unix is None:
+            self._backend_ready_unix = time.time()
+
+    def _startup_spans(self, end: float) -> list:
+        start = process_started_at()
+        spans = [{"name": "startup", "start_unix": start, "end_unix": end,
+                  "parent": None}]
+        ready = self._backend_ready_unix
+        if ready is not None and start <= ready <= end:
+            spans += [
+                {"name": "startup:imports_attach", "start_unix": start,
+                 "end_unix": ready, "parent": "startup"},
+                {"name": "startup:build", "start_unix": ready,
+                 "end_unix": end, "parent": "startup"}]
+        return spans
 
     @contextlib.contextmanager
     def measure(self, program: str):
         """Attribute this thread's compile activity to ``program`` while
         the block runs; the record accumulates across repeat measures of
-        the same name (e.g. precompile then first call)."""
+        the same name (e.g. precompile then first call), and each opening
+        is one span."""
         self._ensure_listening()
+        outer = self._open_measure()
+        opened = time.time()
+        span = {"name": program, "start_unix": opened, "end_unix": None,
+                "parent": outer[1]}
         with self._lock:
-            rec = self._programs.setdefault(program, {
-                "wall_ms": 0.0, "backend_compiles": 0,
-                "backend_compile_ms": 0.0, "cache_hits": 0,
-                "cache_misses": 0,
-            })
-        prev = self._current()
-        self._tls.record = rec
+            rec = self._programs.get(program)
+            if rec is None:
+                rec = self._programs[program] = {
+                    "wall_ms": 0.0, **_zero_counters(), "functions": {}}
+            if not self._spans:
+                self._spans = self._startup_spans(opened)
+            if len(self._spans) < self.MAX_SPANS:
+                self._spans.append(span)
+        self._tls.measure = (rec, program)
         t0 = time.perf_counter()
         try:
-            yield rec
+            with phase(f"compile:{program}"):
+                yield rec
         finally:
             dt = (time.perf_counter() - t0) * 1e3
-            self._tls.record = prev
+            self._tls.measure = outer
             with self._lock:
                 rec["wall_ms"] += dt
+                span["end_unix"] = time.time()
 
     def stats(self) -> Dict:
-        """``{"programs": {name: record}, "totals": {...}}`` snapshot.
+        """``{"programs": {name: record}, "totals": {...}, "spans": [...]}``
+        snapshot.
 
         Each program record carries ``persistent_cache_hit``: True when
         every XLA compile request inside its measures was served from the
         persistent cache, False when any real compile happened, None when
-        the persistent cache was disabled (no hit/miss events at all)."""
+        the persistent cache was disabled (no hit/miss events at all);
+        ``trace_ms`` and ``lower_ms`` (unions), ``cache_load_ms`` (a sum,
+        inside ``backend_compile_ms``) and ``functions``, the
+        :attr:`TOP_FUNCTIONS` names with the most self time:
+        ``[{"name", "trace_ms", "lower_ms", "calls"}]``, ``calls`` being
+        the trace events heard. A span still open has ``end_unix`` None."""
         with self._lock:
             programs = {}
             for name, rec in self._programs.items():
                 rec = dict(rec)
-                rec["wall_ms"] = round(rec["wall_ms"], 1)
-                rec["backend_compile_ms"] = round(rec["backend_compile_ms"], 1)
+                for key in ("wall_ms",) + _COMPILE_MS_KEYS:
+                    rec[key] = round(rec[key], 1)
                 if rec["cache_hits"] or rec["cache_misses"]:
                     rec["persistent_cache_hit"] = rec["cache_misses"] == 0
                 else:
                     rec["persistent_cache_hit"] = None
+                largest = sorted(rec["functions"].items(),
+                                 key=lambda kv: -(kv[1][0] + kv[1][1]))
+                rec["functions"] = [
+                    {"name": fun, "trace_ms": round(row[0], 1),
+                     "lower_ms": round(row[1], 1), "calls": row[2]}
+                    for fun, row in largest[:self.TOP_FUNCTIONS]]
                 programs[name] = rec
             totals = dict(self._totals)
-        totals["backend_compile_ms"] = round(totals["backend_compile_ms"], 1)
-        return {"programs": programs, "totals": totals}
+            spans = [dict(span) for span in self._spans]
+        for key in _COMPILE_MS_KEYS:
+            totals[key] = round(totals[key], 1)
+        return {"programs": programs, "totals": totals, "spans": spans}
 
 
 # Process-wide singleton: entry points (cli, benchmark, tools) and the trainer's
