@@ -261,7 +261,10 @@ def build_parser() -> argparse.ArgumentParser:
                         "block activations in backward instead of storing "
                         "them (~depth x lower activation memory for the "
                         "token axis; composes with --grad-accum and the "
-                        "parallelism flags). --model vit only")
+                        "parallelism flags). Block-structured models: "
+                        "vit, and laguna, sambay and instella, whose "
+                        "blocks keep their experts' choice and the flash "
+                        "kernel's results")
     p.add_argument("--optimizer-sharding", type=str, default="none",
                    choices=["none", "zero1", "zero3"],
                    help="zero1 = shard Adam moments over the data axis "

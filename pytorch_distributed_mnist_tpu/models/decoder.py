@@ -49,6 +49,8 @@ from pytorch_distributed_mnist_tpu.models.moe import (
 from pytorch_distributed_mnist_tpu.models.registry import register_model
 from pytorch_distributed_mnist_tpu.ops.attention import (
     CORE_SCOPE,
+    FLASH_LSE_NAME,
+    FLASH_OUT_NAME,
     full_attention,
 )
 from pytorch_distributed_mnist_tpu.ops.pallas.rope import (
@@ -56,7 +58,10 @@ from pytorch_distributed_mnist_tpu.ops.pallas.rope import (
     whole_heads,
 )
 from pytorch_distributed_mnist_tpu.parallel.moe_dispatch import CHOICE_NAME
-from pytorch_distributed_mnist_tpu.utils.profiling import rotary_sites
+from pytorch_distributed_mnist_tpu.utils.profiling import (
+    flash_schedules,
+    rotary_sites,
+)
 
 FULL, WINDOW = "full_attention", "sliding_attention"
 DENSE, SPARSE = "dense", "sparse"
@@ -198,6 +203,32 @@ def attend(q, k, v, *, window: Optional[int], attention: str,
     kind = "cross" if cross else "full" if window is None else "window"
     with jax.named_scope(f"{CORE_SCOPE}/{kind}"):
         return fn(q, k, v, causal=True, window=window, scale=scale)
+
+
+_KEPT_NAMES = jax.checkpoint_policies.save_only_these_names(
+    CHOICE_NAME, FLASH_OUT_NAME, FLASH_LSE_NAME)
+
+
+def _kept_by_a_recomputed_block(prim, *avals, **params) -> bool:
+    kept = _KEPT_NAMES(prim, *avals, **params)
+    if kept and params["name"] in (FLASH_OUT_NAME, FLASH_LSE_NAME):
+        flash_schedules.record_kept()
+    return kept
+
+
+def recomputed(block_cls):
+    """``block_cls`` under per-block recomputation (``nn.remat``): the
+    backward pass runs the block's forward again and keeps nothing of it
+    but what the block names, wherever it has them: its experts' choice
+    (``route_topk``: chosen again on values rounded elsewhere it is not
+    always the same choice) and the flash forward kernel's result and row
+    statistics (``ops/pallas/flash.py``: all its backward needs beside q, k
+    and v, so the kernel runs once a layer and step; 0.4-1.2 GB a step in
+    the benchmark's cells). The names are values of the block's own
+    program, outside every ``custom_vjp``: the policy is asked about the
+    equations of that program alone and never sees inside a forward rule.
+    ``flash_schedules`` counts the flash results kept."""
+    return nn.remat(block_cls, policy=_kept_by_a_recomputed_block)
 
 
 class GatedAttention(nn.Module):
@@ -414,11 +445,9 @@ class Decoder(nn.Module):
                      embedding_init=nn.initializers.normal(stddev=1.0),
                      dtype=self.compute_dtype, name="embed")(
             tokens.astype(jnp.int32))
-        # A recomputed block keeps its experts' choice (``route_topk``).
-        block_cls = nn.remat(
-            DecoderBlock,
-            policy=jax.checkpoint_policies.save_only_these_names(
-                CHOICE_NAME)) if self.remat else DecoderBlock
+        # A recomputed block keeps its experts' choice and its flash
+        # kernel's results (``recomputed``).
+        block_cls = recomputed(DecoderBlock) if self.remat else DecoderBlock
         experts = _frozen(dict(
             num_experts=self.num_experts, top_k=self.top_k,
             width=self.expert_size, shared_width=self.shared_expert_size,

@@ -62,10 +62,10 @@ from pytorch_distributed_mnist_tpu.models.decoder import (
     LatentAttention,
     RMSNorm,
     _frozen,
+    recomputed,
 )
 from pytorch_distributed_mnist_tpu.models.moe import SparseExperts, SwiGLU
 from pytorch_distributed_mnist_tpu.models.registry import register_model
-from pytorch_distributed_mnist_tpu.parallel.moe_dispatch import CHOICE_NAME
 
 # The tiny preset's rotary settings: the published kind at small numbers.
 TINY_ROPE = {"rope_type": "yarn", "rope_theta": 10000.0, "factor": 4.0,
@@ -171,11 +171,9 @@ class Instella(nn.Module):
                                 preferred_element_type=jnp.float32),
             name="head")
         norm = partial(RMSNorm, self.rms_eps, self.compute_dtype)
-        # A recomputed block keeps its experts' choice (``route_topk``).
-        block_cls = nn.remat(
-            LatentBlock,
-            policy=jax.checkpoint_policies.save_only_these_names(
-                CHOICE_NAME)) if self.remat else LatentBlock
+        # A recomputed block keeps its experts' choice and its flash
+        # kernel's results (``decoder.recomputed``).
+        block_cls = recomputed(LatentBlock) if self.remat else LatentBlock
         block = partial(
             block_cls,
             attn=_frozen(dict(

@@ -56,7 +56,11 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from pytorch_distributed_mnist_tpu.models.decoder import _frozen, attend
+from pytorch_distributed_mnist_tpu.models.decoder import (
+    _frozen,
+    attend,
+    recomputed,
+)
 from pytorch_distributed_mnist_tpu.models.moe import residual_init
 from pytorch_distributed_mnist_tpu.models.registry import register_model
 from pytorch_distributed_mnist_tpu.ops.ssm import selective_scan
@@ -338,7 +342,10 @@ class SambaY(nn.Module):
                          embedding_init=nn.initializers.normal(stddev=0.02),
                          dtype=self.compute_dtype, name="embed")
         x = embed(tokens.astype(jnp.int32))
-        block_cls = nn.remat(SambaYBlock) if self.remat else SambaYBlock
+        # A recomputed block keeps its flash kernel's results
+        # (``decoder.recomputed``); the scan, the combine and the MLP are
+        # computed again.
+        block_cls = recomputed(SambaYBlock) if self.remat else SambaYBlock
         attn = dict(num_heads=self.num_heads, num_kv_heads=self.num_kv_heads,
                     head_dim=self.head_dim, attention=self.attention)
         mixers = {

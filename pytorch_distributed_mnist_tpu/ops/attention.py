@@ -53,6 +53,14 @@ NEG_INF = -1e30  # softmax mask value; avoids -inf NaN propagation in exp
 # attention core from the qkv and output projections whichever one is in.
 CORE_SCOPE = "attn_core"
 
+# What a flash attention call names in its caller's program
+# (``jax.ad_checkpoint.checkpoint_name``; ``ops/pallas/flash.py``): the
+# forward kernel's result and its rows' logsumexp, all its backward reads
+# beside q, k and v. A recomputing policy that keeps both
+# (``models/decoder.py recomputed``) runs the forward kernel once a step.
+FLASH_OUT_NAME = "flash_out"
+FLASH_LSE_NAME = "flash_lse"
+
 # The most float32 scores, in bytes, that one slice of a dense call holds
 # (``slice_count``). Measured on a v5e (PR 30; T = 196, head size 64, bf16
 # operands; the core's device time, forward and backward, from a trace). Two

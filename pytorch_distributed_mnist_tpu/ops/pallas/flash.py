@@ -4,7 +4,8 @@ Blockwise online-softmax attention: scores are computed tile-by-tile in
 VMEM and never materialized as a (T, T) matrix in HBM — in either pass.
 The forward kernel additionally emits the per-row logsumexp; the backward
 is one kernel over that residual, which evaluates every tile once and
-takes all three gradients from it:
+takes all three gradients from it (how the residual reaches it, and what a
+recomputed block keeps of it: the last section):
 
   delta_i = rowsum(dO_i * O_i)                       (tiny elementwise, XLA)
   P_ij    = exp(scale * q_i.k_j - lse_i)             (recomputed per tile)
@@ -88,6 +89,27 @@ by what it keeps resident, and one that would pass ``VMEM_MAX_BYTES``
 (T = 49,152 in bf16 at D = 128) is refused by name; there is no second
 path.
 
+What a recomputed block keeps. The backward kernel reads q, k, v and the
+forward kernel's two results, ``out`` and the rows' ``lse``. Were the
+forward kernel called inside a ``custom_vjp``'s forward rule, a block under
+``jax.checkpoint`` (``nn.remat``) would run it twice a step: a policy is
+asked about the equations of the block's own program, where the whole
+``custom_vjp`` is one equation, so it never sees inside the rule, and the
+recomputation runs the rule again for its residuals. So the kernel is
+called outside any ``custom_vjp``, on ``stop_gradient`` of q, k and v (no
+tangent reaches it, so nothing asks for its derivative), its results are
+named there (``FLASH_OUT_NAME``, ``FLASH_LSE_NAME``: ``checkpoint_name``,
+an identity by itself), and ``_with_gradients``, whose primal is the
+identity on ``out``, takes ``(q, k, v, out, lse)``, keeps them as its
+residuals and hands the backward kernel's ``dq, dk, dv`` back. Under a
+policy that keeps the two names (``models/decoder.py recomputed``: the
+three token models) the recomputed block has ``out`` and ``lse`` already
+and the kernel's equation is dropped from it: ``flash_fwd`` runs once a
+layer and step, for ``out`` in the operands' type and 4 bytes a (row, head)
+kept from the forward pass to the block's backward. With no policy, or one
+without the names (``nn.remat(TransformerBlock)``), and with no
+recomputation at all, the program is what it was.
+
 Layout of the public function: ``(B, T, H, D)``.
 """
 
@@ -98,10 +120,16 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from pytorch_distributed_mnist_tpu.ops.attention import CORE_SCOPE, NEG_INF
+from pytorch_distributed_mnist_tpu.ops.attention import (
+    CORE_SCOPE,
+    FLASH_LSE_NAME,
+    FLASH_OUT_NAME,
+    NEG_INF,
+)
 from pytorch_distributed_mnist_tpu.ops.pallas.backend import should_interpret
 from pytorch_distributed_mnist_tpu.utils.profiling import flash_schedules
 
@@ -688,27 +716,38 @@ def _flash_backward(q, k, v, o, lse, g, causal: bool, window, scale: float,
 # --------------------------------------------------------------------------
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
 def _flash(q, k, v, causal, window, scale, block):
-    out, _ = _flash_forward(
-        q, k, v, causal, window, scale, should_interpret(), block)
+    """The forward kernel on constants of the differentiation, its two
+    results named, and ``_with_gradients`` to carry the gradients past it
+    (module docstring, "What a recomputed block keeps")."""
+    out, lse = _flash_forward(
+        *map(jax.lax.stop_gradient, (q, k, v)), causal, window, scale,
+        should_interpret(), block)
+    return _with_gradients(
+        q, k, v, checkpoint_name(out, FLASH_OUT_NAME),
+        checkpoint_name(lse, FLASH_LSE_NAME), causal, window, scale, block)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
+def _with_gradients(q, k, v, out, lse, causal, window, scale, block):
+    """``out``, as a function of q, k and v whose gradients are the
+    backward kernel's."""
     return out
 
 
-def _flash_fwd(q, k, v, causal, window, scale, block):
-    out, lse = _flash_forward(
-        q, k, v, causal, window, scale, should_interpret(), block)
+def _with_gradients_fwd(q, k, v, out, lse, causal, window, scale, block):
     return out, (q, k, v, out, lse)
 
 
-def _flash_bwd(causal, window, scale, block, residuals, g):
+def _with_gradients_bwd(causal, window, scale, block, residuals, g):
     q, k, v, out, lse = residuals
-    return _flash_backward(
+    # ``out`` and ``lse`` arrive as constants: no gradient goes back to them.
+    return *_flash_backward(
         q, k, v, out, lse, g, causal, window, scale, should_interpret(),
-        block)
+        block), None, None
 
 
-_flash.defvjp(_flash_fwd, _flash_bwd)
+_with_gradients.defvjp(_with_gradients_fwd, _with_gradients_bwd)
 
 
 def flash_attention(q, k, v, *, causal: bool = False,

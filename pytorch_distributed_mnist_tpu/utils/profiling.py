@@ -299,13 +299,18 @@ class ScheduleLog:
     tiles into one, and over those the pairs the tiles evaluate a pair
     the mask keeps — 1 is a kernel that evaluates no pair in vain. Of the
     backward calls, how many there were and how many run as one kernel,
-    which evaluates each tile once for all three gradients."""
+    which evaluates each tile once for all three gradients. And how often
+    a recomputing policy kept a forward's result or its rows' logsumexp
+    (``models/decoder.py recomputed``: two a call whose block is
+    recomputed, each time such a block is differentiated), so that the
+    backward pass does not run the forward kernel again."""
 
     def __init__(self) -> None:
         self._lock = threading.Lock()
         self._sites = self._folded_sites = 0
         self._needed = self._evaluated = 0
         self._backward_sites = self._fused_backward_sites = 0
+        self._kept_results = 0
 
     def record(self, counts: Dict[str, int],
                backward_kernels: int = 0) -> None:
@@ -321,6 +326,10 @@ class ScheduleLog:
                 self._backward_sites += 1
                 self._fused_backward_sites += backward_kernels == 1
 
+    def record_kept(self) -> None:
+        with self._lock:
+            self._kept_results += 1
+
     def snapshot(self) -> Dict:
         with self._lock:
             return {
@@ -330,7 +339,8 @@ class ScheduleLog:
                     round(self._evaluated / self._needed, 4)
                     if self._needed else None),
                 "backward_sites": self._backward_sites,
-                "fused_backward_sites": self._fused_backward_sites}
+                "fused_backward_sites": self._fused_backward_sites,
+                "kept_results": self._kept_results}
 
 
 flash_schedules = ScheduleLog()

@@ -405,6 +405,8 @@ def test_a_traced_call_records_its_schedule():
     # the backward is one site and one kernel, which the program shows
     assert folded["backward_sites"] == after["backward_sites"] + 1
     assert folded["fused_backward_sites"] == folded["backward_sites"]
+    # no policy asked for the forward's results: none kept
+    assert folded["kept_results"] == before["kept_results"]
     assert device_report()["flash_schedules"] == folded
 
 

@@ -238,7 +238,7 @@ def test_metrics_file(tmp_path):
     assert set(summary["pallas_lowerings"]) == {"mosaic", "interpret"}
     assert set(summary["flash_schedules"]) == {
         "sites", "folded_sites", "folded_evaluated_over_needed",
-        "backward_sites", "fused_backward_sites"}
+        "backward_sites", "fused_backward_sites", "kept_results"}
     assert set(summary["dense_attention_slices"]) == {
         "sites", "sliced_sites", "slices_per_sliced_site"}
     assert set(summary["rotary_sites"]) == {

@@ -354,8 +354,10 @@ def test_lagunas_pass_lowers_to_what_it_did():
     """``models/decoder.py attend`` learnt a scale and a third kind of core
     for this model; the calls ``laguna`` makes lower to the text they
     lowered to before it did. The digest is taken with this function: PR
-    31's parent's until PR 32 made the flash backward one kernel, since
-    then PR 32's tree's (the forward's text did not change with it)."""
+    31's parent's until PR 32 made the flash backward one kernel, PR 32's
+    tree's until PR 36 took the recomputed forward kernel out of a
+    recomputed block (``decoder.recomputed`` keeps its two results), since
+    then PR 36's tree's."""
     import jax
     import jax.numpy as jnp
 
@@ -381,4 +383,4 @@ def test_lagunas_pass_lowers_to_what_it_did():
 
 
 LAGUNA_LOWERED = (
-    "35ca6901c26f9b2007ef96eb2450e9a2651181a81e3dfb92cf77301ac091f0bd")
+    "ea940b26af7caec8b87e4d40330d21e1ebd8a7423a489aa67eae87a82094725d")

@@ -280,3 +280,55 @@ def test_the_rotary_is_one_kernel_on_whole_heads_in_the_compiled_layer(
     flash_fwd = re.search(r"custom-call\(([^)]*)\)[^\n]*flash_fwd", text)
     assert {name for name, width, _ in calls if int(width) == heads * d} \
         & set(re.findall(r"%([\w.-]+)", flash_fwd.group(1)))
+
+
+@pytest.mark.parametrize("policy, forwards", [(True, 2), (False, 3)])
+def test_recomputed_layers_compile_to_one_flash_forward_each(
+        one_chip, monkeypatch, policy, forwards):
+    """In this file because it is the one that describes the chip. A
+    jaxpr without the recomputed ``flash_fwd`` equation
+    (tests/test_flash_remat.py) is not yet a compiled program without the
+    kernel: compiled for the chip, two ``GatedAttention`` layers under
+    ``decoder.recomputed`` hold two ``flash_fwd`` custom calls and two
+    backward kernels. Under ``nn.remat`` with no policy they hold three:
+    the first layer's recomputed call, which waits for the second's
+    gradient (XLA shares the last layer's with its forward here; in a
+    model every layer but the last pays)."""
+    import re
+
+    import flax.linen as nn
+
+    from pytorch_distributed_mnist_tpu.models import decoder
+    from pytorch_distributed_mnist_tpu.ops.pallas import flash, rope
+
+    monkeypatch.setattr(flash, "should_interpret", lambda: False)
+    monkeypatch.setattr(rope, "should_interpret", lambda: False)
+    layer_cls = (decoder.recomputed if policy else nn.remat)(
+        decoder.GatedAttention)
+
+    class Layers(nn.Module):
+        @nn.compact
+        def __call__(self, u):
+            for i in range(2):
+                u = u + layer_cls(
+                    num_heads=4, num_kv_heads=2, head_dim=128, window=None,
+                    rope=decoder._frozen(decoder.TINY_ROPE[decoder.FULL]),
+                    depth=2, attention="flash", name=f"attn{i}")(u)
+            return u
+
+    def on_chip(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    u = jax.ShapeDtypeStruct((1, 1024, 256), jnp.bfloat16)
+    weights = jax.eval_shape(Layers().init, jax.random.key(0), u)
+
+    def loss(p, u):
+        return jnp.sum(Layers().apply(p, u).astype(jnp.float32))
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        on_chip(weights), on_chip(u)).compile().as_text()
+    kernels_called = re.findall(
+        r"%(flash_\w+?)[.\d]* = [^\n=]* custom-call\(", text)
+    assert kernels_called.count("flash_fwd") == forwards
+    assert kernels_called.count("flash_bwd_dq_dkv") == 2
