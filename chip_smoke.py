@@ -25,6 +25,11 @@ Drives the main path once, through the entry points a user would call:
               top-4 of 16 experts under a selection bias the step moves
               (the resumed evaluation reads the bias from the
               checkpoint), the multi-token-prediction module;
+            * the Mamba-2 hybrid, ``--model granite_hybrid --dataset
+              synthetic_tokens`` at its tiny preset on the same sequences:
+              three chunked state-space scans of four chunks (the kernel
+              pair ``ssd_fwd`` and ``ssd_bwd``), position-free attention
+              through the flash kernels, the four multipliers;
   server    python -m pytorch_distributed_mnist_tpu serve      (server ->
             engine -> batcher -> pool) on the checkpoint the trainer just
             wrote, answering ``tools/loadgen.py --smoke`` and a batch of
@@ -82,6 +87,7 @@ LAGUNA = ["--model", "laguna", "--dataset", "synthetic_tokens",
           "--synthetic-test-size", "16", "--lr", "1e-3"]
 SAMBAY = ["--model", "sambay"] + LAGUNA[2:]
 INSTELLA = ["--model", "instella"] + LAGUNA[2:]
+GRANITE = ["--model", "granite_hybrid"] + LAGUNA[2:]
 # The trainer's own warnings that a compiled program was refused or
 # compiled twice (train/trainer.py): legitimate on a user's machine,
 # a failure here.
@@ -477,6 +483,8 @@ def main() -> int:
         smoke.train("laguna", LAGUNA, kernels=True, steps=LAGUNA_STEPS)
         smoke.train("sambay", SAMBAY, kernels=True, steps=LAGUNA_STEPS)
         smoke.train("instella", INSTELLA, kernels=True, steps=LAGUNA_STEPS)
+        smoke.train("granite_hybrid", GRANITE, kernels=True,
+                    steps=LAGUNA_STEPS)
         ref = smoke.reference(cnn)
         smoke.serve(cnn, ref, "f32")
         smoke.serve(cnn, ref, "int8")

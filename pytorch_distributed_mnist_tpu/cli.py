@@ -144,7 +144,8 @@ def build_parser() -> argparse.ArgumentParser:
                             "synthetic_tokens"],
                    help="synthetic_tokens: a seeded corpus of packed "
                         "token sequences (data/tokens.py) for a token "
-                        "model (--model laguna, sambay, instella); "
+                        "model (--model laguna, sambay, instella, "
+                        "granite_hybrid); "
                         "--synthetic-*-size count sequences of --seq-len "
                         "tokens")
     p.add_argument("--seq-len", type=int, default=64,
@@ -262,9 +263,9 @@ def build_parser() -> argparse.ArgumentParser:
                         "them (~depth x lower activation memory for the "
                         "token axis; composes with --grad-accum and the "
                         "parallelism flags). Block-structured models: "
-                        "vit, and laguna, sambay and instella, whose "
-                        "blocks keep their experts' choice and the flash "
-                        "kernel's results")
+                        "vit, and laguna, sambay, instella and "
+                        "granite_hybrid, whose blocks keep their experts' "
+                        "choice and the flash kernel's results")
     p.add_argument("--optimizer-sharding", type=str, default="none",
                    choices=["none", "zero1", "zero3"],
                    help="zero1 = shard Adam moments over the data axis "
@@ -1571,7 +1572,8 @@ def _run_body(args) -> dict:
     if tokens != (_token_vocab(args) is not None):
         raise SystemExit(
             f"--model {args.model} and --dataset {args.dataset} do not go "
-            f"together: a token model (laguna, sambay, instella) reads "
+            f"together: a token model (laguna, sambay, instella, "
+            f"granite_hybrid) reads "
             f"--dataset synthetic_tokens, and nothing else does")
     moe_dispatch = getattr(args, "moe_dispatch", "dense")
     if getattr(args, "remat", False):

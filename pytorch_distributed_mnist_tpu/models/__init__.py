@@ -8,9 +8,12 @@ required for the >=99% MNIST accuracy target (BASELINE.json north star),
 ``vit`` and ``moe_mlp`` carry attention and experts, ``laguna`` is the
 decoder-only token model family (``models/decoder.py``), ``sambay`` the
 hybrid of state-space, differential-attention, gated-memory and shared-KV
-cross layers (``models/sambay.py``) and ``instella`` the latent-attention
+cross layers (``models/sambay.py``), ``instella`` the latent-attention
 sparse decoder with a selection bias, a multi-token-prediction module and
-the FarSkip residual (``models/instella.py``).
+the FarSkip residual (``models/instella.py``) and ``granite_hybrid`` the
+hybrid of Mamba-2 layers in their chunked matrix-product form and
+position-free grouped-query attention under the Granite family's four
+multipliers (``models/granite.py``).
 """
 
 from pytorch_distributed_mnist_tpu.models.linear import LinearNet
@@ -20,6 +23,7 @@ from pytorch_distributed_mnist_tpu.models.moe import MoEClassifier, SparseExpert
 from pytorch_distributed_mnist_tpu.models.decoder import Decoder
 from pytorch_distributed_mnist_tpu.models.sambay import SambaY
 from pytorch_distributed_mnist_tpu.models.instella import Instella
+from pytorch_distributed_mnist_tpu.models.granite import GraniteHybrid
 from pytorch_distributed_mnist_tpu.models.registry import get_model, register_model, list_models, model_accepts
 
 __all__ = [
@@ -32,6 +36,7 @@ __all__ = [
     "Decoder",
     "SambaY",
     "Instella",
+    "GraniteHybrid",
     "get_model",
     "register_model",
     "list_models",

@@ -6,10 +6,12 @@ For every sequence, channel ``c`` and state ``n``:
     s_t[c, n] = exp(dt_t[c] A[c, n]) s_{t-1}[c, n] + dt_t[c] B_t[n] a_t[c]
     m_t[c]    = sum_n C_t[n] s_t[c, n] + D[c] a_t[c],            s_0 = 0
 
-The decay differs by channel, by state and by position, so there is no
-matrix-multiplication form of it (that is Mamba-2's, whose decay is one
-number a head): the work is elementwise, 7 operations a (position,
-channel, state), and sequential in ``t``.
+The decay differs by channel, by state and by position, so this
+recurrence has no matrix-multiplication form: the work is elementwise, 7
+operations a (position, channel, state), and sequential in ``t``. The
+recurrence that has one, Mamba-2's, whose decay is one number a head and
+position, is ``ops/ssd.py``'s: another function, because it is another
+recurrence.
 
 :func:`selective_scan` never holds the ``(T, C, N)`` states (5.4 GB a
 sequence in float32 at T = 16,384, C = 5,120, N = 16). It walks the

@@ -406,13 +406,19 @@ rotary_sites = RotaryLog()
 
 
 class ScanLog:
-    """The selective scans traced in this process (``ops/ssm.py`` records
-    one a call) and who reads what a layer publishes for later layers
-    (``models/sambay.py`` records one a reading layer): how many scans
-    there were, the chunks a scan walks, the bytes of state a scan keeps
-    at its chunks' starts for the backward (all it keeps of the ``(T, C,
-    N)`` states), and the layers traced that read the published scan
-    output (``memory``) and the published keys and values (``kv``)."""
+    """The state-space scans traced in this process and who reads what a
+    layer publishes for later layers (``models/sambay.py`` records one a
+    reading layer). Two recurrences, counted apart: the selective scans
+    that step through time (``ops/ssm.py`` records one a call: ``sites``,
+    ``chunks_per_site``, ``state_bytes_kept_per_site``) and the chunked
+    scans in matrix-product form (``ops/ssd.py``: the same three under
+    ``chunked_``). For either: how many there were, the chunks a scan
+    walks, and the bytes of state a scan keeps at its chunks' starts for
+    the backward (all it keeps of the per-position states). And the layers
+    traced that read the published scan output (``memory``) and the
+    published keys and values (``kv``)."""
+
+    _KINDS = {"stepped": "", "chunked": "chunked_"}  # kind: its keys' prefix
 
     def __init__(self) -> None:
         self._lock = threading.Lock()
@@ -420,14 +426,22 @@ class ScanLog:
 
     def reset(self) -> None:
         with self._lock:
-            self._sites = self._chunks = self._state_bytes = 0
+            # kind -> [sites, chunks, state bytes]
+            self._scans = {kind: [0, 0, 0] for kind in self._KINDS}
             self._readers = {"memory": 0, "kv": 0}
 
-    def record_scan(self, *, chunks: int, state_bytes: int) -> None:
+    def _record(self, kind: str, chunks: int, state_bytes: int) -> None:
         with self._lock:
-            self._sites += 1
-            self._chunks += chunks
-            self._state_bytes += state_bytes
+            totals = self._scans[kind]
+            totals[0] += 1
+            totals[1] += chunks
+            totals[2] += state_bytes
+
+    def record_scan(self, *, chunks: int, state_bytes: int) -> None:
+        self._record("stepped", chunks, state_bytes)
+
+    def record_chunked(self, *, chunks: int, state_bytes: int) -> None:
+        self._record("chunked", chunks, state_bytes)
 
     def record_reader(self, what: str) -> None:
         with self._lock:
@@ -435,15 +449,14 @@ class ScanLog:
 
     def snapshot(self) -> Dict:
         with self._lock:
-            n = self._sites
-
-            def per_site(total):
-                return total / n if n else None
-
-            return {"sites": n,
-                    "chunks_per_site": per_site(self._chunks),
-                    "state_bytes_kept_per_site": per_site(self._state_bytes),
-                    "memory_readers": self._readers["memory"],
+            out = {}
+            for kind, prefix in self._KINDS.items():
+                n, chunks, kept = self._scans[kind]
+                out[f"{prefix}sites"] = n
+                out[f"{prefix}chunks_per_site"] = chunks / n if n else None
+                out[f"{prefix}state_bytes_kept_per_site"] = \
+                    kept / n if n else None
+            return {**out, "memory_readers": self._readers["memory"],
                     "kv_readers": self._readers["kv"]}
 
 

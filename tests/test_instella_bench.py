@@ -85,7 +85,8 @@ def test_the_cell_and_its_files_are_in_the_benchmark():
     cell = next(w for w in spec["workloads"] if w["name"] == CELL)
     assert cell == {**cell, "config": "instella-moe-16b-ep8", "chips": 1,
                     "traffic": "train_lm_mtp_packed_8k_b2"}
-    assert spec["workloads"][-1] == cell  # appended, nothing moved
+    # the sixth cell, where PR 33 appended it; later PRs append after it
+    assert spec["workloads"][5] == cell
     entry = next(c for c in spec["configs"] if c["name"] == cell["config"])
     assert entry["source"] == cfg["source"]
     assert entry["reduced"] == cfg["reduced"] == [

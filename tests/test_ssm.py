@@ -1,7 +1,9 @@
 """The chunked selective scan (``ops/ssm.py``) against the recurrence
 written position by position: values and all six gradients, at lengths that
 are and are not multiples of the chunk, at two chunk lengths and at the one
-the shape gives; the chunk length as a function of the shape; the counter."""
+the shape gives; the chunk length as a function of the shape; the counter.
+And, because this is the one file that describes the chip, the compiles
+for a described v5e of every kernel of the token cells' main path."""
 
 import jax
 import jax.numpy as jnp
@@ -155,6 +157,46 @@ def test_the_kernels_compile_at_the_cells_shape_and_hold_no_states(
     assert "selective_scan_fwd" in text and "selective_scan_bwd" in text
     assert compiled.memory_analysis().temp_size_in_bytes < 2e9
     assert f"[1,{t},{n},{c}]" not in text and f"[1,{t},{c},{n}]" not in text
+
+
+def test_the_chunked_scans_kernels_compile_at_the_cells_shape(one_chip,
+                                                              monkeypatch):
+    """In this file because it is the one that describes the chip. Both
+    kernels of ``ops/pallas/ssd.py`` through Mosaic at (1, 8192, 64, 64),
+    N = 128, chunk 256: what the interpreter cannot refuse (tiling, VMEM),
+    and that the compiled gradient holds the chunk-start states (67 MB)
+    and their like, not the 17 GB of per-position states nor a decay
+    tensor of ``(heads, chunks, 256, 256)`` (0.54 GB)."""
+    from pytorch_distributed_mnist_tpu.ops import ssd
+    from pytorch_distributed_mnist_tpu.ops.ssd import ssd_scan
+
+    monkeypatch.setattr(ssd, "should_interpret", lambda: False)
+    t, h, p, n = 8192, 64, 64, 128
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    args = (shape((1, t, h, p), jnp.bfloat16), shape((1, t, h), jnp.float32),
+            shape((h,), jnp.float32), shape((1, t, n), jnp.bfloat16),
+            shape((1, t, n), jnp.bfloat16), shape((h,), jnp.float32))
+
+    def loss(*xs):
+        return jnp.sum(ssd_scan(*xs).astype(jnp.float32))
+
+    compiled = jax.jit(jax.grad(loss, argnums=range(6))).lower(
+        *args).compile()
+    text = compiled.as_text()
+    assert "ssd_fwd" in text and "ssd_bwd" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.5e9
+    assert f"f32[1,{t // 256},{h * p // 128},128,{n}]" in text  # the starts
+    assert f"[1,{t},{h},{p},{n}]" not in text
+    # the (256, 256) tiles that exist are a chunk's, in the kernels' VMEM
+    import math
+    import re
+
+    tiles = [math.prod(int(d) for d in dims.split(","))
+             for dims in re.findall(r"\[([\d,]*256,256)\]", text)]
+    assert all(size <= 256 * 256 for size in tiles), max(tiles)
 
 
 def test_no_op_of_xlas_carries_the_flash_backwards_scope(one_chip,
