@@ -60,6 +60,7 @@ from pytorch_distributed_mnist_tpu.ops.pallas.rope import (
 from pytorch_distributed_mnist_tpu.parallel.moe_dispatch import CHOICE_NAME
 from pytorch_distributed_mnist_tpu.utils.profiling import (
     flash_schedules,
+    head_gate_sites,
     rotary_sites,
 )
 
@@ -231,6 +232,57 @@ def recomputed(block_cls):
     return nn.remat(block_cls, policy=_kept_by_a_recomputed_block)
 
 
+def _head_lanes(h: int, d: int, dtype) -> jnp.ndarray:
+    """``(h, h * d)``: 1 where lane ``j`` of the packed view belongs to
+    head ``h``, else 0. A product with it repeats each head's value over
+    the head's ``d`` lanes; one with its transpose sums each head's lanes.
+    A single 1 a column, so both are exact in bfloat16."""
+    lanes = jax.lax.broadcasted_iota(jnp.int32, (h, h * d), 1) // d
+    heads = jax.lax.broadcasted_iota(jnp.int32, (h, h * d), 0)
+    return (lanes == heads).astype(dtype)
+
+
+@jax.custom_vjp
+def _gated(o: jnp.ndarray, gate: jnp.ndarray) -> jnp.ndarray:
+    b, t, h, d = o.shape
+    return o.reshape(b, t, h * d) * (gate @ _head_lanes(h, d, gate.dtype))
+
+
+def _gated_fwd(o, gate):
+    return _gated(o, gate), (o, gate)
+
+
+def _gated_bwd(residuals, g):
+    o, gate = residuals
+    b, t, h, d = o.shape
+    lanes = _head_lanes(h, d, gate.dtype)
+    do = g * (gate @ lanes)
+    dgate = jax.lax.dot_general(g * o.reshape(b, t, h * d), lanes,
+                                (((2,), (1,)), ((), ())))
+    # The flash backward reads ``do`` packed in its kernel and as (B, T, H,
+    # D) in ``delta``, a re-tile apart. Behind the barrier that re-tile is
+    # of the bfloat16 ``do``; without it the compiler cast ``do`` to
+    # float32 for ``delta`` first and re-tiled twice the bytes.
+    return jax.lax.optimization_barrier(do.reshape(b, t, h, d)), dgate
+
+
+_gated.defvjp(_gated_fwd, _gated_bwd)
+
+
+def gate_heads(o: jnp.ndarray, gate: jnp.ndarray) -> jnp.ndarray:
+    """``o`` (B, T, H, D), the attention's result, times one gate a head
+    ``gate`` (B, T, H), as the packed ``(B, T, H * D)`` that the output
+    projection reads. The gate is repeated over its head's lanes by a
+    product with :func:`_head_lanes`, and its gradient is the same
+    product's transpose: neither builds a ``(B, T, H, D)`` value, which cost
+    the compiled step a re-tile of the result, a broadcast of the gate and
+    a multiply of their own, forward, recomputed and backward. The same
+    bfloat16 products as ``o * gate[..., None]``.
+    ``utils.profiling.head_gate_sites`` counts the calls."""
+    head_gate_sites.record(o.shape[-1])
+    return _gated(o, gate)
+
+
 class GatedAttention(nn.Module):
     """q, kv projections -> rotary -> causal (windowed) grouped-query
     attention -> one sigmoid output gate a head -> output projection."""
@@ -261,10 +313,9 @@ class GatedAttention(nn.Module):
             q = apply_rope(q, inv_freq, factor)
             k = apply_rope(k, inv_freq, factor)
         o = attend(q, k, v, window=self.window, attention=self.attention)
-        o = o.astype(self.compute_dtype) \
-            * nn.sigmoid(dense(h, "gate")(u))[..., None]
-        return dense(c, "proj", kernel_init=residual_init(self.depth))(
-            o.reshape(b, t, h * d))
+        o = gate_heads(o.astype(self.compute_dtype),
+                       nn.sigmoid(dense(h, "gate")(u)))
+        return dense(c, "proj", kernel_init=residual_init(self.depth))(o)
 
 
 def yarn_softmax_scale(qk_dim: int, rope: dict) -> float:

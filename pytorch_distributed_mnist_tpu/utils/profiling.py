@@ -247,7 +247,8 @@ def device_report() -> dict:
     ``numpy``), ``pallas_lowerings`` (:class:`LoweringLog`),
     ``flash_schedules`` (:class:`ScheduleLog`),
     ``dense_attention_slices`` (:class:`SliceLog`), ``rotary_sites``
-    (:class:`RotaryLog`), ``state_scans`` (:class:`ScanLog`) and
+    (:class:`RotaryLog`), ``head_gate_sites`` (:class:`HeadGateLog`),
+    ``state_scans`` (:class:`ScanLog`) and
     ``expert_routing`` (:class:`RoutingLog`: ``{}`` for a model without
     top-k expert layers)."""
     from pytorch_distributed_mnist_tpu.data import native
@@ -261,6 +262,7 @@ def device_report() -> dict:
             "flash_schedules": flash_schedules.snapshot(),
             "dense_attention_slices": dense_attention_slices.snapshot(),
             "rotary_sites": rotary_sites.snapshot(),
+            "head_gate_sites": head_gate_sites.snapshot(),
             "state_scans": scan_log.snapshot(),
             "expert_routing": routing_log.summary()}
 
@@ -403,6 +405,31 @@ class RotaryLog:
 
 
 rotary_sites = RotaryLog()
+
+
+class HeadGateLog:
+    """The output gates a head traced in this process
+    (``models/decoder.py gate_heads``, which gates the attention's result
+    on its packed ``(B, T, H * D)`` view and records each traced forward
+    call): how many there were, and the head widths ``D`` seen."""
+
+    def __init__(self) -> None:
+        self._lock = threading.Lock()
+        self._sites = 0
+        self._head_widths = set()
+
+    def record(self, head_dim: int) -> None:
+        with self._lock:
+            self._sites += 1
+            self._head_widths.add(head_dim)
+
+    def snapshot(self) -> Dict:
+        with self._lock:
+            return {"sites": self._sites,
+                    "head_widths": sorted(self._head_widths)}
+
+
+head_gate_sites = HeadGateLog()
 
 
 class ScanLog:

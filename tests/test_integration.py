@@ -243,6 +243,7 @@ def test_metrics_file(tmp_path):
         "sites", "sliced_sites", "slices_per_sliced_site"}
     assert set(summary["rotary_sites"]) == {
         "sites", "whole_head_sites", "rotated_lanes"}
+    assert set(summary["head_gate_sites"]) == {"sites", "head_widths"}
     assert "train_epoch" in summary["compile_stats"]["programs"]
     assert "history" not in summary  # the epoch rows above already say it
     for key in ("platform", "device_kind", "device_count"):

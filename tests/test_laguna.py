@@ -32,6 +32,10 @@ from pytorch_distributed_mnist_tpu.parallel.moe_dispatch import (
     held_experts_forward,
     route_topk,
 )
+from pytorch_distributed_mnist_tpu.utils.profiling import (
+    device_report,
+    head_gate_sites,
+)
 
 T = 64
 # The tiny preset as a configuration file's kwargs would carry it.
@@ -351,6 +355,101 @@ def test_grouped_heads_read_their_key_value_head():
                              v[:, :, head // 3:head // 3 + 1], causal=True)
         np.testing.assert_allclose(got[:, :, head], one[:, :, 0],
                                    atol=1e-6, rtol=1e-6)
+
+
+def _per_head_gate(o, gate):
+    """The parent's gate: ``gate`` broadcast over each head's lanes on the
+    ``(B, T, H, D)`` view."""
+    b, t, h, d = o.shape
+    return (o * gate[..., None]).reshape(b, t, h * d)
+
+
+def _gate(u, w_g):
+    return jax.nn.sigmoid(u @ w_g.astype(jnp.bfloat16))
+
+
+@pytest.mark.parametrize("heads, d", [(64, 128), (48, 128), (6, 16), (4, 16)],
+                         ids=["cell_window", "cell_full", "cli_window",
+                              "cli_full"])
+def test_the_packed_gate_is_the_per_head_gate(heads, d):
+    """``decoder.gate_heads`` against ``o * sigmoid(u W_g)[..., None]`` at
+    the cell's layers (64 and 48 heads of 128) and the CLI's decoder's (6
+    and 4 of 16). The value and the gradient to ``o`` are the same
+    bfloat16 products, bit for bit. The gradient to the gate is each head's
+    sum of the same products rounded once, and the gradients to ``u`` and
+    ``W_g`` are that sum's; the parent's reduction summed in bfloat16 on
+    the CPU, and is within its own rounding of it."""
+    b, t, c = 2, 16, 32
+    ks = jax.random.split(jax.random.key(heads), 4)
+    o = jax.random.normal(ks[0], (b, t, heads, d), jnp.bfloat16)
+    u = jax.random.normal(ks[1], (b, t, c), jnp.bfloat16)
+    w_g = 0.2 * jax.random.normal(ks[2], (c, heads))
+    cot = jax.random.normal(ks[3], (b, t, heads * d), jnp.bfloat16)
+    got, vjp = jax.vjp(
+        lambda o, u, w: decoder.gate_heads(o, _gate(u, w)), o, u, w_g)
+    want, vjp_want = jax.vjp(
+        lambda o, u, w: _per_head_gate(o, _gate(u, w)), o, u, w_g)
+    np.testing.assert_array_equal(got, want)
+    d_o, d_u, d_w = vjp(cot)
+    want_o, parent_u, parent_w = vjp_want(cot)
+    np.testing.assert_array_equal(d_o, want_o)
+    sums = jnp.sum((cot.reshape(o.shape) * o).astype(jnp.float32), -1)
+    want_u, want_w = jax.vjp(_gate, u, w_g)[1](sums.astype(jnp.bfloat16))
+    assert _rel_err(d_u, want_u) < 2 ** -8
+    assert _rel_err(d_w, want_w) < 2 ** -8
+    assert _rel_err(d_u, parent_u) < 2 ** -5
+    assert _rel_err(d_w, parent_w) < 2 ** -5
+
+
+def test_a_recomputed_layer_gates_as_the_per_head_formula(monkeypatch):
+    """A window layer of heads of 128 under ``decoder.recomputed``: its
+    value is the per-head formula's bit for bit, its gradients are the
+    plain layer's bit for bit and the formula's within the formula's
+    bfloat16 sums."""
+    layer = decoder.GatedAttention(
+        num_heads=4, num_kv_heads=2, head_dim=128, window=8,
+        rope=decoder._frozen(decoder.TINY_ROPE[decoder.WINDOW]), depth=2,
+        attention="dense")
+    recomputed = decoder.recomputed(decoder.GatedAttention)(
+        **{f: getattr(layer, f) for f in (
+            "num_heads", "num_kv_heads", "head_dim", "window", "rope",
+            "depth", "attention")})
+    ks = jax.random.split(jax.random.key(7), 3)
+    u = jax.random.normal(ks[0], (2, 32, 64), jnp.bfloat16)
+    cot = jax.random.normal(ks[1], (2, 32, 64), jnp.bfloat16)
+    params = layer.init(ks[2], u)
+
+    def run(module):
+        y, vjp = jax.vjp(module.apply, params, u)
+        return y, vjp(cot)
+
+    got, plain = run(recomputed), run(layer)
+    monkeypatch.setattr(decoder, "gate_heads", _per_head_gate)
+    want = run(recomputed)
+    np.testing.assert_array_equal(got[0], want[0])
+    for g, p, w in zip(*(jax.tree.leaves(x[1]) for x in (got, plain, want))):
+        np.testing.assert_array_equal(g, p)
+        assert _rel_err(g, w) < 2 ** -5
+
+
+@pytest.mark.parametrize("head_dim", [128, 16])
+def test_every_traced_gate_is_counted_with_its_head_width(head_dim):
+    """Five layers gate their heads, one site a layer and traced forward
+    call: ``init`` counts 5, and so does a traced gradient of the
+    recomputed model, whose recomputed forward is the traced forward's
+    jaxpr and whose backward is the gate's own rule."""
+    model = decoder.Decoder(remat=True, attention="dense", head_dim=head_dim)
+    tokens = jnp.zeros((2, 32), jnp.int32)
+    before = head_gate_sites.snapshot()["sites"]
+    params = jax.eval_shape(model.init, jax.random.key(0), tokens)
+    assert head_gate_sites.snapshot()["sites"] == before + 5
+    jax.make_jaxpr(jax.grad(lambda p: jnp.sum(model.apply(p, tokens))))(
+        params)
+    report = device_report()["head_gate_sites"]
+    assert report == head_gate_sites.snapshot()
+    assert report["sites"] == before + 10
+    assert head_dim in report["head_widths"]
+    assert report["head_widths"] == sorted(report["head_widths"])
 
 
 def test_token_corpus_is_seeded_packed_and_labelled():

@@ -324,6 +324,88 @@ def test_the_rotary_is_one_kernel_on_whole_heads_in_the_compiled_layer(
         & set(re.findall(r"%([\w.-]+)", flash_fwd.group(1)))
 
 
+def _instructions(text):
+    """``(op, result type, scope)`` of every instruction of a compiled
+    program. An instruction inside a fusion that names no scope of its own
+    takes the scope of the fusion that calls it."""
+    import re
+
+    caller_scope, found = {}, []
+    for line in text.splitlines():
+        call = re.search(r"calls=%([\w.-]+)", line)
+        scope = re.search(r'op_name="([^"]*)"', line)
+        if call and scope:
+            caller_scope[call.group(1)] = scope.group(1)
+    computation = ""
+    for line in text.splitlines():
+        head = re.match(r"(ENTRY )?%([\w.-]+) \(", line)
+        if head:
+            computation = "" if head.group(1) else head.group(2)
+        inst = re.match(r"\s*(?:ROOT )?%\S+ = (\w+\[[\d,]*\])\S* ([\w-]+)\(",
+                        line)
+        if inst:
+            scope = re.search(r'op_name="([^"]*)"', line)
+            found.append((inst.group(2), inst.group(1),
+                          scope.group(1) if scope
+                          else caller_scope.get(computation, "")))
+    return found
+
+
+@pytest.mark.parametrize("heads, kind", [(64, "sliding_attention"),
+                                         (48, "full_attention")])
+def test_the_gate_a_head_builds_no_head_view_in_the_compiled_layer(
+        one_chip, monkeypatch, heads, kind):
+    """In this file because it is the one that describes the chip.
+    ``GatedAttention`` at the training cell's ``(2, 8192)``, 64 heads of
+    128 and 48, forward and gradient of a recomputed block. Written on the
+    ``(B, T, H, D)`` view, the gate cost the step a broadcast of itself and
+    a multiply there, and re-tiles of the result, forward, recomputed and
+    backward (``broadcast bf16[2,8192,64,128]``, ``copy
+    bf16[2,8192,64,128]`` under ``attn/reshape``). On the packed view no
+    broadcast or multiply of the program has a result of rank 4 ending in
+    ``[heads,128]`` but the flash backward's own ``delta`` (``attn_core``),
+    and nothing under the ``gate`` or ``proj`` scopes has one."""
+    import json
+    import os
+
+    from pytorch_distributed_mnist_tpu.models import decoder
+    from pytorch_distributed_mnist_tpu.ops.pallas import flash, rope
+
+    monkeypatch.setattr(flash, "should_interpret", lambda: False)
+    monkeypatch.setattr(rope, "should_interpret", lambda: False)
+    with open(os.path.join(os.path.dirname(__file__), "..", "benchmark",
+                           "configs", "laguna-xs2-ep8.json")) as f:
+        params = json.load(f)["rope_parameters"][kind]
+    b, t, c, kv, d = 2, 8192, 2048, 8, 128
+    layer = decoder.recomputed(decoder.GatedAttention)(
+        num_heads=heads, num_kv_heads=kv, head_dim=d,
+        window=512 if kind == "sliding_attention" else None,
+        rope=decoder._frozen(params), depth=5, attention="flash")
+
+    def on_chip(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    u = jax.ShapeDtypeStruct((b, t, c), jnp.bfloat16)
+    weights = jax.eval_shape(layer.init, jax.random.key(0), u)
+
+    def loss(p, u):
+        return jnp.sum(jnp.square(layer.apply(p, u).astype(jnp.float32)))
+
+    ops = _instructions(jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        on_chip(weights), on_chip(u)).compile().as_text())
+    head_view = [(op, result, scope) for op, result, scope in ops
+                 if result.endswith(f",{heads},128]")
+                 and result.count(",") == 3
+                 and op not in ("bitcast", "parameter", "get-tuple-element")]
+    assert [(op, scope) for op, _, scope in head_view
+            if op in ("broadcast", "multiply")
+            and "/attn_core/" not in scope] == []
+    assert [(op, scope) for op, _, scope in head_view
+            if "/gate/" in scope or "/proj/" in scope] == []
+    assert any("/gate/" in scope for _, _, scope in ops)
+
+
 @pytest.mark.parametrize("policy, forwards", [(True, 2), (False, 3)])
 def test_recomputed_layers_compile_to_one_flash_forward_each(
         one_chip, monkeypatch, policy, forwards):
